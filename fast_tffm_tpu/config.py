@@ -376,8 +376,10 @@ class FmConfig:
     # Persistent XLA compilation cache directory (jax's
     # jax_compilation_cache_dir): restarts and replica spawns reuse
     # compiled executables from disk instead of paying warmup compiles
-    # again.  "" = off.  platform.enable_compile_cache() is the one
-    # wiring point; platform.compile_cache_stats() counts hits/misses.
+    # again.  "" = off, unless JAX_COMPILATION_CACHE_DIR is set: the
+    # environment's directory always wins over this knob.
+    # platform.enable_compile_cache() is the one wiring point;
+    # platform.compile_cache_stats() counts hits/misses.
     compile_cache_dir: str = ""
     # Sparse row updates (IndexedSlices-style): optimizer touches only the
     # rows in the batch. Falls back to dense when the optimizer/l2_mode

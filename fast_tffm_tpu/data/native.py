@@ -1,15 +1,16 @@
 """ctypes wrapper around the C++ batch parser (lazy-built with g++).
 
 The shared library is compiled on first use into the package directory and
-cached (rebuilt when the source is newer).  ``NativeParser.parse_batch`` is
-the drop-in fast path for the Python oracle's parse+batch
-(:func:`fast_tffm_tpu.data.libsvm.parse_lines` + ``make_batch``); tests
-enforce bit-exact agreement between the two.
+cached under a name that carries a hash of the source's content.
+``NativeParser.parse_batch`` is the drop-in fast path for the Python
+oracle's parse+batch (:func:`fast_tffm_tpu.data.libsvm.parse_lines` +
+``make_batch``); tests enforce bit-exact agreement between the two.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -35,27 +36,43 @@ class OutOfRangeIdsError(ValueError):
 
 _SRC_DIR = os.path.join(os.path.dirname(__file__), "_src")
 _SRC = os.path.join(_SRC_DIR, "fm_parser.cc")
-_LIB = os.path.join(_SRC_DIR, "libfm_parser.so")
+# Plain -O3, no -march=native: measured FASTER here (819k vs 705k
+# lines/s — native's wider vectorization loses on this workload), and a
+# baseline-ISA .so stays safe if the built artifact ever moves to a
+# different CPU.
+_CXX = ("g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread")
 _build_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 
 
+def _lib_path() -> str:
+    """The artifact's path, keyed on the CONTENT of the source and the
+    compile command: a binary built from another source (a copied tree
+    with arbitrary mtimes, an edited fm_parser.cc) has another name and
+    can never be loaded in place of the current one."""
+    h = hashlib.sha256(" ".join(_CXX).encode())
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    return os.path.join(_SRC_DIR, f"libfm_parser.{h.hexdigest()[:16]}.so")
+
+
 def _build() -> str:
     with _build_lock:
-        if os.path.exists(_LIB) and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC):
-            return _LIB
-        # Plain -O3, no -march=native: measured FASTER here (819k vs 705k
-        # lines/s — native's wider vectorization loses on this workload),
-        # and a baseline-ISA .so stays safe if the built artifact ever
-        # moves to a different CPU (the mtime cache can't tell).
-        cmd = [
-            "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-            _SRC, "-o", _LIB + ".tmp",
-        ]
+        lib = _lib_path()
+        if os.path.exists(lib):
+            return lib
+        # pid-suffixed temp + atomic rename: concurrent first builds
+        # (test workers, parse processes) never load a half-written file.
+        tmp = f"{lib}.{os.getpid()}.tmp"
+        cmd = [*_CXX, _SRC, "-o", tmp]
         log.info("building native parser: %s", " ".join(cmd))
-        subprocess.run(cmd, check=True, capture_output=True)
-        os.replace(_LIB + ".tmp", _LIB)
-        return _LIB
+        try:
+            subprocess.run(cmd, check=True, capture_output=True)
+            os.replace(tmp, lib)
+        finally:
+            if os.path.exists(tmp):
+                os.remove(tmp)
+        return lib
 
 
 def _load() -> ctypes.CDLL:

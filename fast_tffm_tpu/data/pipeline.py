@@ -509,12 +509,8 @@ class BatchPipeline:
         self._native, self._parser = _make_parser(cfg)
         # (vocab, chunk, tile) or None: when set, workers attach host-
         # computed sparse-apply prep (native.sort_meta) to each batch,
-        # moving the device step's id sort onto these threads.  Needs the
-        # native lib; silently skipped if it failed to build (the device
-        # fallback path sorts on-chip).
-        self._sort_meta_spec = (
-            sort_meta_spec if self._native is not None else None
-        )
+        # moving the device step's id sort onto these threads.
+        self._sort_meta_spec = sort_meta_spec
         self._sort_meta_warned = False
         # Truncation counted OUTSIDE the in-process native parser: process
         # workers ship their per-batch drop counts back with each batch,
@@ -523,14 +519,11 @@ class BatchPipeline:
         # trainer's periodic warning stays truthful in every ingest mode.
         self._trunc_extra = 0
         # Fast ingest: raw binary chunks + C++ line scan, no Python string
-        # per line. Requires the native parser; weight_files need per-line
-        # pairing so they stay on the line path. Shuffling permutes LINES
+        # per line. weight_files need per-line pairing so they stay on
+        # the line path. Shuffling permutes LINES
         # within shuffle_buffer-line windows (matching the line path's
         # reservoir window).
-        self._raw = (
-            cfg.fast_ingest and self._native is not None
-            and not self.weight_files
-        )
+        self._raw = cfg.fast_ingest and not self.weight_files
         # Multi-epoch parsed-batch cache (the tf.data ``.cache()``
         # pattern): epoch 0 parses normally while retaining every
         # delivered Batch; epochs 1..E-1 replay the cached batches in a
@@ -561,7 +554,7 @@ class BatchPipeline:
         FmParser warned about truncation, SURVEY.md §2 #1); the trainer
         surfaces this periodically.  Includes process-worker drops and
         cached-epoch replays (each replay re-adds epoch 0's total)."""
-        base = self._native.truncated_features if self._native else 0
+        base = self._native.truncated_features
         return base + self._trunc_extra
 
     @property
@@ -1182,7 +1175,6 @@ class BatchPipeline:
             hash_feature_id=cfg.hash_feature_id,
             field_num=cfg.field_num,
             batch_size=cfg.batch_size,
-            use_native=self._native is not None,
             sort_meta_spec=self._sort_meta_spec,
             shm_tag=shm_tag,
             ring_name=ring.name if ring is not None else None,
@@ -1908,39 +1900,29 @@ class DevicePrefetcher:
 
 
 def _make_parser(cfg: FmConfig):
-    """Returns (native_parser_or_None, (lines, weights) -> Batch)."""
-    native = None
-    try:
-        from fast_tffm_tpu.data import native as _native
+    """Returns (native_parser, (lines, weights) -> Batch).
 
-        # Parallelism comes from the pipeline's thread_num WORKERS (each
-        # parses a different group with the GIL released); internal C++
-        # threads on top would oversubscribe cores (thread_num^2) and a
-        # per-group fork/join barrier pipelines worse than independent
-        # groups anyway.
-        native = _native.NativeParser(
-            vocabulary_size=cfg.vocabulary_size,
-            max_features=cfg.max_features,
-            hash_feature_id=cfg.hash_feature_id,
-            field_num=cfg.field_num,
-            num_threads=1,
-        )
-    except Exception as e:  # pragma: no cover - env-dependent
-        log.info("native parser unavailable (%s); using Python parser", e)
+    The C++ parser is the only production parser: a failed build or
+    load raises here instead of handing over to the pure-Python
+    ``data.libsvm`` parser (the tests' oracle), which is orders of
+    magnitude slower and would also switch off fast_ingest and the
+    host sort metadata without a trace in the result."""
+    from fast_tffm_tpu.data import native as _native
 
-    if native is not None:
+    # Parallelism comes from the pipeline's thread_num WORKERS (each
+    # parses a different group with the GIL released); internal C++
+    # threads on top would oversubscribe cores (thread_num^2) and a
+    # per-group fork/join barrier pipelines worse than independent
+    # groups anyway.
+    native = _native.NativeParser(
+        vocabulary_size=cfg.vocabulary_size,
+        max_features=cfg.max_features,
+        hash_feature_id=cfg.hash_feature_id,
+        field_num=cfg.field_num,
+        num_threads=1,
+    )
 
-        def parse(lines, weights):
-            return native.parse_batch(lines, cfg.batch_size, weights)
+    def parse(lines, weights):
+        return native.parse_batch(lines, cfg.batch_size, weights)
 
-        return native, parse
-
-    def parse_py(lines, weights):
-        examples = libsvm.parse_lines(
-            lines, cfg.vocabulary_size, cfg.hash_feature_id, cfg.field_num
-        )
-        return libsvm.make_batch(
-            examples, cfg.batch_size, cfg.max_features, weights
-        )
-
-    return None, parse_py
+    return native, parse

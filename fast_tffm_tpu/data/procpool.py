@@ -86,7 +86,6 @@ class WorkerSpec:
     hash_feature_id: bool
     field_num: int
     batch_size: int
-    use_native: bool  # parent's parser choice; children must match it
     sort_meta_spec: Optional[tuple]  # (vocab, chunk, tile) or None
     shm_tag: str = "tffm0p0"  # name prefix for all segments of this run
     ring_name: Optional[str] = None  # inbound ShmRing segment (None = off)
@@ -422,53 +421,30 @@ def _safe_exc(e: BaseException) -> BaseException:
 
 
 def _build_parser(spec: WorkerSpec):
-    """(parse_lines_fn, parse_raw_fn, trunc_fn) for this worker."""
-    native_parser = None
-    if spec.use_native:
-        # The parent parsed natively; a child that silently fell back to
-        # the Python oracle could disagree bit-for-bit on edge tokens —
-        # fail loudly instead (same container, so this only fires when
-        # the build env genuinely changed under us).
-        from fast_tffm_tpu.data import native
+    """(parse_lines_fn, parse_raw_fn, trunc_fn) for this worker: the
+    same C++ parser the parent uses (a failed load raises here and
+    surfaces as an ("err", ...) result — never a quiet switch to the
+    Python oracle, which could disagree bit-for-bit on edge tokens)."""
+    from fast_tffm_tpu.data import native
 
-        native_parser = native.NativeParser(
-            vocabulary_size=spec.vocabulary_size,
-            max_features=spec.max_features,
-            hash_feature_id=spec.hash_feature_id,
-            field_num=spec.field_num,
-            num_threads=1,
-        )
+    native_parser = native.NativeParser(
+        vocabulary_size=spec.vocabulary_size,
+        max_features=spec.max_features,
+        hash_feature_id=spec.hash_feature_id,
+        field_num=spec.field_num,
+        num_threads=1,
+    )
 
-        def parse_lines(lines, weights):
-            return native_parser.parse_batch(
-                lines, spec.batch_size, weights
-            )
+    def parse_lines(lines, weights):
+        return native_parser.parse_batch(lines, spec.batch_size, weights)
 
-        def parse_raw(buf, starts, ends):
-            return native_parser.parse_raw(
-                buf, starts, ends, spec.batch_size
-            )
+    def parse_raw(buf, starts, ends):
+        return native_parser.parse_raw(buf, starts, ends, spec.batch_size)
 
-        def trunc():
-            return native_parser.truncated_features
+    def trunc():
+        return native_parser.truncated_features
 
-        return parse_lines, parse_raw, trunc
-
-    from fast_tffm_tpu.data import libsvm
-
-    def parse_lines_py(lines, weights):
-        examples = libsvm.parse_lines(
-            lines, spec.vocabulary_size, spec.hash_feature_id,
-            spec.field_num,
-        )
-        return libsvm.make_batch(
-            examples, spec.batch_size, spec.max_features, weights
-        )
-
-    def parse_raw_py(buf, starts, ends):  # pragma: no cover - guarded
-        raise RuntimeError("raw ingest requires the native parser")
-
-    return parse_lines_py, parse_raw_py, lambda: 0
+    return parse_lines, parse_raw, trunc
 
 
 def parse_worker_main(spec: WorkerSpec, work, out, stop,

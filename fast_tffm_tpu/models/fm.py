@@ -24,12 +24,6 @@ import jax
 import jax.numpy as jnp
 
 from fast_tffm_tpu.config import FmConfig
-from fast_tffm_tpu.platform import ensure_sharding_invariant_rng
-
-# Any module that can init a table imports this one; pin the RNG mode
-# here so a sharded init is element-wise identical on every mesh shape
-# (the `[4-2]` mixed-mesh parity fix — see platform.py for the story).
-ensure_sharding_invariant_rng()
 
 
 class FmParams(NamedTuple):

@@ -119,13 +119,18 @@ def cache_key(context: str, backend: str, batch: int, features: int,
 def default_cache_path(cfg) -> str:
     """Where the persistent cache lives for this run: the
     ``FAST_TFFM_AUTOTUNE_CACHE`` env override (empty string = memory
-    only), else alongside the persistent compile cache, else next to
-    the model checkpoint (the serve fleet reads the same file)."""
+    only), else alongside the persistent compile cache (the directory
+    ``platform.compile_cache_dir`` resolves: JAX_COMPILATION_CACHE_DIR
+    wins over the cfg knob), else next to the model checkpoint (the
+    serve fleet reads the same file)."""
     env = os.environ.get("FAST_TFFM_AUTOTUNE_CACHE")
     if env is not None:
         return env
-    if getattr(cfg, "compile_cache_dir", ""):
-        return os.path.join(cfg.compile_cache_dir, "autotune_cache.json")
+    from fast_tffm_tpu.platform import compile_cache_dir
+
+    cc = compile_cache_dir(getattr(cfg, "compile_cache_dir", ""))
+    if cc:
+        return os.path.join(cc, "autotune_cache.json")
     if getattr(cfg, "model_file", ""):
         d = os.path.dirname(os.path.abspath(cfg.model_file))
         return os.path.join(d, "autotune_cache.json")
@@ -342,14 +347,11 @@ def _measure(cfg, context: str, batch: int, table_dtype: str,
     survivors = []
     for name in names:
         fn = ref_fn if name == "reference" else make(name)
-        try:
-            out = fn(*args)
-            jax.block_until_ready(out)
-        except Exception as e:  # noqa: BLE001 - a broken candidate loses
-            log.warning("autotune candidate %s failed to run (%s: %s); "
-                        "excluded", name, type(e).__name__, e)
-            parity[name] = float("inf")
-            continue
+        # A candidate that fails to compile or run RAISES: losing
+        # quietly would let a kernel the chip's compiler refuses give
+        # way to the reference with nothing in the result to show it.
+        out = fn(*args)
+        jax.block_until_ready(out)
         _MEASUREMENTS += 1
         err = 0.0 if name == "reference" else _parity_error(out, ref_out)
         parity[name] = round(err, 9)

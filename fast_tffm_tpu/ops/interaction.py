@@ -130,7 +130,7 @@ def fm_interaction_sharded(rows, vals, use_pallas, mesh, data_axis: str):
         return fm_interaction(rows, vals, impl)
     from jax.sharding import PartitionSpec as P
 
-    from fast_tffm_tpu.platform import shard_map
+    from jax import shard_map
 
     # check_vma=False: pallas_call out_shapes don't carry vma annotations.
     return shard_map(

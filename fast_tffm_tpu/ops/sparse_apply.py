@@ -694,7 +694,7 @@ def make_entries_prefetch(mesh, data_axis, model_axis, vocab):
     """
     from jax.sharding import PartitionSpec as P
 
-    from fast_tffm_tpu.platform import shard_map
+    from jax import shard_map
 
     model_shards = mesh.shape[model_axis]
     vocab_local = vocab // model_shards
@@ -1007,7 +1007,7 @@ def _sharded_call(update_fn, mesh, data_axis, model_axis, tables, ids,
         dense = jax.lax.psum(dense, data_axis)
         return update_fn(dense[:, :d], dense[:, d:], *tables_l)
 
-    from fast_tffm_tpu.platform import shard_map
+    from jax import shard_map
 
     extra = () if rows_all is None else (rows_all,)
     extra_specs = () if rows_all is None else (P(model_axis),)

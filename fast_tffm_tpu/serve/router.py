@@ -43,6 +43,7 @@ verbatim, reusing kept-alive connections to each replica.
 from __future__ import annotations
 
 import collections
+import glob
 import http.client
 import json
 import logging
@@ -231,6 +232,41 @@ def _passthrough_flags(overrides: Optional[dict]) -> list:
     return args
 
 
+def _host_tpu_chips() -> int:
+    """TPU chips on this host, counted WITHOUT importing jax: the router
+    process must never initialize (and so hold) a chip its replicas
+    need.  0 on a host with no TPU, or when the environment pins the
+    CPU backend."""
+    plats = os.environ.get("JAX_PLATFORMS", "").strip().lower()
+    if plats and "tpu" not in plats.split(","):
+        return 0
+    return len(glob.glob("/dev/accel[0-9]*")) or len(
+        glob.glob("/dev/vfio/[0-9]*")
+    )
+
+
+def _replica_env(base: dict, index: int, n_replicas: int,
+                 chips: int) -> dict:
+    """Replica ``index``'s environment.  A chip belongs to one process
+    at a time, and a replica that inherits the parent's view initializes
+    EVERY visible chip — the second replica then fails or hangs.  So on
+    a TPU host replica i sees chip i and nothing else (a one-chip
+    process topology), and more replicas than chips is refused."""
+    env = dict(base)
+    if chips <= 0:
+        return env
+    if n_replicas > chips:
+        raise ValueError(
+            f"serve_replicas={n_replicas} but this host has {chips} TPU "
+            "chip(s): a chip serves one replica process at a time.  "
+            "Lower --replicas (or pin JAX_PLATFORMS=cpu for a CPU fleet)"
+        )
+    env["TPU_VISIBLE_CHIPS"] = str(index)
+    env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = "1,1,1"
+    env["TPU_PROCESS_BOUNDS"] = "1,1,1"
+    return env
+
+
 def _replica_command(cfg: FmConfig, cfg_path: str, index: int,
                      overrides: Optional[dict]) -> list:
     cmd = [
@@ -310,6 +346,7 @@ class ReplicaManager:
         self._cfg_path = cfg_path
         self._overrides = overrides
         self._env = env
+        self._chips = _host_tpu_chips()
         self._lock = threading.Lock()
         self._closed = False
         self._procs: list = []
@@ -317,7 +354,10 @@ class ReplicaManager:
         try:
             for i in range(cfg.serve_replicas):
                 cmd = _replica_command(cfg, cfg_path, i, overrides)
-                self._procs.append(_ReplicaProc(i, cmd, env))
+                self._procs.append(_ReplicaProc(
+                    i, cmd,
+                    _replica_env(env, i, cfg.serve_replicas, self._chips),
+                ))
             deadline = time.time() + startup_timeout_s
             for rp in self._procs:
                 rp.ready.wait(max(0.0, deadline - time.time()))
@@ -360,7 +400,9 @@ class ReplicaManager:
             cmd = _replica_command(
                 self._cfg, self._cfg_path, index, self._overrides
             )
-            fresh = _ReplicaProc(index, cmd, self._env)
+            fresh = _ReplicaProc(index, cmd, _replica_env(
+                self._env, index, self._cfg.serve_replicas, self._chips
+            ))
             self._procs[index] = fresh
         log.info("respawning replica %d (pid %d)", index,
                  fresh.proc.pid)

@@ -127,7 +127,6 @@ class _LadderScorer:
         self._prev = None
         self._cache: dict = {}
         self._pools: dict = {}  # rung -> (ids, vals, fields) host buffers
-        self._aot_broken = False
         self._warmed = False
         # Whether EXPECTED compiles may legitimately happen after
         # warmup: False for the dense scorer (warmup compiles the whole
@@ -205,18 +204,6 @@ class _LadderScorer:
             s = jax.nn.sigmoid(s)
         return s
 
-    def _aot_fail(self, e: BaseException):
-        """Permanent fallback on AOT API drift: dispatch through the
-        plain jit (identical math; compiles become invisible to the
-        zero-compile accounting, so say so loudly)."""
-        self._aot_broken = True
-        log.warning(
-            "serve AOT compile path unavailable (%s: %s); falling back "
-            "to plain jit dispatch (compiles become invisible to the "
-            "zero-compile accounting)", type(e).__name__, e,
-        )
-        return self._jit
-
     # -- compile accounting --------------------------------------------
 
     def _account_compile(self, wall: float, key, expected: bool) -> None:
@@ -264,7 +251,7 @@ class _LadderScorer:
         """
         t0 = time.perf_counter()
         with self._lock:
-            if len(self.ladder) > 1 and not self._aot_broken:
+            if len(self.ladder) > 1:
                 with ThreadPoolExecutor(
                     max_workers=min(len(self.ladder), 8),
                     thread_name_prefix="tffm-warmup",
@@ -628,8 +615,6 @@ class FixedShapeScorer(_LadderScorer):
         fn = self._cache.get(b)
         if fn is not None:
             return fn
-        if self._aot_broken:
-            return self._jit
         structs = tuple(
             jax.ShapeDtypeStruct((b, self._feat), dt)
             for dt in self._arg_dtypes[:self._n_args]
@@ -638,11 +623,8 @@ class FixedShapeScorer(_LadderScorer):
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self._params
         )
         t0 = time.perf_counter()
-        try:
-            with _quiet_donation():
-                fn = self._jit.lower(p_struct, *structs).compile()
-        except Exception as e:  # pragma: no cover - jax API drift
-            return self._aot_fail(e)
+        with _quiet_donation():
+            fn = self._jit.lower(p_struct, *structs).compile()
         self._account_compile(
             time.perf_counter() - t0, b, expected=b in self._ladder_set
         )
@@ -744,8 +726,6 @@ class OverlayScorer(_LadderScorer):
         fn = self._cache.get(key)
         if fn is not None:
             return fn
-        if self._aot_broken:
-            return self._jit
         structs = (
             jax.ShapeDtypeStruct((), np.float32),
             jax.ShapeDtypeStruct((rows, self._dim), np.float32),
@@ -754,11 +734,8 @@ class OverlayScorer(_LadderScorer):
             for dt in self._arg_dtypes[:self._n_args]
         )
         t0 = time.perf_counter()
-        try:
-            with _quiet_donation():
-                fn = self._jit.lower(*structs).compile()
-        except Exception as e:  # pragma: no cover - jax API drift
-            return self._aot_fail(e)
+        with _quiet_donation():
+            fn = self._jit.lower(*structs).compile()
         # Bucketed compact-table shapes are all expected: the row
         # ladder is log-sized by construction, the rung ladder by
         # config.  Off-ladder RUNGS still flag.

@@ -659,12 +659,12 @@ def serve(cfg: FmConfig, mesh=None, port: Optional[int] = None
     ``port`` overrides ``cfg.serve_port`` (tests pass 0 for an
     OS-assigned port; the bound port is ``handle.port``).
     """
-    # Persistent XLA compilation cache (compile_cache_dir knob),
-    # enabled before the scorer's warmup compiles: a replica spawned
-    # against a populated cache replays its whole ladder from disk —
-    # zero fresh lowers (platform.compile_cache_stats counts both ways).
-    if cfg.compile_cache_dir:
-        platform.enable_compile_cache(cfg.compile_cache_dir)
+    # Persistent XLA compilation cache (JAX_COMPILATION_CACHE_DIR, else
+    # the compile_cache_dir knob), enabled before the scorer's warmup
+    # compiles: a replica spawned against a populated cache replays its
+    # whole ladder from disk — zero fresh lowers
+    # (platform.compile_cache_stats counts both ways).
+    platform.enable_compile_cache(cfg.compile_cache_dir)
     writer = (
         obs.JsonlWriter(cfg.metrics_file) if cfg.metrics_file else None
     )
@@ -728,8 +728,8 @@ def serve(cfg: FmConfig, mesh=None, port: Optional[int] = None
         "precompiled — steady-state serving performs zero compiles",
         scorer.step, list(scorer.ladder), n_compiles,
     )
-    if cfg.compile_cache_dir:
-        stats = platform.compile_cache_stats()
+    stats = platform.compile_cache_stats()
+    if stats["dir"]:
         log.info(
             "compile cache %s: %d hit(s), %d miss(es) during warmup%s",
             stats["dir"], stats["hits"], stats["misses"],

@@ -440,11 +440,11 @@ class Trainer:
 
     def __init__(self, cfg: FmConfig, mesh=None):
         self.cfg = cfg
-        # Persistent XLA compilation cache (compile_cache_dir knob):
-        # enabled before ANY jit below so restarts replay this run's
-        # step/eval compiles from disk instead of re-lowering.
-        if cfg.compile_cache_dir:
-            platform.enable_compile_cache(cfg.compile_cache_dir)
+        # Persistent XLA compilation cache (JAX_COMPILATION_CACHE_DIR,
+        # else the compile_cache_dir knob; neither = no-op): enabled
+        # before ANY jit below so restarts replay this run's step/eval
+        # compiles from disk instead of re-lowering.
+        platform.enable_compile_cache(cfg.compile_cache_dir)
         self.mesh = mesh if mesh is not None else mesh_lib.make_mesh(cfg)
         # Run-wide telemetry registry, shared by the ingest pipeline, the
         # transfer thread, and the dispatch loop.  Disabled -> every
@@ -738,7 +738,6 @@ class Trainer:
         # historical implicit-jit path, bit-identical training.
         self._compile_cache: dict = {}
         self._primary_rest = None  # non-leading shape sig of compile #1
-        self._aot_broken = False  # toolchain drift -> permanent fallback
         # A short-k compile is whitelisted PROVISIONALLY: a real epoch
         # tail is followed by the EpochEnd marker (or end of stream),
         # so the dispatch loop confirms the boundary and reclassifies
@@ -818,7 +817,7 @@ class Trainer:
                     )
 
                 self._tier_gather_jit = jax.jit(
-                    platform.shard_map(
+                    jax.shard_map(
                         _gather_fn, mesh=self.mesh,
                         in_specs=(tab_spec, P(mp)),
                         out_specs=tab_spec,
@@ -827,7 +826,7 @@ class Trainer:
                     out_shardings=tab_sh,
                 )
                 self._tier_load_jit = jax.jit(
-                    platform.shard_map(
+                    jax.shard_map(
                         _load_fn, mesh=self.mesh,
                         in_specs=(tab_spec, P(mp), tab_spec),
                         out_specs=tab_spec,
@@ -1120,7 +1119,7 @@ class Trainer:
         sentinel sees every (re)compilation; the executable is the same
         lowering jit would have produced, so the math is identical
         either way."""
-        if self._sentinel is not None and not self._aot_broken:
+        if self._sentinel is not None:
             fn = self._compiled_scan(state, batches)
         else:
             fn = self._scan_health_jit
@@ -1144,9 +1143,9 @@ class Trainer:
         epoch-tail K' < steps_per_dispatch whose non-leading shapes
         match the first compile's — whitelisted provisionally, then
         confirmed by the dispatch loop (an epoch boundary must follow;
-        see _resolve_tail_probation).  Any API drift in the AOT path
-        degrades permanently to the implicit-jit call — observability
-        must never take down the training it observes."""
+        see _resolve_tail_probation).  A compile error (HBM, VMEM,
+        tiling) raises: re-dispatching through plain jit would only
+        hit the same compiler with the cause hidden."""
         leaves, treedef = jax.tree_util.tree_flatten(batches)
         key = (treedef, tuple((x.shape, str(x.dtype)) for x in leaves))
         fn = self._compile_cache.get(key)
@@ -1154,22 +1153,13 @@ class Trainer:
             return fn
         k = int(batches.labels.shape[0])
         rest = tuple(x.shape[1:] for x in leaves)
-        try:
-            t0 = time.perf_counter()
-            with self.tracer.span("train.compile", args={"k": k}), \
-                    obs.trace_span("tffm:compile"):
-                fn = self._scan_health_jit.lower(
-                    state, self._health, batches
-                ).compile()
-            wall = time.perf_counter() - t0
-        except Exception as e:  # pragma: no cover - jax API drift
-            self._aot_broken = True
-            log.warning(
-                "AOT compile path unavailable (%s: %s); compile "
-                "sentinel disabled, dispatching through plain jit",
-                type(e).__name__, e,
-            )
-            return self._scan_health_jit
+        t0 = time.perf_counter()
+        with self.tracer.span("train.compile", args={"k": k}), \
+                obs.trace_span("tffm:compile"):
+            fn = self._scan_health_jit.lower(
+                state, self._health, batches
+            ).compile()
+        wall = time.perf_counter() - t0
         if self._primary_rest is None:
             expected = True  # startup compile (whatever its K)
             self._primary_rest = rest

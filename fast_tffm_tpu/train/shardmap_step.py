@@ -307,7 +307,7 @@ def sparse_step_shardmap(cfg: FmConfig, params, opt_state, batch: Batch,
         + (P(MODEL_AXIS, None),) * n_opt
         + ((P(), P()) if health else ())
     )
-    from fast_tffm_tpu.platform import shard_map
+    from jax import shard_map
 
     outs = shard_map(
         device_fn,
@@ -348,7 +348,7 @@ def make_exchange_probe(mesh):
     import numpy as np
     from jax.sharding import NamedSharding
 
-    from fast_tffm_tpu.platform import shard_map
+    from jax import shard_map
 
     spec = P((DATA_AXIS, MODEL_AXIS))
     reduce = jax.jit(shard_map(

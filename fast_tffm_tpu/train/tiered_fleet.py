@@ -23,7 +23,7 @@ system's parameter-server role, recast for SPMD):
   (``rows_enabled``): cold stores, write-back ledger, row fetch,
   ``tiered.*`` telemetry.  The rest are metadata MIRRORS — per-rank host
   bytes, migration H2D/D2H traffic, and telemetry all read ~1/R.
-- Device-side migration runs through ``platform.shard_map`` programs
+- Device-side migration runs through ``jax.shard_map`` programs
   whose bodies contain no collectives (see ``train.loop``): each column
   loads/gathers only its own rows, so cross-rank migration traffic is
   structurally zero, not merely observed to be.

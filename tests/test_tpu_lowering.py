@@ -24,48 +24,6 @@ from fast_tffm_tpu import platform as pf
 from fast_tffm_tpu.ops import fm_pallas, sparse_apply
 
 
-def _export_skip_reason() -> str:
-    """Version-aware probe of the jax.export / Mosaic toolchain.
-
-    These tests need ``jax.export`` AND a Mosaic pass that can lower a
-    trivial kernel for the tpu platform from a CPU-only host.  Both
-    drift with the container's jax build (this jax 0.4.37 build ships
-    no ``jax.export`` at all) — a DOCUMENTED pre-existing failure
-    (ROADMAP.md "Pre-existing failures"), not a regression this suite
-    should keep re-reporting as red.  Probe once at collection and
-    skip LOUDLY: the skip reason names the exact drift so a toolchain
-    bump that restores export support turns the suite back on by
-    itself (and a skip that persists on a fixed toolchain is a bug in
-    this probe).
-    """
-    if not hasattr(jax, "export"):
-        return (
-            f"jax {jax.__version__} in this container has no jax.export "
-            "— the TPU-lowering gate cannot run (documented "
-            "pre-existing failure; re-enable on a toolchain with "
-            "jax.export + Mosaic)"
-        )
-    try:
-        with pf.force_compiled():
-            jax.export.export(
-                jax.jit(lambda x: x + 1), platforms=["tpu"]
-            )(jax.ShapeDtypeStruct((8,), jnp.float32))
-    except Exception as e:  # pragma: no cover - toolchain-dependent
-        return (
-            f"jax.export for platform 'tpu' is broken in this container "
-            f"(jax {jax.__version__}: {type(e).__name__}: {e}) — "
-            "Mosaic container drift, documented pre-existing failure"
-        )
-    return ""
-
-
-_SKIP_REASON = _export_skip_reason()
-# Loud module-wide skip: every test here depends on the same probe, and
-# a silent collection error would look identical to "suite green".
-pytestmark = pytest.mark.skipif(
-    bool(_SKIP_REASON), reason=_SKIP_REASON
-)
-
 V, D, N = 4096, 9, 2048
 B, F, K = 1024, 39, 8
 

@@ -164,7 +164,7 @@ def bench_procs(files, n_procs: int, epochs: int = 2):
 
 
 def main() -> int:
-    from bench import _gen_libsvm_files
+    from fast_tffm_tpu.data.synth import gen_libsvm_files
     from fast_tffm_tpu import obs
     from fast_tffm_tpu.config import FmConfig
     from fast_tffm_tpu.data import native as native_lib
@@ -173,7 +173,7 @@ def main() -> int:
     tmpdir = tempfile.mkdtemp(prefix="ingest_bench_")
     try:
         rng = np.random.default_rng(7)
-        files = _gen_libsvm_files(tmpdir, rng, 4, 8 * BATCH, NFEAT, VOCAB)
+        files = gen_libsvm_files(tmpdir, rng, 4, 8 * BATCH, NFEAT, VOCAB)
         total = 4 * 8 * BATCH
         size = sum(os.path.getsize(f) for f in files)
         print(json.dumps({

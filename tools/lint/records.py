@@ -1,7 +1,7 @@
 """RS00x — JSONL record-schema drift analyzer.
 
 check_obs pinned metric NAMES; this rule generalizes the discipline to
-the full ``record:`` taxonomy of the metrics stream (run_header /
+the full ``record:`` catalogue of the metrics stream (run_header /
 train / validation / heartbeat / final / compile / alert / status, plus
 the ``health`` / ``tiered`` / ``resource`` / ``serve`` / ``stages``
 blocks that ride the heartbeat-shaped records), pinned against the
@@ -213,7 +213,7 @@ class RecordsRule:
             findings.append(Finding(
                 rule="RS002", path=ctx.obs_md, line=1,
                 message="no '## Record schema' table found — the "
-                        "record taxonomy is unpinned",
+                        "record catalogue is unpinned",
                 hint="add the table (see LINTING.md)",
                 symbol="<missing-table>",
             ))

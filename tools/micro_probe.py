@@ -42,6 +42,7 @@ import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from fast_tffm_tpu.ops import sparse_apply as sa
+from fast_tffm_tpu.platform import use_interpret
 
 def _k2t_kernel(ts_ref, table_ref, acc_ref, u_hbm_ref, table_out_ref,
                 acc_out_ref, u_vmem, sem, *, tile, group, d, lr, eps):
@@ -95,7 +96,7 @@ def k2t_apply(table_t, acc_t, ids_, g_rows, *, lr, eps):
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((d, vocab), jnp.float32)] * 2,
         input_output_aliases={1: 0, 2: 1},
-        interpret=jax.default_backend() == "cpu",
+        interpret=use_interpret(),
     )(tile_start, table_t, acc_t, u)
 
 
@@ -104,7 +105,7 @@ def _k2p_kernel(ts_ref, table_ref, acc_ref, u_hbm_ref, table_out_ref,
     """Packed-layout K2: tables stored [V/8, 128] — 8 consecutive rows
     of 16 lanes (d values + pad) per 128-lane line, so the physical HBM
     stream is ~1.8x logical instead of the ~14x a lane-padded [V, 9]
-    layout costs (decision tree in TPU_STATUS.md).  Placement: entry
+    layout costs (decision tree in PERF.md, "One v5e window, round 4").  Placement: entry
     payloads are lane-shifted into their slot with pure VPU iota math
     (no relayout reshapes), then one [R, lines] one-hot matmul sums
     them per packed line."""
@@ -195,7 +196,7 @@ def k2p_apply(table_p, acc_p, ids_, g_rows, *, lr, eps):
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((vocab // 8, 128), jnp.float32)] * 2,
         input_output_aliases={1: 0, 2: 1},
-        interpret=jax.default_backend() == "cpu",
+        interpret=use_interpret(),
     )(tile_start, table_p, acc_p, u)
 
 

@@ -69,12 +69,12 @@ echo "== quantized-table smoke (tools/quant_smoke.py) =="
 JAX_PLATFORMS=cpu python tools/quant_smoke.py || exit 1
 
 echo
-echo "== tier-1 pytest (pinned invocation from ROADMAP.md) =="
+echo "== tier-1 pytest (the driver's flags: 6 xdist workers, loadfile) =="
 set -o pipefail
 rm -f /tmp/_t1.log
-timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
+timeout -k 10 1470 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
     -m 'not slow' --continue-on-collection-errors -p no:cacheprovider \
-    -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log
+    -p xdist -n 6 --dist loadfile -p no:randomly 2>&1 | tee /tmp/_t1.log
 rc=${PIPESTATUS[0]}
 echo "DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log \
     | tr -cd . | wc -c)"
