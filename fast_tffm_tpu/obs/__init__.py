@@ -4,7 +4,8 @@ observability plane (status endpoint + alert watchdog).
 ``obs.Telemetry`` is the shared instrument registry (counters, gauges,
 ring-buffer timings) every pipeline stage writes into; ``obs.NULL`` is
 the always-safe disabled registry; ``obs.trace_span`` names host phases
-in xprof traces; ``obs.Heartbeat``/``obs.JsonlWriter`` turn a running
+in xprof traces and ``obs.Phase`` pairs one with a timer;
+``obs.Heartbeat``/``obs.JsonlWriter`` turn a running
 train into a self-reporting JSONL stream; ``obs.Tracer`` /
 ``obs.NULL_TRACER`` record Chrome-trace (Perfetto-loadable) spans from
 every stage, correlated per batch/super-batch (trace.py), with windowed
@@ -38,13 +39,13 @@ from fast_tffm_tpu.obs.resource import (
 from fast_tffm_tpu.obs.sketch import FreqSketch, QuantileSketch, SketchSet
 from fast_tffm_tpu.obs.status import StatusServer, render_prometheus
 from fast_tffm_tpu.obs.telemetry import (
-    NULL, Counter, DepthHist, Gauge, Telemetry, Timing, trace_span,
+    NULL, Counter, DepthHist, Gauge, Phase, Telemetry, Timing, trace_span,
 )
 from fast_tffm_tpu.obs.trace import NULL_TRACER, Tracer
 
 __all__ = [
     "Counter", "Gauge", "Timing", "DepthHist", "Telemetry", "NULL",
-    "trace_span", "Heartbeat", "JsonlWriter", "rank_suffix_path",
+    "Phase", "trace_span", "Heartbeat", "JsonlWriter", "rank_suffix_path",
     "Tracer", "NULL_TRACER",
     "MergeSpec", "TrainFleet", "labeled_lines", "merge_blocks",
     "StatusServer", "render_prometheus",

@@ -46,7 +46,7 @@ from typing import Callable, Dict, Optional
 
 __all__ = [
     "Counter", "Gauge", "Timing", "DepthHist", "Telemetry", "NULL",
-    "trace_span",
+    "Phase", "trace_span",
 ]
 
 _RING = 512  # recent-window size for percentile estimates
@@ -388,26 +388,18 @@ _trace_annotation: Optional[Callable] = None
 _trace_resolved = False
 
 
-def trace_span(name: str):
-    """``jax.profiler.TraceAnnotation(name)`` when jax is importable,
-    else a null context manager.
-
-    Makes xprof traces readable — stack/H2D/dispatch phases show up as
-    named host spans — without making the data layer depend on jax (the
-    spawned parse workers must never import it).  The annotation only
-    resolves once jax is ALREADY imported by someone else: a jax import
-    triggered from here would make a jax-free process (a parse worker,
-    ingest_bench) a jax process — one that may go on to claim the chip
-    its parent holds — and with no jax there is no trace to annotate
-    anyway.  With no active trace an annotation is
-    nearly free.
-    """
+def _annotation() -> Optional[Callable]:
+    """``jax.profiler.TraceAnnotation`` once jax is ALREADY imported by
+    someone else, else None: a jax import triggered from here would
+    make a jax-free process (a parse worker, ingest_bench) a jax
+    process — one that may go on to claim the chip its parent holds —
+    and with no jax there is no trace to annotate anyway."""
     global _trace_annotation, _trace_resolved
     if not _trace_resolved:
         import sys as _sys
 
         if "jax" not in _sys.modules:
-            return contextlib.nullcontext()
+            return None
         _trace_resolved = True
         try:  # pragma: no cover - env-dependent
             import jax.profiler as _prof
@@ -415,6 +407,60 @@ def trace_span(name: str):
             _trace_annotation = _prof.TraceAnnotation
         except Exception:
             _trace_annotation = None
-    if _trace_annotation is None:
+    return _trace_annotation
+
+
+def trace_span(name: str, **stats):
+    """``jax.profiler.TraceAnnotation(name, **stats)`` when jax is
+    importable, else a null context manager.
+
+    Makes xprof traces readable — stack/H2D/dispatch phases show up as
+    named host spans — without making the data layer depend on jax (the
+    spawned parse workers must never import it).  With no active trace
+    an annotation is nearly free.  ``stats`` (small ints) ride the
+    event as its stats.
+    """
+    annotation = _annotation()
+    if annotation is None:
         return contextlib.nullcontext()
-    return _trace_annotation(name)
+    return annotation(name, **stats)
+
+
+class Phase:
+    """One pass through a named phase of a request path: a
+    :class:`Timing` observation and a :func:`trace_span` over the same
+    block.  ``t0`` / ``t1`` are the block's ``perf_counter`` boundaries,
+    kept so that a ``Tracer`` span of the same phase reads no clock of
+    its own; ``set(**stats)`` adds the annotation stats that are only
+    known once the work is done.  With no profiler session open at its
+    start a pass makes no annotation at all (about half its cost)."""
+
+    __slots__ = ("_timing", "_span", "t0", "t1")
+
+    def __init__(self, timing, span_name: str, **stats) -> None:
+        self._timing = timing
+        annotation = _annotation()
+        self._span = (
+            annotation(span_name, **stats)
+            if annotation is not None and annotation.is_enabled() else None
+        )
+
+    def __enter__(self) -> "Phase":
+        self.t0 = time.perf_counter()
+        if self._span is not None:
+            self._span.__enter__()
+        return self
+
+    def set(self, **stats) -> None:
+        if self._span is not None:
+            self._span.set_metadata(**stats)
+
+    def __exit__(self, *exc) -> None:
+        if self._span is not None:
+            self._span.__exit__(*exc)
+        self.t1 = t1 = time.perf_counter()
+        self._timing.observe(t1 - self.t0)
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0

@@ -23,18 +23,28 @@ swap can never interleave old and new params inside one microbatch.
 Instruments (all ``serve.*``, documented in OBSERVABILITY.md):
 ``requests`` / ``examples`` / ``batches`` counters, the ``latency``
 timer (enqueue -> scores delivered; p50/p95/p99 ride every snapshot),
-the ``batch_fill`` gauge (cumulative filled/dispatched slots), and the
+the ``queue_wait`` timer (enqueue -> picked, every request), the
+``batch_fill`` gauge (cumulative filled/dispatched slots), and the
 ``queue_depth`` histogram.
+
+The dispatcher's work is tiled by phases, each an ``obs.Phase`` (a
+``serve.<phase>`` timer and a ``tffm:serve.<phase>`` annotation on the
+device trace's clock): ``coalesce`` (first request picked -> group
+closed: the batcher's own deliberate wait), ``fill`` (group -> staging
+buffers), the scorer's ``launch`` / ``readback``, ``deliver`` (scores
+split, every waiter released) and ``quality`` (the skew-sketch fold).
+The wait on an empty queue carries no annotation: a span over a wait
+for another thread would cover whole idle gaps of the device and hide
+what the working thread did.
 
 Distributed tracing: a request carrying a request id (``rid``, from
 the ``X-Request-Id`` header or the binary frame's trailer on a SAMPLED
 request) gets per-request spans — ``serve.queue_wait`` (enqueue ->
-picked by the dispatcher), ``serve.coalesce`` (picked -> the
-microbatch dispatches) and ``serve.dispatch`` (the rung dispatch, with
-a flow step on the rid) — emitted from recorded timestamps AFTER the
-dispatch, so the hot path pays nothing but two ``perf_counter`` reads.
-A rid-less request touches none of it (the unsampled path is the
-pre-trace code path).
+picked by the dispatcher), ``serve.coalesce`` (picked -> its group
+closed) and ``serve.dispatch`` (fill + the rung dispatch, with
+``launch_ms`` / ``readback_ms`` in its args and a flow step on the
+rid) — emitted AFTER the dispatch from the timestamps the phases
+already took.
 """
 
 from __future__ import annotations
@@ -62,8 +72,7 @@ class ScoreRequest:
 
     ``rid`` is the distributed-trace request id (None = unsampled);
     ``t_picked`` is stamped by the dispatcher when the request leaves
-    the queue, only for rid-carrying requests (span reconstruction
-    needs it; the unsampled path skips the write).
+    the queue (``serve.queue_wait`` = ``t_picked - t0``, every request).
 
     ``on_done`` is the scratch-release hook for pooled parse buffers
     (serve/textparse.py): the batcher fires it exactly once when it is
@@ -123,6 +132,16 @@ class ServeBatcher:
         self._c_examples = tel.counter("serve.examples")
         self._c_batches = tel.counter("serve.batches")
         self._t_latency = tel.timer("serve.latency")
+        self._t_queue_wait = tel.timer("serve.queue_wait")
+        self._t_coalesce = tel.timer("serve.coalesce")
+        self._t_fill = tel.timer("serve.fill")
+        self._t_deliver = tel.timer("serve.deliver")
+        self._t_quality = tel.timer("serve.quality")
+        # The scorer's two phase timers (same registry, same names): a
+        # sampled request's serve.dispatch span reads its dispatch's
+        # share as the difference of their totals (0 with telemetry off).
+        self._t_launch = tel.timer("serve.launch")
+        self._t_readback = tel.timer("serve.readback")
         self._g_fill = tel.gauge("serve.batch_fill")
         # Live in-flight count (accepted, scores not yet delivered):
         # the replica-side load signal the router's P2C dispatch and
@@ -234,112 +253,138 @@ class ServeBatcher:
         max_b = self._scorer.max_rung
         pending: Optional[ScoreRequest] = None
         while True:
+            # The wait on an empty queue: no annotation (module docstring).
             first = pending if pending is not None else self._q.get()
-            pending = None
             if first is _CANCELLED:
                 break
-            if first.rid is not None and first.t_picked is None:
-                first.t_picked = time.perf_counter()
-            group = [first]
-            total = first.n
-            deadline = time.monotonic() + self._wait_s
-            while total < max_b:
-                remaining = deadline - time.monotonic()
-                if remaining <= 0:
-                    break
-                nxt = self._q.get(timeout=remaining)
-                if nxt is _TIMEOUT:
-                    break
-                if nxt is _CANCELLED:
-                    break
-                if nxt.rid is not None:
+            with obs.Phase(self._t_coalesce, "tffm:serve.coalesce") as ph:
+                if pending is None:
+                    first.t_picked = ph.t0
+                pending = None
+                group = [first]
+                total = first.n
+                deadline = time.monotonic() + self._wait_s
+                while total < max_b:
+                    remaining = deadline - time.monotonic()
+                    if remaining <= 0:
+                        break
+                    nxt = self._q.get(timeout=remaining)
+                    if nxt is _TIMEOUT:
+                        break
+                    if nxt is _CANCELLED:
+                        break
                     nxt.t_picked = time.perf_counter()
-                if total + nxt.n > max_b:
-                    # Doesn't fit this rung: dispatch what we have and
-                    # seed the next microbatch (keeps every coalesced
-                    # group within one dispatch).
-                    pending = nxt
-                    break
-                group.append(nxt)
-                total += nxt.n
-            self._dispatch(group, total)
+                    if total + nxt.n > max_b:
+                        # Doesn't fit this rung: dispatch what we have and
+                        # seed the next microbatch (keeps every coalesced
+                        # group within one dispatch).
+                        pending = nxt
+                        break
+                    group.append(nxt)
+                    total += nxt.n
+                ph.set(reqs=len(group), n=total)
+            self._dispatch(group, total, ph.t1)
         # Queue cancelled: fail whatever is still outstanding (items
         # the cancel discarded AND a pending carry-over).
         self._fail_outstanding(RuntimeError("ServeBatcher closed"))
 
-    def _trace_request(self, g: ScoreRequest, t_d0: float,
-                       t_d1: float, rung: int, total: int) -> None:
+    def _trace_request(self, g: ScoreRequest, t_closed: float,
+                       t_scored: float, rung: int, total: int,
+                       launch_s: float, readback_s: float) -> None:
         """Emit one sampled request's replica-side spans from the
-        recorded timestamps (queue wait -> coalesce -> dispatch).  The
+        phases' timestamps (queue wait -> coalesce -> dispatch).  The
         flow step on the rid links the chain to the router's proxy
-        span and the handler's respond span."""
-        args = {"rid": g.rid}
-        picked = g.t_picked if g.t_picked is not None else t_d0
+        span and the handler's respond span.  A request carried over
+        from the group before was picked before that group closed: its
+        coalesce span covers that dispatch too."""
         self._tracer.emit(
-            "serve.queue_wait", g.t0, picked - g.t0, args=args,
+            "serve.queue_wait", g.t0, g.t_picked - g.t0,
+            args={"rid": g.rid},
         )
         self._tracer.emit(
-            "serve.coalesce", picked, t_d0 - picked,
+            "serve.coalesce", g.t_picked, t_closed - g.t_picked,
             args={"rid": g.rid, "group_n": total},
         )
         self._tracer.emit(
-            "serve.dispatch", t_d0, t_d1 - t_d0,
-            args={"rid": g.rid, "rung": rung, "n": total},
+            "serve.dispatch", t_closed, t_scored - t_closed,
+            args={"rid": g.rid, "rung": rung, "n": total,
+                  "launch_ms": round(1e3 * launch_s, 4),
+                  "readback_ms": round(1e3 * readback_s, 4)},
             flow=("t", g.rid),
         )
 
-    def _dispatch(self, group, total: int) -> None:
+    def _dispatch(self, group, total: int, t_closed: float) -> None:
         scorer = self._scorer
-        rung = 0
-        t_d0 = time.perf_counter()
         try:
-            if len(group) == 1 and total > scorer.max_rung:
-                # One oversized request: the scorer chunks it itself
-                # and owns the matching slot accounting.
+            with obs.Phase(self._t_fill, "tffm:serve.fill") as ph:
+                qwait = 0.0
+                for g in group:
+                    wait = g.t_picked - g.t0
+                    self._t_queue_wait.observe(wait)
+                    qwait += wait
+                oversized = len(group) == 1 and total > scorer.max_rung
+                if oversized:
+                    # One oversized request: the scorer chunks and
+                    # fills it itself.
+                    rung = scorer.max_rung
+                else:
+                    rung = b = scorer.rung_for(total)
+                    bi, bv, bf = self._pool(b)
+                    pos = 0
+                    any_fields = any(g.fields is not None for g in group)
+                    for g in group:
+                        bi[pos:pos + g.n] = g.ids
+                        bv[pos:pos + g.n] = g.vals
+                        if any_fields:
+                            bf[pos:pos + g.n] = (
+                                g.fields if g.fields is not None else 0
+                            )
+                        pos += g.n
+                    if pos < b:
+                        bi[pos:] = 0
+                        bv[pos:] = 0.0
+                        if any_fields:
+                            bf[pos:] = 0
+                ph.set(reqs=len(group), n=total, rung=rung,
+                       qwait_us=int(1e6 * qwait))
+            launch_s = self._t_launch.total_s
+            readback_s = self._t_readback.total_s
+            if oversized:
+                # ... and owns the matching slot accounting.
                 req = group[0]
                 scores = scorer.score(req.ids, req.vals, req.fields)
                 self._slots += scorer.slots_for(total)
-                rung = scorer.max_rung
             else:
-                rung = b = scorer.rung_for(total)
-                bi, bv, bf = self._pool(b)
-                pos = 0
-                any_fields = any(g.fields is not None for g in group)
-                for g in group:
-                    bi[pos:pos + g.n] = g.ids
-                    bv[pos:pos + g.n] = g.vals
-                    if any_fields:
-                        bf[pos:pos + g.n] = (
-                            g.fields if g.fields is not None else 0
-                        )
-                    pos += g.n
-                if pos < b:
-                    bi[pos:] = 0
-                    bv[pos:] = 0.0
-                    if any_fields:
-                        bf[pos:] = 0
                 scores = scorer.score_rung(
                     bi, bv, bf if any_fields else None, b
                 )
                 self._slots += b
-            self._filled += total
-            self._g_fill.set(round(self.batch_fill, 6))
-            self._c_batches.add()
-            self._c_examples.add(total)
-            now = time.perf_counter()
-            pos = 0
-            for g in group:
-                g.scores = np.asarray(scores[pos:pos + g.n], np.float32)
-                pos += g.n
-                self._t_latency.observe(now - g.t0)
-                if self._slo is not None:
-                    self._slo.observe(True, now - g.t0)
-                if g.rid is not None:
-                    self._trace_request(g, t_d0, now, rung, total)
-                with self._out_lock:
-                    self._outstanding.discard(g)
-                    self._g_inflight.set(len(self._outstanding))
-                g.event.set()
+            with obs.Phase(self._t_deliver, "tffm:serve.deliver",
+                           reqs=len(group)) as ph:
+                now = ph.t0
+                self._filled += total
+                self._g_fill.set(round(self.batch_fill, 6))
+                self._c_batches.add()
+                self._c_examples.add(total)
+                pos = 0
+                for g in group:
+                    g.scores = np.asarray(
+                        scores[pos:pos + g.n], np.float32
+                    )
+                    pos += g.n
+                    self._t_latency.observe(now - g.t0)
+                    if self._slo is not None:
+                        self._slo.observe(True, now - g.t0)
+                    if g.rid is not None:
+                        self._trace_request(
+                            g, t_closed, now, rung, total,
+                            self._t_launch.total_s - launch_s,
+                            self._t_readback.total_s - readback_s,
+                        )
+                    with self._out_lock:
+                        self._outstanding.discard(g)
+                        self._g_inflight.set(len(self._outstanding))
+                    g.event.set()
             if self._quality is not None:
                 # Skew sketching AFTER every waiter is released: the
                 # request's own (unpadded) arrays and its served
@@ -357,20 +402,22 @@ class ServeBatcher:
                     # round-trips would add straight to the next
                     # group's queueing latency under many-small-
                     # request traffic.
-                    if len(group) == 1:
-                        g = group[0]
-                        self._quality.observe_batch(g.ids, g.vals)
-                        self._quality.observe_scores(g.scores)
-                    else:
-                        self._quality.observe_batch(
-                            np.concatenate([g.ids for g in group]),
-                            np.concatenate([g.vals for g in group]),
-                        )
-                        self._quality.observe_scores(
-                            np.concatenate(
-                                [g.scores for g in group]
+                    with obs.Phase(self._t_quality, "tffm:serve.quality",
+                                   n=total):
+                        if len(group) == 1:
+                            g = group[0]
+                            self._quality.observe_batch(g.ids, g.vals)
+                            self._quality.observe_scores(g.scores)
+                        else:
+                            self._quality.observe_batch(
+                                np.concatenate([g.ids for g in group]),
+                                np.concatenate([g.vals for g in group]),
                             )
-                        )
+                            self._quality.observe_scores(
+                                np.concatenate(
+                                    [g.scores for g in group]
+                                )
+                            )
                 except Exception as e:  # noqa: BLE001 - observe only
                     log.warning("skew sketching failed: %s", e)
             # Last reader done (microbatch copy + quality fold both

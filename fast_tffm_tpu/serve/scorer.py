@@ -114,6 +114,15 @@ class _LadderScorer:
         self._tel = tel
         self._t_compile = tel.timer("serve.compile")
         self._t_dispatch = tel.timer("serve.dispatch")
+        # The two halves of a dispatch, each also a tffm:serve.<phase>
+        # annotation (obs.Phase): the rung call (implicit H2D of the
+        # numpy arguments + enqueue) and the blocking read of its scores.
+        self._t_launch = tel.timer("serve.launch")
+        self._t_readback = tel.timer("serve.readback")
+        # Wait for the dispatch lock: a timer and never an annotation —
+        # a span over a wait on another thread would cover whole idle
+        # gaps and hide what the working thread was doing.
+        self._t_lock_wait = tel.timer("serve.lock_wait")
         self._c_unexpected = tel.counter("serve.recompiles_unexpected")
         self._c_swaps = tel.counter("serve.swaps")
         self._writer = writer
@@ -276,6 +285,16 @@ class _LadderScorer:
 
     # -- scoring -------------------------------------------------------
 
+    def _launch_and_read(self, fn, args, b: int) -> np.ndarray:
+        """Call the compiled rung and read its scores back, as the
+        ``launch`` and ``readback`` phases."""
+        with obs.Phase(self._t_launch, "tffm:serve.launch", rung=b):
+            out = fn(*args)
+        # The blocking host read is part of the dispatch: the score
+        # goes back to a client, so D2H latency is request latency.
+        with obs.Phase(self._t_readback, "tffm:serve.readback", rung=b):
+            return np.asarray(out)
+
     def score(self, ids: np.ndarray, vals: np.ndarray,
               fields: Optional[np.ndarray] = None) -> np.ndarray:
         """Scores for ``n`` examples (``[n, max_features]`` arrays), any
@@ -285,7 +304,9 @@ class _LadderScorer:
         n = len(ids)
         out = np.empty((n,), np.float32)
         pos = 0
+        t_ask = time.perf_counter()
         with self._lock:
+            self._t_lock_wait.observe(time.perf_counter() - t_ask)
             while pos < n:
                 c = min(n - pos, self.max_rung)
                 b = self.rung_for(c)
@@ -311,7 +332,9 @@ class _LadderScorer:
                    fields: Optional[np.ndarray], b: int) -> np.ndarray:
         """One dispatch of exactly-rung-shaped arrays (the batcher's
         entry: it fills the pooled buffers itself)."""
+        t_ask = time.perf_counter()
         with self._lock:
+            self._t_lock_wait.observe(time.perf_counter() - t_ask)
             if fields is None:
                 fields = self._pool(b)[2]
                 if self._n_args == 3:
@@ -623,7 +646,7 @@ class FixedShapeScorer(_LadderScorer):
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self._params
         )
         t0 = time.perf_counter()
-        with _quiet_donation():
+        with _quiet_donation(), obs.trace_span("tffm:serve.compile", rung=b):
             fn = self._jit.lower(p_struct, *structs).compile()
         self._account_compile(
             time.perf_counter() - t0, b, expected=b in self._ladder_set
@@ -639,13 +662,8 @@ class FixedShapeScorer(_LadderScorer):
             fn = self._compiled(b)
             with self._swap_lock:
                 params = self._params
-            if self._n_args == 3:
-                out = fn(params, ids, vals, fields)
-            else:
-                out = fn(params, ids, vals)
-            # The blocking host read is part of the dispatch: the score
-            # goes back to a client, so D2H latency is request latency.
-            return np.asarray(out)
+            args = (params, ids, vals, fields)[:1 + self._n_args]
+            return self._launch_and_read(fn, args, b)
 
 
 class OverlayScorer(_LadderScorer):
@@ -734,7 +752,7 @@ class OverlayScorer(_LadderScorer):
             for dt in self._arg_dtypes[:self._n_args]
         )
         t0 = time.perf_counter()
-        with _quiet_donation():
+        with _quiet_donation(), obs.trace_span("tffm:serve.compile", rung=b):
             fn = self._jit.lower(*structs).compile()
         # Bucketed compact-table shapes are all expected: the row
         # ladder is log-sized by construction, the rung ladder by
@@ -764,11 +782,8 @@ class OverlayScorer(_LadderScorer):
             mini[:len(u)] = store.gather(u)
             local_ids = inv.astype(np.int32).reshape(ids.shape)
             fn = self._compiled(b, rows)
-            if self._n_args == 3:
-                out = fn(w0, mini, local_ids, vals, fields)
-            else:
-                out = fn(w0, mini, local_ids, vals)
-            return np.asarray(out)
+            args = (w0, mini, local_ids, vals, fields)[:2 + self._n_args]
+            return self._launch_and_read(fn, args, b)
 
 
 # ----------------------------------------------------------------------

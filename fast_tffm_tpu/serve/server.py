@@ -216,6 +216,11 @@ class ServeServer:
         # serve_bin_p50_ms).
         parse_t = tel.timer("serve.parse")
         parse_bin_t = tel.timer("serve.parse_bin")
+        # The HTTP worker's two other phases (obs.Phase: a timer and a
+        # tffm:serve.<phase> annotation over the same block).  Its wait
+        # in batcher.score() is neither: it is a wait on another thread.
+        read_body_t = tel.timer("serve.read_body")
+        respond_t = tel.timer("serve.respond")
         # Recycled per-request parse scratch (textparse.py): the text
         # path's arrays come from here and go back via the batcher's
         # on_done hook — steady-state text scoring allocates near-zero
@@ -230,12 +235,13 @@ class ServeServer:
         server = self
 
         def score_arrays(handler, ids, vals, fields, n, truncated,
-                         encode, rid=None, on_done=None) -> None:
+                         encode, parse, rid=None, on_done=None) -> None:
             """Shared tail of both transports: count integrity events,
             batch-score, encode the response.  ``rid`` (a sampled or
             client-supplied request id) is echoed in the response's
-            ``X-Request-Id`` header and closes the request's span
-            chain with a ``serve.respond`` span.  ``on_done`` is the
+            ``X-Request-Id`` header; its span chain opens with
+            ``serve.parse`` (from ``parse``, the finished parse phase)
+            and closes with ``serve.respond``.  ``on_done`` is the
             pooled-scratch release hook: from here on the BATCHER owns
             firing it (exactly once, when its dispatcher stops reading
             the arrays — a client-side timeout must NOT release a
@@ -246,6 +252,11 @@ class ServeServer:
                 # truncated example scores as a different example.
                 truncated_c.add(truncated)
             rid_hdr = {"X-Request-Id": rid} if rid is not None else None
+            if rid is not None:
+                tracer.emit(
+                    "serve.parse", parse.t0, parse.seconds,
+                    args={"rid": rid, "n": n},
+                )
             if n == 0:
                 if on_done is not None:
                     on_done()
@@ -291,16 +302,15 @@ class ServeServer:
                 return
             if cap_req is not None:
                 capture.write(cap_req, encode_bin_response(scores))
-            t_r0 = time.perf_counter()
-            ctype, body = encode(scores)
-            handler._send(200, body, ctype, headers=rid_hdr)
+            with obs.Phase(respond_t, "tffm:serve.respond", n=n) as ph:
+                ctype, body = encode(scores)
+                handler._send(200, body, ctype, headers=rid_hdr)
             if rid is not None:
                 # Chain tail: scores -> encoded -> written back to the
                 # client; the flow end ("f") binds the arrow from the
                 # dispatch step to this span.
                 tracer.emit(
-                    "serve.respond", t_r0,
-                    time.perf_counter() - t_r0,
+                    "serve.respond", ph.t0, ph.seconds,
                     args={"rid": rid, "n": n}, flow=("f", rid),
                 )
 
@@ -334,9 +344,12 @@ class ServeServer:
                         "text/plain",
                     )
                     return
-                body = self._read_body(_MAX_BODY_BYTES)
-                if body is None:
-                    return  # error response already sent
+                with obs.Phase(read_body_t,
+                               "tffm:serve.read_body") as ph:
+                    body = self._read_body(_MAX_BODY_BYTES)
+                    if body is None:
+                        return  # error response already sent
+                    ph.set(bytes=len(body))
                 # Request id: the X-Request-Id header (either
                 # transport), overridden by the binary frame's own
                 # trailer (the router stamps SAMPLED frames there).
@@ -347,26 +360,31 @@ class ServeServer:
                 if rid is not None and not wire.valid_request_id(rid):
                     rid = None
                 on_done = None
+                text = path == "/score"
                 try:
-                    if path == "/score":
-                        with parse_t.time():
-                            parsed = parse_request(
+                    # One phase, two timers: serve.parse times the text
+                    # parse and serve.parse_bin the frame decode.
+                    with obs.Phase(parse_t if text else parse_bin_t,
+                                   "tffm:serve.parse",
+                                   text=int(text)) as parse:
+                        if text:
+                            ids, vals, fields, n, truncated = parse_request(
                                 body.decode(), cfg, pool=parse_pool
                             )
-                        ids, vals, fields, n, truncated = parsed
-                        on_done = lambda i=ids: parse_pool.release(i)  # noqa: E731
-                    else:
-                        with parse_bin_t.time():
+                        else:
                             (ids, vals, fields, n, truncated,
                              frame_rid) = decode_bin_request(body, cfg)
+                        parse.set(n=n)
+                    if text:
+                        on_done = lambda i=ids: parse_pool.release(i)  # noqa: E731
+                    elif frame_rid is not None and \
+                            wire.valid_request_id(frame_rid):
                         # Same sanitization as the header path: the
                         # rid echoes into a response HEADER, so a
                         # trailer smuggling CR/LF (or non-latin-1
                         # bytes send_header can't write) must be
                         # dropped, never reflected.
-                        if frame_rid is not None and \
-                                wire.valid_request_id(frame_rid):
-                            rid = frame_rid
+                        rid = frame_rid
                 except (ValueError, UnicodeDecodeError) as e:
                     self._send(
                         400, f"bad request: {e}\n".encode(), "text/plain"
@@ -380,7 +398,7 @@ class ServeServer:
                     rid = sampler.mint()
                 score_arrays(
                     self, ids, vals, fields, n, truncated,
-                    encode_text if path == "/score" else encode_bin,
+                    encode_text if text else encode_bin, parse,
                     rid=rid, on_done=on_done,
                 )
 

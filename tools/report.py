@@ -750,15 +750,15 @@ def _chain_segments(chain: dict) -> dict:
 
 # Serve-path request chain: sequential segments (the critical path a
 # request walks) in order, plus the router spans that wrap them.
-_SERVE_SEGMENTS = ("admit", "queue_wait", "coalesce", "dispatch",
-                   "respond")
+_SERVE_SEGMENTS = ("admit", "parse", "queue_wait", "coalesce",
+                   "dispatch", "respond")
 
 
 def serve_request_chains(events: list) -> list:
     """Reconstruct per-request span chains from serving traces.
 
     Join key: the ``rid`` arg every serve-path span carries
-    (``serve.admit`` / ``serve.proxy`` on the router,
+    (``serve.admit`` / ``serve.proxy`` on the router, ``serve.parse`` /
     ``serve.queue_wait`` / ``serve.coalesce`` / ``serve.dispatch`` /
     ``serve.respond`` on the replica).  Unlike super-batch chains, rid
     uniqueness is fleet-global (pid + boot time + counter), so chains
