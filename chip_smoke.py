@@ -14,7 +14,10 @@ script exits nonzero — nothing is caught and carried past):
             stack+H2D ship did the work
 3. kernels  the trainer's step with the default config vs the XLA oracle
             (``interaction=jnp``, ``sparse_apply=scatter``) on the same
-            batches, and ``compute_dtype=bfloat16`` vs f32
+            batches, ``compute_dtype=bfloat16`` vs f32, and one apply of
+            the scatter path (sort, K1 sums, unique-row scatter; Adagrad
+            and FTRL) and of the tile kernels vs the per-occurrence sums
+            in float64 on the host
 4. predict  ``cli.main(["predict", cfg])``
 5. serve    ``serve(cfg, port=0)``; text and binary requests over the socket
             must match the predict scores; zero steady-state compiles
@@ -63,13 +66,23 @@ PATH_FIELDS = frozenset({
 F32_TOL = {"rtol": 1e-4, "atol": 1e-5}
 # Optimizer accumulators get their own relative tolerance.  At Zipf skew
 # the hottest id has ~15k occurrences in one batch, each adding g^2 ~ 1e-8
-# to an accumulator that starts at 0.1 (one f32 ulp there is 7.5e-9).  The
-# XLA oracle scatter-adds them into the accumulator one by one and loses
-# low bits every time (numpy: -6e-6 per step for 15k such adds); the
-# kernels pre-sum the duplicates (K1) and add once.  First chip run: max
-# abs 3.2e-5 on values <= 0.15 after 4 steps, with scores and tables
-# inside F32_TOL at a quarter of it.
+# to an accumulator that starts at 0.1 (one f32 ulp there is 7.5e-9).  A
+# per-occurrence scatter-add (the XLA oracle until PR 27) loses low bits
+# every time (numpy: -6e-6 per step for 15k such adds); both paths now
+# pre-sum the duplicates (K1) and add once, the oracle through
+# scatter_apply_unique.  First chip run, against the per-occurrence
+# oracle: max abs 3.2e-5 on values <= 0.15 after 4 steps, with scores and
+# tables inside F32_TOL at a quarter of it.
 ACC_TOL = {"rtol": 5e-4, "atol": 1e-5}
+# One apply against the per-occurrence formula in float64 on the host,
+# absolute, on weights <= 0.1, FTRL z <= 1 and accumulators <= ~2.  The
+# single-device scatter apply (sort, three-pass K1 sums, unique-row
+# scatter; Adagrad and FTRL) keeps float32: first chip readings 2e-7
+# (accumulators), 5e-8 (weights).  The tile kernels' one-hot matmuls
+# keep 16 bits of a value: 2^-17 of an accumulator of 2 is 1.5e-5, and
+# the hottest row (15k occurrences) read 7.5e-6, the weights 1.1e-6.
+SCATTER_ATOL = 3e-6
+TILE_ATOL = 2e-5
 # bf16 compute rounds the interaction operands to 8 mantissa bits;
 # the repo's own bf16 tests (tests/test_bf16.py) hold this tolerance.
 BF16_TOL = {"rtol": 0.05, "atol": 0.02}
@@ -385,7 +398,95 @@ def _passed(cmp: dict) -> bool:
     return all(v["worst_over_allowed"] <= 1.0 for v in cmp.values())
 
 
-def phase_kernels(out: Out, cfg, size: Size, work: str):
+def _applies_vs_host(cfg, seed: int) -> dict:
+    """One optimizer apply at the cfg's own V, B x F and k (Zipf ids, so
+    the hottest row has thousands of occurrences) against every
+    occurrence added singly in float64 ON THE HOST — the one side of
+    this phase that shares no sort, payload or K1 with the program:
+
+    * ``unique_adagrad`` / ``unique_ftrl``: the single-device scatter
+      apply (``scatter_apply_unique``), its additive and its
+      gather-update-set form;
+    * ``tile_adagrad``: the tile kernels (K1 + K2), whose step-level
+      oracle (``sparse_apply=scatter``) runs the same prep since PR 27.
+    """
+    from functools import partial
+
+    import jax
+
+    from fast_tffm_tpu.data import synth
+    from fast_tffm_tpu.ops import sparse_apply
+    from fast_tffm_tpu.train import sparse as sparse_lib
+
+    v, d = cfg.vocabulary_size, 1 + cfg.factor_num
+    n = cfg.batch_size * cfg.max_features
+    lr, eps = cfg.learning_rate, sparse_lib.ADAGRAD_EPS
+    l1, l2, beta = cfg.ftrl_l1, cfg.ftrl_l2, cfg.ftrl_beta
+    rng = np.random.default_rng(seed)
+    ids = synth.zipf_ids(rng, (n,), v).astype(np.int32)
+    g = (rng.normal(size=(n, d)) * 1e-2).astype(np.float32)
+    table = rng.uniform(-0.1, 0.1, size=(v, d)).astype(np.float32)
+    acc = np.full((v, d), cfg.adagrad_initial_accumulator, np.float32)
+    z = rng.uniform(-1.0, 1.0, size=(v, d)).astype(np.float32)
+    uniq, inv = np.unique(ids, return_inverse=True)
+    g1 = np.zeros((len(uniq), d))
+    g2 = np.zeros((len(uniq), d))
+    g64 = g.astype(np.float64)
+    np.add.at(g1, inv, g64)
+    np.add.at(g2, inv, g64 * g64)
+    rest = np.ones(v, bool)
+    rest[uniq] = False
+
+    acc_ref = acc[uniq] + g2
+    adagrad_ref = [table[uniq] - lr * g1 / np.sqrt(acc_ref + eps), acc_ref]
+    z_ref = z[uniq] + g1 - (
+        np.sqrt(acc_ref) - np.sqrt(acc[uniq])) / lr * table[uniq]
+    ftrl_ref = [
+        np.where(np.abs(z_ref) <= l1, 0.0,
+                 -(z_ref - np.sign(z_ref) * l1)
+                 / ((beta + np.sqrt(acc_ref)) / lr + l2)),
+        z_ref, acc_ref,
+    ]
+
+    def unique(update, additive):
+        def apply(i, gr, *tabs):
+            return sparse_apply.scatter_apply_unique(
+                update, tabs, i, gr, additive=additive)
+        return apply
+
+    def tile(i, gr, t, a):
+        return sparse_apply.adagrad_apply(t, a, i, gr, lr=lr, eps=eps), None
+
+    cases = {
+        "unique_adagrad": (unique(partial(
+            sparse_apply.adagrad_update, lr=lr, eps=eps), True),
+            [table, acc], adagrad_ref),
+        "unique_ftrl": (unique(partial(
+            sparse_apply.ftrl_update, lr=lr, l1=l1, l2=l2, beta=beta),
+            False), [table, z, acc], ftrl_ref),
+    }
+    if sparse_apply.supports_tile(v, "adagrad"):
+        cases["tile_adagrad"] = (tile, [table, acc], adagrad_ref)
+    out = {
+        "occurrences": n, "unique_rows": int(len(uniq)),
+        "hottest_row_occurrences": int(np.bincount(inv).max()),
+    }
+    for name, (apply, before, want) in cases.items():
+        after, count = jax.jit(apply)(ids, g, *before)
+        after = [np.asarray(x) for x in after]
+        out[name] = {
+            "atol": TILE_ATOL if name == "tile_adagrad" else SCATTER_ATOL,
+            "rows_written": None if count is None else int(count),
+            "max_abs": [float(np.abs(x[uniq] - w).max())
+                        for x, w in zip(after, want)],
+            "untouched_rows_changed": int(sum(
+                np.any(x[rest] != b[rest], axis=1).sum()
+                for x, b in zip(after, before))),
+        }
+    return out
+
+
+def phase_kernels(out: Out, cfg, size: Size, work: str, seed: int):
     import jax
 
     from fast_tffm_tpu import platform
@@ -409,11 +510,13 @@ def phase_kernels(out: Out, cfg, size: Size, work: str):
     )
     f32 = _compare(default, oracle, F32_TOL, ACC_TOL)
     b16 = _compare(bf16, default, BF16_TOL, BF16_TOL)
+    applies = _applies_vs_host(base, seed)
     acc_max = max(float(x.max()) for x in jax.tree.leaves(default["opt"]))
     out.emit({
         "phase": "kernels", "steps": k,
         "f32_tol": F32_TOL, "acc_tol": ACC_TOL, "default_vs_oracle": f32,
         "bf16_tol": BF16_TOL, "bf16_vs_f32": b16,
+        "applies_vs_host": applies,
         "compile_s": {"default": default["compile_s"],
                       "oracle": oracle["compile_s"],
                       "bf16": bf16["compile_s"]},
@@ -429,6 +532,16 @@ def phase_kernels(out: Out, cfg, size: Size, work: str):
     })
     require(_passed(f32), f"kernels differ from the XLA oracle: {f32}")
     require(_passed(b16), f"bf16 compute differs from f32: {b16}")
+    for name in ("unique_adagrad", "unique_ftrl", "tile_adagrad"):
+        got = applies.get(name)  # no tile case at a vocabulary it refuses
+        require(
+            got is None or (
+                got["rows_written"] in (None, applies["unique_rows"])
+                and got["untouched_rows_changed"] == 0
+                and max(got["max_abs"]) <= got["atol"]),
+            f"the {name} apply differs from the host's per-occurrence "
+            f"sums: {got} of {applies}",
+        )
     # A comparison of two untrained tables would pass vacuously.
     require(acc_max > cfg.adagrad_initial_accumulator,
             "the optimizer state did not move over the steps")
@@ -617,7 +730,7 @@ def run(chips: int = 1, rehearse: bool = False, seed: int = 0,
             phase_sharded(out, cfg, size, work)
         else:
             phase_train(out, cfg_path, cfg, size, rehearse)
-            phase_kernels(out, cfg, size, work)
+            phase_kernels(out, cfg, size, work, seed)
             predicted = phase_predict(out, cfg_path, cfg, size)
             phase_serve(out, cfg, predicted)
         stats = platform.compile_cache_stats()

@@ -1,14 +1,27 @@
-"""Tile-scan sparse optimizer apply — Pallas TPU replacement for row scatter.
+"""Sparse optimizer apply on the TPU: sort + dedup, then tile kernels or
+a unique-row scatter.
 
 The reference applies sparse updates with TF's SparseApplyAdagrad/-Ftrl over
 ``IndexedSlices`` (SURVEY.md §2 #8, §3.2): per step it updates only the rows
-the batch touched.  The direct XLA translation (``table.at[ids].add``) is
-correct but slow on TPU: a scatter of N≈640k rows costs ~73ms on v5e — the
-scatter unit processes rows serially — and sparse Adagrad needs *three* such
-passes (acc scatter-add, acc re-gather, table scatter).
+the batch touched.  The direct XLA translation (``table.at[ids].add`` per
+occurrence) is correct but slow on TPU: the scatter writes rows one after the
+other, ~0.1 us each on v5e whatever the data (640k occurrences: 73 ms;
+2.56 M: 262 ms), and sparse Adagrad needed two such scatters and a re-gather
+of the accumulator, each over every occurrence.
 
-This module replaces all of it with a sort + two Pallas kernels, turning the
-random-access scatter into sequential streams and MXU matmuls:
+Both replacements here start from the same prep (``_prep``: sort the
+occurrence ids, K1 sums ``g`` and ``g^2`` per unique row):
+
+* ``scatter_apply_unique`` — the ``scatter`` apply mode on one device.
+  Gathers, updates and scatters only the UNIQUE rows (sorted, unique
+  indices) into the un-padded ``[V, D]`` tables, in chunks, as many as the
+  batch's unique rows need.  Works at any V the chip holds.
+* the tile kernels — the ``tile`` / ``sharded`` apply modes, which need
+  a TILE-aligned vocabulary and today a table small enough to be copied
+  into the kernels' layout (PERF.md §4).
+
+The tile path replaces the scatter with a sort + two Pallas kernels, turning
+the random-access scatter into sequential streams and MXU matmuls:
 
 1. XLA prep: sort occurrence ids (carrying a permutation), mark segment
    starts, prefix-sum to get each occurrence's *unique-row position* (upos).
@@ -27,8 +40,14 @@ random-access scatter into sequential streams and MXU matmuls:
 Per step this costs one pass over the table (streaming) plus the MXU
 placement matmuls, independent of duplicate structure — measured 2.3x
 faster than the XLA scatter path on real v5e at Criteo shapes (V=2^22,
-B=16k, F=39; TPU_RESULTS.md) and exact to ~1e-6 relative (one-hot
-matmuls run as two-pass bf16 hi/lo splits, keeping ~f32 precision).
+B=16k, F=39; TPU_RESULTS.md, against the per-occurrence scatter).  The
+one-hot matmuls run as two-pass bf16 hi/lo splits: a product keeps 16
+bits (a value that occurs once is off by up to 2^-17 of itself; sums of
+many occurrences average it out).  The unique-row scatter asks K1 for
+three passes, which keep all 24: its cell is stated as float32, and its
+tile-index column must come back exact at any vocabulary (two passes
+carry 17 bits of an integer: vocab <= 2^25 at TILE = 256; PERF.md §6,
+PR 27).
 
 Semantics match train.sparse exactly: per-occurrence g² accumulation,
 shared post-update denominator for duplicates (Adagrad), single -sigma*w
@@ -134,7 +153,8 @@ def supports_tile(vocab: int, optimizer: str) -> bool:
 
 
 def _k1_kernel(starts_ref, firsts_ref, ends_ref, payload_ref, upos_ref,
-               out_ref, u_vmem, carry_ref, sem, *, chunk, group, lanes):
+               out_ref, u_vmem, carry_ref, sem, *, chunk, group, lanes,
+               passes):
     t = pl.program_id(0)
     prev_cp = None  # the single in-flight output copy
     for j in range(group):  # unrolled: all slices static
@@ -151,15 +171,17 @@ def _k1_kernel(starts_ref, firsts_ref, ends_ref, payload_ref, upos_ref,
         oh = (
             jnp.broadcast_to(l, (chunk, chunk)) == s_iota
         ).astype(jnp.bfloat16)
-        # Segment-sum on the MXU.  f32 payload exactness via bf16 hi/lo
-        # split: hi rounds to bf16, lo carries the residual; both
-        # accumulate in f32.
-        p_hi = payload.astype(jnp.bfloat16)
-        p_lo = (payload - p_hi.astype(jnp.float32)).astype(jnp.bfloat16)
-        u_local = (
-            jax.lax.dot(oh, p_hi, preferred_element_type=jnp.float32)
-            + jax.lax.dot(oh, p_lo, preferred_element_type=jnp.float32)
-        )  # [C, L]
+        # Segment-sum on the MXU.  The f32 payload goes through as
+        # ``passes`` bf16 terms, each rounding what the ones before left
+        # over, all accumulated in f32: two keep 16 bits of a value
+        # (17 of an integer), three all 24, so that a row that occurs
+        # once comes out bit for bit.
+        u_local, rest = None, payload
+        for _ in range(passes):
+            term = rest.astype(jnp.bfloat16)
+            rest = rest - term.astype(jnp.float32)
+            part = jax.lax.dot(oh, term, preferred_element_type=jnp.float32)
+            u_local = part if u_local is None else u_local + part  # [C, L]
         # Segment spanning in from the previous chunk: add its partial
         # sums to row 0 via an iota mask — `.at[0:1].add` would emit a
         # scatter-add HLO, which Mosaic has no TPU lowering for (it
@@ -209,7 +231,7 @@ def _k1_kernel(starts_ref, firsts_ref, ends_ref, payload_ref, upos_ref,
     prev_cp.wait()
 
 
-def _k1_dedup(payload, upos, starts, firsts, ends, n_out):
+def _k1_dedup(payload, upos, starts, firsts, ends, n_out, passes=2):
     n, lanes = payload.shape
     chunk = CHUNK
     group = _group_for(n // chunk, K1_GROUP)
@@ -230,7 +252,8 @@ def _k1_dedup(payload, upos, starts, firsts, ends, n_out):
     )
     return pl.pallas_call(
         functools.partial(
-            _k1_kernel, chunk=chunk, group=group, lanes=lanes
+            _k1_kernel, chunk=chunk, group=group, lanes=lanes,
+            passes=passes,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_out, lanes), jnp.float32),
@@ -573,7 +596,8 @@ def entries_cap(n_occurrences: int, vocab: int) -> int:
     return min(n_pad, -(-vocab // CHUNK) * CHUNK)
 
 
-def unique_entries(ids, g_rows, *, vocab, cap):
+def unique_entries(ids, g_rows, *, vocab, cap, segment_sums=_k1_dedup,
+                   pad_first=False):
     """Deduped touched-row entry stream: (rows [cap] i32, pay [cap, 2D]
     f32, count).
 
@@ -581,16 +605,19 @@ def unique_entries(ids, g_rows, *, vocab, cap):
     (SURVEY.md §3.2): instead of a dense [vocab, 2D] delta, emit only
     the rows the batch touched — sorted, deduped (sum g / sum g² per
     row), sentinel-padded (row == vocab, zero payload) to the static
-    ``cap``.  Rows are recovered exactly from the K1 stream's
-    lrow/tidx metadata columns (integer-valued f32, exact — see _prep).
+    ``cap``.  Rows are recovered from the K1 stream's lrow/tidx
+    metadata columns (integer-valued f32 — see _prep; exact through
+    K1's default two passes while vocab / TILE <= 2^17, through three at
+    any vocabulary).  ``segment_sums`` stands in for K1 (same signature
+    as _k1_dedup); ``pad_first`` as in _prep.
     """
     d = g_rows.shape[1]
     payload, upos, starts, firsts, ends, sidx, n_pad = _prep(
-        ids, g_rows, vocab
+        ids, g_rows, vocab, pad_first
     )
     if cap > n_pad:
         raise ValueError(f"cap={cap} exceeds padded occurrences {n_pad}")
-    u = _k1_dedup(payload, upos, starts, firsts, ends, n_pad + TILE)
+    u = segment_sums(payload, upos, starts, firsts, ends, n_pad + TILE)
     count = _tile_starts(
         sidx, upos, jnp.full((1,), vocab, sidx.dtype)
     )[0]  # uniques among real (non-sentinel) rows
@@ -600,6 +627,116 @@ def unique_entries(ids, g_rows, *, vocab, cap):
     rows = jnp.where(valid, tidx * TILE + lrow, vocab)
     pay = jnp.where(valid[:, None], u[:cap, :2 * d], 0.0)
     return rows, pay, count
+
+
+# ----------------------------------------- unique-row scatter (one device)
+
+# Entries per trip of scatter_apply_unique's loop.  A trip's fixed cost
+# does not show on v5e (2,048 to 32,768 entries a trip read within 2%
+# of each other), but an out-of-range row costs what a real one does, so
+# the last trip's padding is paid in full: 65,536 read 16 ms slower than
+# 4,096 at 806k unique rows (PERF.md §6, PR 27).
+SCATTER_CHUNK = 4096
+
+
+# bf16 terms K1 splits a float32 into for the unique-row scatter: three
+# carry all 24 bits, so single-occurrence rows come out bit for bit and
+# the tile-index column is exact for any int32 vocabulary.  Two (K1's
+# default, what the tile path runs) lose the low 8 bits of a product and
+# recover wrong rows once vocab / TILE passes 2^17 (tested).
+_EXACT_PASSES = 3
+
+
+def _xla_segment_sums(payload, upos, starts, firsts, ends, n_out):
+    """K1's stand-in where Pallas kernels run interpreted (off the
+    TPU): the same per-segment sums of the sorted payload by XLA's
+    sorted segment_sum (plain f32 adds).  An interpreted kernel is a
+    correctness tool, orders of magnitude slower; on the TPU this
+    scatter-add costs 311 ms where K1 costs 7.5 (two passes) or 9.5
+    (three; n = 2.56 M; PERF.md §6, PR 27)."""
+    del starts, firsts, ends  # K1's chunk-boundary scalars
+    return jax.ops.segment_sum(
+        payload, upos, num_segments=n_out, indices_are_sorted=True
+    )
+
+
+def scatter_apply_unique(update, tables, ids, g_rows, *, additive=False):
+    """XLA row-scatter optimizer apply that writes each touched row ONCE.
+
+    The per-occurrence scatter costs ~0.1 us a row on v5e whatever the
+    data (PERF.md §5), and hashed CTR batches repeat ids heavily (69% of
+    the occurrences of a Zipf(1.1) Criteo batch are duplicates).  So:
+    sort + segment-sum the occurrences into the unique stream
+    (:func:`unique_entries`: ``sum g`` and the per-occurrence ``sum g²``
+    per row), then gather -> ``update`` -> scatter only the unique rows,
+    with sorted, unique indices.  ``update(g1, g2, *table_rows) ->
+    new_table_rows`` is one of the shared elementwise formulas
+    (adagrad_update / ftrl_update / sgd_update), exactly as K2 takes it.
+
+    ``additive``: ``update``'s first output is ``tables[0]`` minus a
+    term that does not read it (Adagrad, SGD).  The weights are then not
+    gathered: ``update`` sees zeros in their place and what it returns
+    is scatter-ADDed, the same float32 subtraction done by the scatter.
+
+    The stream has the static length ``cap`` but only ``count`` real
+    entries; its tail is padded with DISTINCT out-of-range rows
+    (``vocab, vocab+1, ...`` — so ``unique_indices`` stays true) that
+    ``mode="drop"`` never writes.  A dropped row costs what a written
+    one does, so the apply walks the stream in SCATTER_CHUNK pieces and
+    stops after ``ceil(count / chunk)`` of them: its cost follows the
+    batch's unique rows, not ``cap``.  Against the per-occurrence
+    apply at n = 2.56 M on v5e: 245 against 584 ms at 0.31 unique rows
+    an occurrence, even at ~0.88, and 660 against 583 ms where no id
+    repeats (the sort, payload and K1 come on top of as many writes) —
+    accepted: hashed CTR batches sit far below, and the caller's
+    ``count`` (gauge ``train.apply_unique_frac``) shows where a run is.
+
+    Single-device only (a global sort under GSPMD would all-gather the
+    batch, and the partitioner cannot split the Mosaic call).  Returns
+    ``(new_tables, count)``.
+    """
+    vocab, d = tables[0].shape
+    cap = entries_cap(ids.shape[0], vocab)
+    if vocab + cap >= 1 << 31:
+        raise ValueError(
+            f"vocab {vocab} + stream cap {cap} overflows int32 row ids"
+        )
+    rows, pay, count = unique_entries(
+        ids, g_rows, vocab=vocab, cap=cap, pad_first=True,
+        segment_sums=(
+            _xla_segment_sums if _use_interpret()
+            else functools.partial(_k1_dedup, passes=_EXACT_PASSES)
+        ),
+    )
+    chunk = min(SCATTER_CHUNK, cap)
+    pad = -cap % chunk
+    if pad:  # the last trip must not run off the stream
+        rows = jnp.pad(rows, (0, pad))
+        pay = jnp.pad(pay, ((0, pad), (0, 0)))
+    tail = jnp.arange(cap + pad, dtype=jnp.int32) - count
+    rows_u = jnp.where(tail < 0, rows, vocab + tail)
+    index = dict(indices_are_sorted=True, unique_indices=True)
+    writes = ["add" if additive else "set"] + ["set"] * (len(tables) - 1)
+
+    def body(i, tabs):
+        r = jax.lax.dynamic_slice_in_dim(rows_u, i * chunk, chunk)
+        p = jax.lax.dynamic_slice_in_dim(pay, i * chunk, chunk)
+        # A padding row reads a clamped row; what update() makes of it
+        # is dropped by the scatter below.
+        old = [
+            jnp.zeros((chunk, d), t.dtype) if how == "add"
+            else t.at[r].get(mode="clip", **index)
+            for t, how in zip(tabs, writes)
+        ]
+        new = update(p[:, :d], p[:, d:], *old)
+        return tuple(
+            getattr(t.at[r], how)(v, mode="drop", **index)
+            for t, v, how in zip(tabs, new, writes)
+        )
+
+    trips = (count + chunk - 1) // chunk
+    tables = jax.lax.fori_loop(0, trips, body, tuple(tables))
+    return tables, count
 
 
 def merge_entries(rows, pay, *, vocab):
@@ -807,14 +944,36 @@ def _payload(g_sorted, lrow_last, tidx_last=None):
 
     ``tidx_last`` (the occurrence's tile index, · last-in-segment flag)
     is carried only where the deduped stream's global rows must be
-    recoverable afterwards — the entries exchange.  Like lrow, K1's
-    segment sum leaves exactly the value on the unique entry because
-    only the last occurrence contributes.
+    recoverable afterwards — the entries exchange and the unique-row
+    scatter.  Like lrow, K1's segment sum leaves exactly the value on
+    the unique entry because only the last occurrence contributes.
     """
     cols = [g_sorted, g_sorted * g_sorted, lrow_last[:, None]]
     if tidx_last is not None:
         cols.append(tidx_last[:, None])
     return _pad_lanes(jnp.concatenate(cols, axis=1))
+
+
+def _payload_padded_first(g_rows, perm, lrow_last, tidx_last):
+    """``_payload(g_rows[perm], ...)`` bit for bit, in another order:
+    the rows are squared and lane-padded BEFORE they are permuted, and
+    the (already sorted) metadata lanes selected in afterwards.  At the
+    unique-row scatter's n = 2.56 M on v5e a gather of 512-byte rows
+    costs ~10 ns a row where the 9-wide ``g_rows[perm]`` costs 23 ns and
+    the concatenation of the sorted narrow arrays as much again: 45.7
+    against 85.4 ms for the whole prep.  Not so at the tile path's
+    n = 640k, where this order read 1.5 ms slower (53.7 against 52.3 ms
+    an apply), so that path keeps _payload (PERF.md §6, PR 27)."""
+    n, d = g_rows.shape
+    meta = [lrow_last, tidx_last]
+    p = _pad_lanes(jnp.concatenate(
+        [g_rows, g_rows * g_rows, jnp.zeros((n, len(meta)), g_rows.dtype)],
+        axis=1,
+    ))[perm]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, p.shape[1]), 1)
+    for k, col in enumerate(meta):
+        p = jnp.where(lane == 2 * d + k, col[:, None], p)
+    return p
 
 
 def _sorted_stream_meta(sidx):
@@ -831,8 +990,10 @@ def _sorted_stream_meta(sidx):
     return upos, last, starts, firsts, ends
 
 
-def _prep(ids, g_rows, vocab):
-    """Sort, dedup-position, and chunk-boundary metadata (all XLA)."""
+def _prep(ids, g_rows, vocab, pad_first=False):
+    """Sort, dedup-position, and chunk-boundary metadata (all XLA).
+    ``pad_first`` picks _payload_padded_first's order of the same
+    payload."""
     n = ids.shape[0]
     d = g_rows.shape[1]
     n_pad = -(-n // CHUNK) * CHUNK
@@ -846,13 +1007,16 @@ def _prep(ids, g_rows, vocab):
             [g_rows, jnp.zeros((n_pad - n, d), g_rows.dtype)]
         )
     sidx, perm = jax.lax.sort_key_val(ids, jnp.arange(n_pad, dtype=jnp.int32))
-    g_sorted = g_rows[perm]
     upos, last, starts, firsts, ends = _sorted_stream_meta(sidx)
     lrow = (sidx % TILE).astype(jnp.float32)  # tile-local row, exact < TILE
     # Tile index, f32-exact while vocab/TILE < 2^24 (true for any vocab
     # < 2^31 at TILE >= 256 — int32 ids cap vocab below that anyway).
+    # K1 must carry it whole: two bf16 passes hold 17 bits of it.
     tidx = (sidx // TILE).astype(jnp.float32)
-    payload = _payload(g_sorted, lrow * last, tidx * last)
+    if pad_first:
+        payload = _payload_padded_first(g_rows, perm, lrow * last, tidx * last)
+    else:
+        payload = _payload(g_rows[perm], lrow * last, tidx * last)
     return payload, upos, starts, firsts, ends, sidx, n_pad
 
 

@@ -104,6 +104,11 @@ class HealthState(NamedTuple):
     # default x64-disabled mode would silently truncate int64.  Exact to
     # 2^24 events per step-increment, which is plenty for a monitor.
     touch_events: jax.Array  # f32: cumulative real feature occurrences
+    # uint32[2], cumulative and wrapping: rows the deduped scatter apply
+    # wrote, and the occurrences it merged them from (zeros on every
+    # other apply path).  The host reads the difference of two
+    # dispatches, which the wrap leaves exact.
+    apply_rows: jax.Array
     rows_touched: jax.Array  # bool[vocab]: rows ever touched this run
 
     @staticmethod
@@ -114,6 +119,7 @@ class HealthState(NamedTuple):
             nonfinite_steps=jnp.zeros((), jnp.int32),
             first_nonfinite_step=jnp.full((), -1, jnp.int32),
             touch_events=jnp.zeros((), jnp.float32),
+            apply_rows=jnp.zeros((2,), jnp.uint32),
             rows_touched=jnp.zeros((vocab,), jnp.bool_),
         )
 
@@ -243,7 +249,7 @@ def make_health_update(cfg: FmConfig):
 
     def update(health: HealthState, new_state: TrainState, batch: Batch,
                aux) -> HealthState:
-        grad_sq, nonfinite = aux
+        grad_sq, nonfinite, *applied = aux
         bad = nonfinite > 0
         real = batch.vals.reshape(-1) != 0
         ids = jnp.where(real, batch.ids.reshape(-1), vocab)
@@ -262,6 +268,7 @@ def make_health_update(cfg: FmConfig):
             touch_events=(
                 health.touch_events + jnp.sum(real, dtype=jnp.float32)
             ),
+            apply_rows=health.apply_rows + sum(applied),
             rows_touched=health.rows_touched.at[ids].set(
                 True, mode="drop"
             ),
@@ -1814,17 +1821,27 @@ class Trainer:
             if cfg.quality else None
         )
         self._last_scores = None
-        pending_health = None  # (nonfinite_arr, grad_sq_arr, grad_sq_sum_arr, stepno)
+        # (nonfinite_arr, grad_sq_arr, grad_sq_sum_arr, apply_rows_arr, stepno)
+        pending_health = None
+        apply_rows_seen = np.zeros(2, np.uint32)  # at the last readback
         pending_quality = None  # (scores_arr, labels_arr, weights_arr)
         nonfinite_warned = False
 
         def check_health(pending) -> None:
             """Consume one delayed health readback; apply nan_policy."""
-            nonlocal nonfinite_warned
-            nf_arr, gs_arr, ss_arr, at_step = pending
+            nonlocal nonfinite_warned, apply_rows_seen
+            nf_arr, gs_arr, ss_arr, ap_arr, at_step = pending
             nf = int(nf_arr)
             gs = float(gs_arr)
             ss = float(ss_arr)
+            ap = np.asarray(ap_arr)
+            # this dispatch's own share (uint32 differences wrap back)
+            written, merged = (int(x) for x in ap - apply_rows_seen)
+            apply_rows_seen = ap
+            if merged:  # the deduped scatter apply ran (train.sparse)
+                self.telemetry.gauge("train.apply_unique_frac").set(
+                    round(written / merged, 6)
+                )
             self._health_host["grad_norm"] = round(
                 float(np.sqrt(gs)) if np.isfinite(gs) else gs, 6
             )
@@ -2312,15 +2329,19 @@ class Trainer:
                     nf_arr = self._health.nonfinite_steps
                     gs_arr = self._health.grad_sq_last
                     ss_arr = self._health.grad_sq_sum
+                    ap_arr = self._health.apply_rows
                     try:
                         nf_arr.copy_to_host_async()
                         gs_arr.copy_to_host_async()
                         ss_arr.copy_to_host_async()
+                        ap_arr.copy_to_host_async()
                     except Exception:  # pragma: no cover - backend drift
                         pass
                     if pending_health is not None:
                         check_health(pending_health)
-                    pending_health = (nf_arr, gs_arr, ss_arr, stepno)
+                    pending_health = (
+                        nf_arr, gs_arr, ss_arr, ap_arr, stepno
+                    )
                     # Quality eval feed, same one-dispatch-delayed
                     # discipline: start an async D2H of THIS dispatch's
                     # stacked scores (+ the labels/weights the batch

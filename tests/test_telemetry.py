@@ -382,6 +382,34 @@ class TestTrainerTelemetry:
         assert tm["truncated_features"] == 0
         assert tm["out_of_range_batches"] == 0
 
+    @pytest.mark.parametrize("devices", [1, 8])
+    def test_apply_unique_frac_in_the_final_record(
+        self, devices, train_file, tmp_path
+    ):
+        """One device: the scatter apply dedups first and the gauge
+        reads rows written / occurrences (< 1 with 50 ids under 128
+        occurrences a step).  A multi-device mesh runs the GSPMD
+        per-occurrence scatter and publishes no such gauge."""
+        import jax
+
+        from fast_tffm_tpu.parallel import mesh as mesh_lib
+        from fast_tffm_tpu.train.loop import Trainer
+
+        mf = str(tmp_path / "metrics.jsonl")
+        cfg = _train_cfg(
+            train_file, tmp_path, f"uf{devices}", metrics_file=mf,
+            sparse_apply="scatter",
+        )
+        mesh = mesh_lib.make_mesh(cfg, jax.devices()[:devices])
+        Trainer(cfg, mesh=mesh).train()
+        final = [json.loads(line) for line in open(mf)][-1]
+        assert final["record"] == "final"
+        gauges = final["stages"]["gauges"]
+        if devices == 1:
+            assert 0.0 < gauges["train.apply_unique_frac"] <= 50 / 128
+        else:
+            assert "train.apply_unique_frac" not in gauges
+
     def test_truncation_counter_in_results(self, tmp_path):
         """max_features smaller than the widest line: the drop count
         must surface in train results, not just a log warning."""

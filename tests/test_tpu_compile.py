@@ -152,6 +152,29 @@ def test_sparse_apply_kernels(one_chip, no_persistent_cache, case):
     assert compiled.as_text().count("tpu_custom_call") >= 2
 
 
+@pytest.mark.parametrize("optimizer", ["adagrad", "ftrl"])
+def test_unique_scatter_apply(one_chip, no_persistent_cache, optimizer):
+    """The single-device scatter apply: sort, K1 with the three passes
+    it asks for, and the counted loop of unique-row gathers and scatters
+    (additive under Adagrad, gather-update-set under FTRL)."""
+    tab, ids, g = _s((V_APPLY, D)), _s((N_OCC,), jnp.int32), _s((N_OCC, D))
+    if optimizer == "ftrl":
+        update = functools.partial(
+            sparse_apply.ftrl_update, lr=0.1, l1=0.01, l2=0.01, beta=1.0)
+        tables = (tab, tab, tab)
+    else:
+        update = functools.partial(
+            sparse_apply.adagrad_update, lr=0.1, eps=1e-7)
+        tables = (tab, tab)
+    compiled = compile_for(
+        one_chip,
+        lambda i, gr, *t: sparse_apply.scatter_apply_unique(
+            update, t, i, gr, additive=optimizer == "adagrad"),
+        ids, g, *tables,
+    )
+    assert "while" in compiled.as_text()
+
+
 def test_whole_tile_step_at_criteo_kaggle_shape(topo, no_persistent_cache):
     """The program the trainer really dispatches for
     examples/criteo_kaggle.cfg on one chip: the scan-fused tile step
