@@ -452,6 +452,9 @@ class BatchPipeline:
         self._c_examples = tel.counter("ingest.examples")
         self._c_cache_replays = tel.counter("ingest.cache_replay_batches")
         self._t_parse = tel.timer("ingest.parse")
+        # The drift-sketch fold on the thread-worker path (of it,
+        # StreamSketch times its own lock: ingest.sketch_lock).
+        self._t_sketch = tel.timer("ingest.sketch")
         self._t_reader_block = tel.timer("ingest.reader_block")
         self._t_out_block = tel.timer("ingest.out_block")
         # Prestacked-cache + inbound-ring instruments: how many windows
@@ -1047,9 +1050,12 @@ class BatchPipeline:
                         # surface through the worker's fatal error
                         # path and kill the training it observes.
                         try:
-                            self._quality.update_batch(
-                                batch.ids, batch.vals, batch.weights
-                            )
+                            with obs.Phase(self._t_sketch,
+                                           "tffm:ingest.sketch",
+                                           n=len(batch.labels)):
+                                self._quality.update_batch(
+                                    batch.ids, batch.vals, batch.weights
+                                )
                         except Exception as e:  # noqa: BLE001
                             self._quality = None  # degrade for good
                             log.warning(

@@ -52,7 +52,8 @@ from typing import Optional
 
 import numpy as np
 
-from fast_tffm_tpu.obs.sketch import SketchSet
+from fast_tffm_tpu.obs.sketch import SketchSet, finite_values, fold_batch
+from fast_tffm_tpu.obs.telemetry import NULL
 
 __all__ = ["QualityMonitor", "ServeSkewMonitor", "StreamSketch"]
 
@@ -73,13 +74,16 @@ _BLOCK_MEMO_S = 0.5
 class StreamSketch:
     """Thread-safe windowed + cumulative SketchSet accumulator."""
 
-    def __init__(self, window_examples: int = 65536):
+    def __init__(self, window_examples: int = 65536, telemetry=NULL):
         if window_examples < 1:
             raise ValueError(
                 f"window_examples must be >= 1, got {window_examples}"
             )
         self.window_examples = int(window_examples)
         self._lock = threading.Lock()
+        # Seconds a batch's fold spends waiting for plus holding the
+        # lock: what the parse threads and the loop contend on.
+        self._t_lock = telemetry.timer("ingest.sketch_lock")
         self.total = SketchSet()
         self.window = SketchSet()
         # The two most recent COMPLETED windows: psi() prefers the
@@ -98,16 +102,23 @@ class StreamSketch:
             self.rotations += 1
 
     def update_batch(self, ids, vals, weights=None) -> None:
-        """One parsed batch's features (thread-worker path)."""
+        """One parsed batch's features (thread-worker path).  The
+        batch's own arithmetic is done once, for both views and before
+        the lock: sixteen parse threads and the training loop queue
+        there, so only what reads the views' state runs under it."""
+        folded = fold_batch(ids, vals, weights)
+        t0 = time.perf_counter()
         with self._lock:
-            self.total.update_batch(ids, vals, weights)
-            self.window.update_batch(ids, vals, weights)
+            self.total.apply(folded)
+            self.window.apply(folded)
             self._maybe_rotate_locked()
+        self._t_lock.observe(time.perf_counter() - t0)
 
     def update_scores(self, scores) -> None:
+        fv = finite_values(scores)
         with self._lock:
-            self.total.update_scores(scores)
-            self.window.update_scores(scores)
+            self.total.scores.apply(fv)
+            self.window.scores.apply(fv)
 
     def absorb(self, delta: dict) -> None:
         """Merge a serialized SketchSet DELTA a process worker shipped
@@ -412,8 +423,9 @@ class ServeSkewMonitor:
     # -- request path (serve dispatcher thread) ------------------------
 
     def observe_batch(self, ids, vals) -> None:
+        folded = fold_batch(ids, vals)
         with self._lock:
-            self.live.update_batch(ids, vals)
+            self.live.apply(folded)
             if self.live.examples >= self.window_examples:
                 self._prev = self.live
                 self.live = SketchSet()
@@ -435,8 +447,9 @@ class ServeSkewMonitor:
                 self._memo = None
 
     def observe_scores(self, scores) -> None:
+        fv = finite_values(scores)
         with self._lock:
-            self.live.update_scores(np.asarray(scores, np.float64))
+            self.live.scores.apply(fv)
 
     # -- record-builder side -------------------------------------------
 

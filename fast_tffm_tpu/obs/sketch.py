@@ -47,13 +47,14 @@ jax-free router would be free to import it.
 from __future__ import annotations
 
 import math
-from typing import List, Optional
+from typing import List, NamedTuple, Optional
 
 import numpy as np
 
 __all__ = [
     "FreqSketch", "QuantileSketch", "SketchSet", "psi_freq",
     "psi_quantile", "DEFAULT_K", "DEFAULT_BUCKETS", "PSI_BINS",
+    "FiniteValues", "FoldedBatch", "finite_values", "fold_batch",
 ]
 
 DEFAULT_K = 128  # per-level capacity: ~2-3% rank error, ~KBs of state
@@ -68,6 +69,42 @@ def _round6(x: float) -> float:
     return float(f"{x:.6g}")
 
 
+_EMPTY = np.empty(0, np.float64)  # shared: levels are never mutated
+_EMPTY.setflags(write=False)
+
+
+class FiniteValues(NamedTuple):
+    """What a :class:`QuantileSketch` update needs of its input, none
+    of it depending on the sketch: the finite values (flat, in their
+    own dtype) and their extremes."""
+    arr: np.ndarray
+    lo: float
+    hi: float
+
+
+def finite_values(values) -> Optional[FiniteValues]:
+    """The batch-only half of ``QuantileSketch.update`` (None when no
+    finite value is left).  Floats and ints are reduced in their own
+    dtype: widening float32 or an int count to float64 is exact, so
+    min/max are the numbers the float64 copy would give.  min and max
+    propagate NaN and show an inf, so finite extremes mean a finite
+    array, and the screening pass and its boolean gather run only when
+    they are not."""
+    arr = np.asarray(values)
+    if arr.dtype.kind not in "fiu":
+        arr = arr.astype(np.float64)
+    arr = arr.reshape(-1)
+    if arr.size == 0:
+        return None
+    lo, hi = arr.min(), arr.max()
+    if not (np.isfinite(lo) and np.isfinite(hi)):
+        arr = arr[np.isfinite(arr)]
+        if arr.size == 0:
+            return None
+        lo, hi = arr.min(), arr.max()
+    return FiniteValues(arr, float(lo), float(hi))
+
+
 class QuantileSketch:
     """KLL-style mergeable quantile sketch over a float stream."""
 
@@ -76,7 +113,8 @@ class QuantileSketch:
             raise ValueError(f"k must be >= 8, got {k}")
         self.k = int(k)
         self.n = 0  # total stream elements represented
-        self._levels: List[list] = [[]]  # level i item weight = 2^i
+        # level i item weight = 2^i; each level a float64 array
+        self._levels: List[np.ndarray] = [_EMPTY]
         self._flip: List[bool] = [False]  # alternating compaction offset
         self._min = math.inf
         self._max = -math.inf
@@ -103,41 +141,47 @@ class QuantileSketch:
 
     def update(self, values) -> None:
         """Fold an array (or scalar) of values into the sketch."""
-        arr = np.asarray(values, np.float64).reshape(-1)
-        if arr.size == 0:
+        self.apply(finite_values(values))
+
+    def apply(self, fv: Optional[FiniteValues]) -> None:
+        """The part of :meth:`update` that reads the sketch's state —
+        what a caller that holds a lock does under it, with
+        :func:`finite_values` done before."""
+        if fv is None:
             return
-        arr = arr[np.isfinite(arr)]
-        if arr.size == 0:
-            return
-        self.n += int(arr.size)
+        arr = fv.arr
+        self.n += arr.size
         self._weighted_cache = None
-        self._min = min(self._min, float(arr.min()))
-        self._max = max(self._max, float(arr.max()))
+        self._min = min(self._min, fv.lo)
+        self._max = max(self._max, fv.hi)
         if arr.size > self.UPDATE_CAP:
-            # Deterministic stride with a rotating offset (the level-0
-            # flip bit doubles as the rotation) so periodic batch
-            # layouts can't alias into the subsample.
+            # Deterministic stride with a rotating offset (the running
+            # n doubles as the rotation) so periodic batch layouts
+            # can't alias into the subsample.
             stride = -(-arr.size // self.UPDATE_CAP)
             off = (self.n + stride - 1) % stride
             arr = arr[off::stride]
-        lvl0 = self._levels[0]
-        lvl0.extend(arr.tolist())
-        if len(lvl0) >= 2 * self.k:
+        # Only what is inserted (<= UPDATE_CAP) is widened to float64.
+        lvl0 = np.concatenate((self._levels[0], arr), dtype=np.float64)
+        self._levels[0] = lvl0
+        if lvl0.size >= 2 * self.k:
             self._compact_from(0)
 
     def _compact_from(self, i: int) -> None:
-        while i < len(self._levels) and len(self._levels[i]) >= 2 * self.k:
-            items = sorted(self._levels[i])
+        levels = self._levels
+        while i < len(levels) and levels[i].size >= 2 * self.k:
+            # Items that compare equal are interchangeable (no NaN gets
+            # here; a zero's sign is read by no query), so the default
+            # kind, ~10x the stable one at these sizes, gives the
+            # levels sorted() gave.
+            items = np.sort(levels[i])
             off = 1 if self._flip[i] else 0
             self._flip[i] = not self._flip[i]
-            # An odd survivor stays at this level so no weight is lost
-            # beyond the compaction's inherent halving.
-            keep = items[off::2]
-            self._levels[i] = []
-            if i + 1 == len(self._levels):
-                self._levels.append([])
+            levels[i] = _EMPTY
+            if i + 1 == len(levels):
+                levels.append(_EMPTY)
                 self._flip.append(False)
-            self._levels[i + 1].extend(keep)
+            levels[i + 1] = np.concatenate((levels[i + 1], items[off::2]))
             i += 1
 
     def merge(self, other: "QuantileSketch") -> "QuantileSketch":
@@ -150,10 +194,10 @@ class QuantileSketch:
         self._min = min(self._min, other._min)
         self._max = max(self._max, other._max)
         while len(self._levels) < len(other._levels):
-            self._levels.append([])
+            self._levels.append(_EMPTY)
             self._flip.append(False)
         for i, items in enumerate(other._levels):
-            self._levels[i].extend(items)
+            self._levels[i] = np.concatenate((self._levels[i], items))
         self._compact_from(0)
         # A merge can overfill upper levels directly; sweep them all.
         for i in range(len(self._levels)):
@@ -167,16 +211,14 @@ class QuantileSketch:
         memoized until the next update/merge."""
         if self._weighted_cache is not None:
             return self._weighted_cache
-        vals: list = []
-        wts: list = []
-        for i, items in enumerate(self._levels):
-            vals.extend(items)
-            wts.extend([1 << i] * len(items))
-        if not vals:
+        v = np.concatenate(self._levels)
+        if v.size == 0:
             self._weighted_cache = (None, None)
             return self._weighted_cache
-        v = np.asarray(vals, np.float64)
-        w = np.asarray(wts, np.float64)
+        w = np.repeat(
+            np.ldexp(1.0, np.arange(len(self._levels))),
+            [lvl.size for lvl in self._levels],
+        )
         order = np.argsort(v, kind="stable")
         self._weighted_cache = (v[order], np.cumsum(w[order]))
         return self._weighted_cache
@@ -207,7 +249,7 @@ class QuantileSketch:
     @property
     def retained(self) -> int:
         """Items held across all levels — the memory bound under test."""
-        return sum(len(lvl) for lvl in self._levels)
+        return sum(lvl.size for lvl in self._levels)
 
     # -- serialization -------------------------------------------------
 
@@ -218,7 +260,7 @@ class QuantileSketch:
             "min": _round6(self._min) if self.n else None,
             "max": _round6(self._max) if self.n else None,
             "levels": [
-                [_round6(x) for x in lvl] for lvl in self._levels
+                [_round6(x) for x in lvl.tolist()] for lvl in self._levels
             ],
         }
 
@@ -226,8 +268,8 @@ class QuantileSketch:
     def from_dict(cls, doc: dict) -> "QuantileSketch":
         sk = cls(k=int(doc.get("k", DEFAULT_K)))
         sk.n = int(doc.get("n", 0))
-        sk._levels = [list(map(float, lvl))
-                      for lvl in doc.get("levels", [[]])] or [[]]
+        sk._levels = [np.asarray(lvl, np.float64).reshape(-1)
+                      for lvl in doc.get("levels", [[]])] or [_EMPTY]
         sk._flip = [False] * len(sk._levels)
         if sk.n:
             sk._min = float(doc["min"])
@@ -249,20 +291,34 @@ class FreqSketch:
         self.counts = np.zeros(self.buckets, np.int64)
         self.n = 0
 
-    def update(self, ids) -> None:
-        arr = np.asarray(ids).reshape(-1)
-        if arr.size == 0:
-            return
-        with np.errstate(over="ignore"):
-            h = (arr.astype(np.uint64) * self._MIX) >> np.uint64(17)
+    @classmethod
+    def histogram(cls, ids, buckets: int) -> np.ndarray:
+        """Bucket counts of ``ids`` alone (int64[buckets]) — the
+        batch-only half of :meth:`update`."""
+        # One uint64 buffer, hashed in place: the multiply casts as
+        # astype(uint64) would (wrapping) on its way into a new array,
+        # so the caller's ids are never written.
+        h = np.multiply(np.asarray(ids).reshape(-1), cls._MIX,
+                        dtype=np.uint64, casting="unsafe")
+        np.right_shift(h, np.uint64(17), out=h)
+        if buckets & (buckets - 1) == 0:
+            np.bitwise_and(h, np.uint64(buckets - 1), out=h)
+        else:
+            np.remainder(h, np.uint64(buckets), out=h)
         # bincount, not add.at: one histogram pass instead of a
         # scattered-index loop (matters at batch_size * max_features
-        # ids per parsed batch).
-        self.counts += np.bincount(
-            (h % np.uint64(self.buckets)).astype(np.int64),
-            minlength=self.buckets,
-        )
-        self.n += int(arr.size)
+        # ids per parsed batch).  Every value is < buckets, so the
+        # int64 view bincount wants holds the same numbers.
+        return np.bincount(h.view(np.int64), minlength=buckets)
+
+    def update(self, ids) -> None:
+        self.apply(self.histogram(ids, self.buckets))
+
+    def apply(self, hist: np.ndarray) -> None:
+        """Add a :meth:`histogram` of the same width (another width
+        does not broadcast: ValueError)."""
+        self.counts += hist
+        self.n += int(hist.sum())
 
     def merge(self, other: "FreqSketch") -> "FreqSketch":
         if other.buckets != self.buckets:
@@ -350,6 +406,43 @@ def psi_quantile(ref: QuantileSketch, live: QuantileSketch,
     return _debias(psi, len(edges), ref.n, live.n)
 
 
+class FoldedBatch(NamedTuple):
+    """One batch reduced to what a :class:`SketchSet` adds to its
+    state (``SketchSet.apply``)."""
+    examples: int
+    values: Optional[FiniteValues]
+    lengths: Optional[FiniteValues]
+    hist: np.ndarray  # FreqSketch.histogram of the real slots' ids
+
+
+def fold_batch(ids, vals, weights=None,
+               buckets: int = DEFAULT_BUCKETS) -> Optional[FoldedBatch]:
+    """Everything of ``SketchSet.update_batch`` that depends on the
+    batch alone — done once per batch and outside any lock, however
+    many sets (a stream's ``total`` and ``window``) then apply it.
+    None for a batch without a weighted example."""
+    ids = np.asarray(ids)
+    vals = np.asarray(vals)
+    if vals.ndim == 1:
+        ids = ids.reshape(1, -1)
+        vals = vals.reshape(1, -1)
+    if weights is not None:
+        rows = np.asarray(weights).reshape(-1) > 0
+        if not rows.all():
+            ids, vals = ids[rows], vals[rows]
+    n = int(vals.shape[0])
+    if n == 0:
+        return None
+    real = vals != 0
+    if real.all():  # no padded slot: both boolean gathers are copies
+        lengths = np.full(n, vals.shape[1], np.int64)
+    else:
+        lengths = real.sum(axis=1)
+        ids, vals = ids[real], vals[real]
+    return FoldedBatch(n, finite_values(vals), finite_values(lengths),
+                       FreqSketch.histogram(ids, buckets))
+
+
 class SketchSet:
     """The model-quality sketch bundle over one example stream.
 
@@ -383,21 +476,17 @@ class SketchSet:
         self.examples = 0
 
     def update_batch(self, ids, vals, weights=None) -> None:
-        ids = np.asarray(ids)
-        vals = np.asarray(vals)
-        if vals.ndim == 1:
-            ids = ids.reshape(1, -1)
-            vals = vals.reshape(1, -1)
-        if weights is not None:
-            rows = np.asarray(weights).reshape(-1) > 0
-            ids, vals = ids[rows], vals[rows]
-        if vals.shape[0] == 0:
+        self.apply(fold_batch(ids, vals, weights, self.ids.buckets))
+
+    def apply(self, folded: Optional[FoldedBatch]) -> None:
+        """Add one :func:`fold_batch` result: everything that reads or
+        writes this set's state, and nothing else."""
+        if folded is None:
             return
-        real = vals != 0
-        self.values.update(vals[real])
-        self.lengths.update(real.sum(axis=1))
-        self.ids.update(ids[real])
-        self.examples += int(vals.shape[0])
+        self.values.apply(folded.values)
+        self.lengths.apply(folded.lengths)
+        self.ids.apply(folded.hist)
+        self.examples += folded.examples
 
     def update_scores(self, scores) -> None:
         self.scores.update(scores)

@@ -1811,7 +1811,8 @@ class Trainer:
         # parse workers feed, and the windowed online-eval monitor the
         # dispatch loop feeds one dispatch delayed.
         self._quality_sketch = (
-            obs.StreamSketch(cfg.quality_window) if cfg.quality else None
+            obs.StreamSketch(cfg.quality_window, telemetry=self.telemetry)
+            if cfg.quality else None
         )
         self._quality = (
             obs.QualityMonitor(

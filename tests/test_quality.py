@@ -6,7 +6,11 @@ publication, and training→serving skew end-to-end over real sockets.
 """
 
 import json
+import math
 import os
+import sys
+import threading
+import time
 import urllib.request
 
 import numpy as np
@@ -359,6 +363,319 @@ class TestStreamSketch:
 
 
 # ----------------------------------------------------------------------
+# the fold, against the loop it replaced
+# ----------------------------------------------------------------------
+
+
+class _ParentQuantile:
+    """``QuantileSketch`` as it stood before the fold was split and
+    vectorised: a float64 copy of every value, levels as Python lists,
+    ``sorted()`` in the cascade.  Kept as the reference."""
+
+    def __init__(self, k=128):
+        self.k, self.n = k, 0
+        self.levels, self.flip = [[]], [False]
+        self.min, self.max = math.inf, -math.inf
+
+    def update(self, values):
+        arr = np.asarray(values, np.float64).reshape(-1)
+        arr = arr[np.isfinite(arr)]
+        if arr.size == 0:
+            return
+        self.n += int(arr.size)
+        self.min = min(self.min, float(arr.min()))
+        self.max = max(self.max, float(arr.max()))
+        if arr.size > QuantileSketch.UPDATE_CAP:
+            stride = -(-arr.size // QuantileSketch.UPDATE_CAP)
+            arr = arr[(self.n + stride - 1) % stride::stride]
+        self.levels[0].extend(arr.tolist())
+        i = 0
+        while i < len(self.levels) and len(self.levels[i]) >= 2 * self.k:
+            items = sorted(self.levels[i])
+            keep = items[1 if self.flip[i] else 0::2]
+            self.flip[i] = not self.flip[i]
+            self.levels[i] = []
+            if i + 1 == len(self.levels):
+                self.levels.append([])
+                self.flip.append(False)
+            self.levels[i + 1].extend(keep)
+            i += 1
+
+    def to_dict(self):
+        r6 = lambda x: float(f"{x:.6g}")  # noqa: E731
+        return {"k": self.k, "n": self.n,
+                "min": r6(self.min) if self.n else None,
+                "max": r6(self.max) if self.n else None,
+                "levels": [[r6(x) for x in lvl] for lvl in self.levels]}
+
+
+class _ParentSketchSet:
+    """``SketchSet.update_batch`` / ``FreqSketch.update`` likewise."""
+
+    def __init__(self, buckets=512):
+        self.values, self.lengths = _ParentQuantile(), _ParentQuantile()
+        self.scores = _ParentQuantile()
+        self.buckets, self.ids_n, self.examples = buckets, 0, 0
+        self.counts = np.zeros(buckets, np.int64)
+
+    def update_batch(self, ids, vals, weights=None):
+        ids, vals = np.asarray(ids), np.asarray(vals)
+        if vals.ndim == 1:
+            ids, vals = ids.reshape(1, -1), vals.reshape(1, -1)
+        if weights is not None:
+            rows = np.asarray(weights).reshape(-1) > 0
+            ids, vals = ids[rows], vals[rows]
+        if vals.shape[0] == 0:
+            return
+        real = vals != 0
+        self.values.update(vals[real])
+        self.lengths.update(real.sum(axis=1))
+        arr = ids[real].reshape(-1)
+        with np.errstate(over="ignore"):
+            h = (arr.astype(np.uint64) * FreqSketch._MIX) >> np.uint64(17)
+        self.counts += np.bincount(
+            (h % np.uint64(self.buckets)).astype(np.int64),
+            minlength=self.buckets,
+        )
+        self.ids_n += int(arr.size)
+        self.examples += int(vals.shape[0])
+
+    def to_dict(self):
+        return {"version": 1, "examples": self.examples,
+                "values": self.values.to_dict(),
+                "lengths": self.lengths.to_dict(),
+                "ids": {"buckets": self.buckets, "n": self.ids_n,
+                        "counts": self.counts.tolist()},
+                "scores": self.scores.to_dict()}
+
+
+def _fold_stream(case, seed=0):
+    """(ids, vals, weights, scores) batches of one equality case."""
+    rng = np.random.default_rng(seed)
+    n, f = case.get("shape", (1024, 39))
+    dtype = case.get("dtype", np.float32)
+    for _ in range(case.get("batches", 24)):
+        ids = rng.integers(-5, 1 << 40, size=(n, f))
+        vals = (rng.standard_normal((n, f)) * 3).astype(dtype)
+        if case.get("pad"):
+            # A zero value is a padded slot; a tail of them is what the
+            # parsers leave, a scattered one is as legal.
+            vals[rng.random((n, f)) < 0.2] = 0
+            vals[:, f - 3:][rng.random((n, 3)) < 0.7] = 0
+        if case.get("nonfinite"):
+            vals[rng.random((n, f)) < 0.01] = np.nan
+            vals[rng.random((n, f)) < 0.01] = np.inf
+            vals[rng.random((n, f)) < 0.01] = -np.inf
+        weights = None
+        if case.get("zero_weights"):
+            weights = (rng.random(n) < 0.8).astype(np.float32)
+        if case.get("one_d"):
+            ids, vals = ids[0], vals[0]
+        scores = rng.random(n if not case.get("one_d") else 1)
+        yield ids, vals, weights, scores.astype(dtype)
+
+
+_FOLD_CASES = {
+    "1024x39": {},
+    "8192x39": {"shape": (8192, 39), "batches": 12},
+    "padded": {"pad": True},
+    "zero_weights": {"zero_weights": True},
+    "all_weights_zero": {"zero_weights": True, "shape": (3, 39),
+                         "batches": 40},
+    "nonfinite": {"nonfinite": True},
+    "everything_float64": {"pad": True, "nonfinite": True,
+                           "zero_weights": True, "dtype": np.float64},
+    "buckets_500": {"buckets": 500, "pad": True},
+    "buckets_500_8192": {"buckets": 500, "shape": (8192, 39),
+                         "batches": 6},
+    "one_example_1d": {"one_d": True, "batches": 600},
+    "int32_ids_float64_vals": {"dtype": np.float64, "int32": True},
+    # 96 x 4,096 inserted values cascade seven levels of k=128
+    "long_stream": {"batches": 96},
+}
+
+
+class TestFoldEquality:
+    @pytest.mark.parametrize("name", sorted(_FOLD_CASES))
+    def test_to_dict_equals_the_parent_fold(self, name):
+        case = _FOLD_CASES[name]
+        buckets = case.get("buckets", 512)
+        ref = _ParentSketchSet(buckets)
+        new = SketchSet(buckets=buckets)
+        for ids, vals, weights, scores in _fold_stream(case):
+            if case.get("int32"):
+                ids = (ids % (1 << 31)).astype(np.int32)
+            before = (ids.copy(), vals.copy())
+            ref.update_batch(ids, vals, weights)
+            new.update_batch(ids, vals, weights)
+            ref.scores.update(scores)
+            new.update_scores(scores)
+            # the in-place hash works on the fold's own buffer
+            np.testing.assert_array_equal(ids, before[0])
+            np.testing.assert_array_equal(vals, before[1])
+        assert new.to_dict() == ref.to_dict()
+        if name == "long_stream":
+            assert len(new.values.to_dict()["levels"]) >= 5
+        # what the levels are read through: same numbers as the lists
+        ref_levels = ref.values.levels
+        flat = sorted(x for lvl in ref_levels for x in lvl)
+        if flat:
+            assert new.values.retained == len(flat)
+            assert new.values.quantile(0.0) == ref.values.min
+            total = sum(len(lvl) << i for i, lvl in enumerate(ref_levels))
+            assert new.values.rank(flat[-1]) == 1.0
+            assert new.values._weighted()[1][-1] == total
+
+    def test_window_rotates_twice_on_the_same_sketches(self):
+        """One folded batch applied to both views under the lock: the
+        stream's ``total`` and each completed window equal the parent's
+        per-view folds of the same batches."""
+        ss = StreamSketch(window_examples=8192)
+        ref_total, ref_window, ref_done = (
+            _ParentSketchSet(), _ParentSketchSet(), [])
+        for ids, vals, weights, scores in _fold_stream(
+                {"pad": True, "zero_weights": True, "batches": 28}):
+            ss.update_batch(ids, vals, weights)
+            ss.update_scores(scores)
+            ref_total.update_batch(ids, vals, weights)
+            ref_window.update_batch(ids, vals, weights)
+            if ref_window.examples >= 8192:  # a batch's fold rotates
+                ref_done.append(ref_window)
+                ref_window = _ParentSketchSet()
+            ref_total.scores.update(scores)
+            ref_window.scores.update(scores)
+        assert ss.rotations == len(ref_done) >= 2
+        assert ss.total.to_dict() == ref_total.to_dict()
+        assert ss.window.to_dict() == ref_window.to_dict()
+        assert ss.prev.to_dict() == ref_done[-1].to_dict()
+        assert ss.prev2.to_dict() == ref_done[-2].to_dict()
+
+    def test_serve_monitor_folds_the_same_sketch(self):
+        mon = ServeSkewMonitor(window_examples=1 << 30)
+        ref = _ParentSketchSet()
+        for ids, vals, _, scores in _fold_stream({"pad": True,
+                                                  "batches": 8}):
+            mon.observe_batch(ids, vals)
+            mon.observe_scores(scores)
+            ref.update_batch(ids, vals)
+            ref.scores.update(scores)
+        assert mon.live.to_dict() == ref.to_dict()
+
+    def test_serialized_levels_round_trip_and_merge(self):
+        a, b = SketchSet(), SketchSet()
+        for i, (ids, vals, _, scores) in enumerate(
+                _fold_stream({"pad": True, "batches": 10})):
+            (a if i % 2 else b).update_batch(ids, vals)
+            (a if i % 2 else b).update_scores(scores)
+        doc = json.loads(json.dumps(a.to_dict()))
+        back = SketchSet.from_dict(doc)
+        assert back.to_dict() == doc
+        merged = SketchSet.from_dict(doc).merge(b)
+        assert merged.examples == a.examples + b.examples
+        assert merged.values.n == a.values.n + b.values.n
+        assert merged.values.retained < 2 * 128 * len(
+            merged.values.to_dict()["levels"])
+        assert b.to_dict() == SketchSet.from_dict(b.to_dict()).to_dict()
+
+    @pytest.mark.parametrize("values", [
+        [], [np.nan, np.inf], np.float32([1.5, np.nan, -2.0]),
+        np.arange(5), [True, False], 3.25,
+    ], ids=["empty", "no_finite", "float32_nan", "ints", "bools",
+            "scalar"])
+    def test_quantile_update_small_inputs(self, values):
+        ref, new = _ParentQuantile(), QuantileSketch()
+        ref.update(values)
+        new.update(values)
+        assert new.to_dict() == ref.to_dict()
+
+    def test_histogram_of_another_width_is_refused(self):
+        with pytest.raises(ValueError):
+            FreqSketch(512).apply(FreqSketch.histogram([1, 2, 3], 500))
+
+
+class TestStreamSketchThreads:
+    THREADS = 8
+
+    def _batches(self, n_batches):
+        return [b[:3] for b in _fold_stream(
+            {"pad": True, "zero_weights": True, "shape": (256, 39),
+             "batches": n_batches}, seed=7)]
+
+    def test_eight_threads_equal_a_serial_fold(self):
+        """No update lost or doubled with the arithmetic outside the
+        lock: everything order-free equals a serial fold."""
+        batches = self._batches(8 * 24)
+        window = 4096
+        serial = StreamSketch(window_examples=1 << 40)
+        for ids, vals, weights in batches:
+            serial.update_batch(ids, vals, weights)
+        ss = StreamSketch(window_examples=window)
+        errors = []
+
+        def work(mine):
+            try:
+                for ids, vals, weights in mine:
+                    ss.update_batch(ids, vals, weights)
+                    ss.update_scores(np.float32([0.25, 0.5]))
+            except BaseException as e:  # noqa: BLE001 - reported below
+                errors.append(e)
+
+        threads = [
+            threading.Thread(target=work, args=(batches[i::self.THREADS],))
+            for i in range(self.THREADS)
+        ]
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+        finally:
+            sys.setswitchinterval(old)
+        assert not any(t.is_alive() for t in threads)
+        assert errors == []
+        want, got = serial.total, ss.total
+        assert got.examples == want.examples
+        np.testing.assert_array_equal(got.ids.counts, want.ids.counts)
+        assert got.ids.n == want.ids.n
+        for axis in ("values", "lengths"):
+            a, b = getattr(got, axis), getattr(want, axis)
+            assert (a.n, a._min, a._max) == (b.n, b._min, b._max)
+        assert got.scores.n == 2 * len(batches)
+        # every batch here is far smaller than a window and a window
+        # closes on the batch that fills it, so the count of rotations
+        # is that of the examples whatever the order
+        sizes = sorted(int((w > 0).sum()) for _, _, w in batches)
+        assert ss.rotations >= want.examples // (window + sizes[-1])
+        assert ss.rotations <= want.examples // window
+        assert ss.window.examples < window
+        assert ss.prev is not None and ss.prev.examples >= window
+
+    def test_rotation_count_is_floor_of_examples_over_window(self):
+        """Batches that divide the window: exactly floor(n / window)
+        rotations, under threads too."""
+        rng = np.random.default_rng(3)
+        ss = StreamSketch(window_examples=1024)
+        batches = [(rng.integers(0, 1 << 20, size=(128, 8)),
+                    rng.random((128, 8)) + 0.5) for _ in range(8 * 9)]
+        threads = [
+            threading.Thread(
+                target=lambda mine: [ss.update_batch(*b) for b in mine],
+                args=(batches[i::self.THREADS],))
+            for i in range(self.THREADS)
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+        assert ss.examples == 128 * len(batches)
+        assert ss.rotations == ss.examples // 1024 == 9
+        assert ss.window.examples == 0
+
+
+# ----------------------------------------------------------------------
 # config: inert-knob discipline
 # ----------------------------------------------------------------------
 
@@ -532,6 +849,49 @@ class TestTrainerQuality:
         man = read_manifest(cfg.model_file)
         assert man["quality"]["examples"] == 320
 
+    def test_parse_thread_fold_is_a_phase(self, train_file, tmp_path,
+                                          monkeypatch):
+        """Timers ``ingest.sketch`` (the whole fold, one a batch) and
+        ``ingest.sketch_lock`` (its part at the accumulator's lock),
+        and the annotation ``tffm:ingest.sketch`` with ``n``."""
+        from fast_tffm_tpu.obs import telemetry as tel_mod
+        from fast_tffm_tpu.train.loop import Trainer
+
+        spans = []
+
+        class Annotation:
+            def __init__(self, name, **stats):
+                self.item = (name, stats)
+
+            @staticmethod
+            def is_enabled():
+                return True
+
+            def __enter__(self):
+                spans.append(self.item)
+
+            def __exit__(self, *exc):
+                return None
+
+        monkeypatch.setattr(tel_mod, "_annotation", lambda: Annotation)
+        trainer = Trainer(_train_cfg(train_file, tmp_path, "phase"))
+        res = trainer.train()
+        timers = trainer.telemetry.snapshot()["timers"]
+        fold, lock = timers["ingest.sketch"], timers["ingest.sketch_lock"]
+        assert fold["count"] == lock["count"] == 320 // 32
+        assert 0 < lock["total_s"] <= fold["total_s"]
+        mine = [st for name, st in spans if name == "tffm:ingest.sketch"]
+        assert len(mine) == 10 and all(st == {"n": 32} for st in mine)
+        assert res["train"]["quality"]["sketch_examples"] == 320
+        # quality off: no fold, no timer observation, no span
+        spans.clear()
+        off = Trainer(_train_cfg(train_file, tmp_path, "phase_off",
+                                 quality=False))
+        off.train()
+        assert off.telemetry.snapshot()["timers"].get(
+            "ingest.sketch", {"count": 0})["count"] == 0
+        assert not [n for n, _ in spans if n == "tffm:ingest.sketch"]
+
     def test_sketch_failure_never_kills_training(self, train_file,
                                                  tmp_path,
                                                  monkeypatch):
@@ -579,6 +939,19 @@ def _get_json(url, timeout=10):
         return json.loads(r.read())
 
 
+def _serve_block_when(url, ready, timeout=10.0):
+    """/status's serve block once ``ready(block)`` (or the last one
+    read): the dispatcher folds a group into the skew sketches after
+    it has released the reply, so a scrape right behind a reply may
+    come before the fold."""
+    deadline = time.monotonic() + timeout
+    while True:
+        block = _get_json(url + "/status")["serve"]
+        if ready(block) or time.monotonic() > deadline:
+            return block
+        time.sleep(0.05)
+
+
 @pytest.fixture(scope="module")
 def served(tmp_path_factory, train_file):
     """One trained checkpoint with manifest sketches, shared by the
@@ -605,7 +978,8 @@ class TestServeSkew:
             url = f"http://127.0.0.1:{handle.port}"
             body = open(data, "rb").read()
             _post(url + "/score", body)
-            block = _get_json(url + "/status")["serve"]
+            block = _serve_block_when(
+                url, lambda b: b.get("skew_examples", 0) >= 128)
             assert block["skew_ref_step"] > 0
             assert block["skew_examples"] >= 128
             assert block["skew_psi_max"] <= 0.1, block
@@ -619,7 +993,8 @@ class TestServeSkew:
                 for _ in range(320)
             ).encode()
             _post(url + "/score", shifted)
-            block = _get_json(url + "/status")["serve"]
+            block = _serve_block_when(
+                url, lambda b: b.get("skew_psi_max", 0) > 0.25)
             assert block["skew_psi_max"] > 0.25, block
             assert block["skew_psi_values"] > 0.25, block
             metrics = urllib.request.urlopen(
