@@ -358,8 +358,7 @@ class FmConfig:
     # 'pallas', False -> 'jnp'); 'flat' selects the pure-XLA flat-layout
     # one-hot-matmul variant (same math as the Pallas kernels, fused by
     # XLA instead).  Applies to plain FM; field-aware FM (field_num > 0)
-    # always uses its closed-form op (ops.interaction.ffm_interaction;
-    # FAST_TFFM_FFM_AUTODIFF=1 forces the autodiff einsum oracle).
+    # always uses its closed-form op (ops.interaction.ffm_interaction).
     interaction: str = ""
     # Kernel autotuner surface (ops/autotune.py): "auto" benchmarks the
     # candidate interaction implementations at the run's actual shapes,

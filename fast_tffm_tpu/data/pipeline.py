@@ -301,19 +301,6 @@ def _iter_raw_windows(
         yield buf, starts[:n_keep], ends[:n_keep]
 
 
-def _iter_raw_groups(
-    files: Sequence[str], batch_size: int, chunk_bytes: int = _CHUNK_BYTES
-):
-    """Yield (buf, starts, ends) groups of <= batch_size raw lines, in
-    file order (no shuffle) — the unshuffled convenience used by bench
-    and tests; BatchPipeline slices windows itself to shuffle lines."""
-    for buf, starts, ends in _iter_raw_windows(
-        files, batch_size, batch_size, chunk_bytes
-    ):
-        for i in range(0, len(starts), batch_size):
-            yield buf, starts[i:i + batch_size], ends[i:i + batch_size]
-
-
 def _item_len(item) -> int:
     """Number of lines in a work item (line chunk or raw group)."""
     if isinstance(item, tuple):
@@ -589,8 +576,7 @@ class BatchPipeline:
         # Delivery accounting happens at the single exit point so every
         # path (threads, procpool, cached replay) counts identically.
         # The O(batch) example count only runs when telemetry is live —
-        # "disabled" must mean no per-batch work at all, or the bench's
-        # on/off overhead probe compares against a lie.
+        # "disabled" must mean no per-batch work at all.
         counting = self.telemetry.enabled
         tracing = self.tracer.enabled
         for item in inner:
@@ -1020,10 +1006,8 @@ class BatchPipeline:
                     continue
                 try:
                     # Per-batch timing only when someone consumes it:
-                    # "disabled" must mean no per-batch work at all, or
-                    # the bench's on/off overhead probes compare
-                    # against a lie (same invariant as delivery
-                    # counting above).
+                    # "disabled" must mean no per-batch work at all
+                    # (same invariant as delivery counting above).
                     t0p = time.perf_counter() if timed else 0.0
                     if isinstance(chunk, tuple):  # raw (buf,starts,ends)
                         batch = self._native.parse_raw(
@@ -1752,9 +1736,9 @@ class DevicePrefetcher:
         self._sb_id = 0
         self._batch_idx = 0
         # Staging-buffer reuse is opt-in: it requires put_fn to COPY out
-        # of the host arrays (device_put does; an identity put_fn used
-        # by tests/bench drains hands the arrays downstream, where a
-        # recycled buffer would be overwritten under the consumer).
+        # of the host arrays (device_put does; an identity put_fn, as
+        # tests use, hands the arrays downstream, where a recycled
+        # buffer would be overwritten under the consumer).
         self._pool = (
             _StagingPool(
                 max(1, depth) + 1,

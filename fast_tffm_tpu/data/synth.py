@@ -1,7 +1,7 @@
 """Seeded synthetic CTR data: Zipf-skewed hashed ids as libsvm text.
 
-The generator behind ``bench.py``'s e2e files and ``chip_smoke.py``'s
-train/validation/predict files — the chip machine has no network and
+The generator behind ``chip_smoke.py``'s train/validation/predict
+files — the chip machine has no network and
 ``examples/data/`` is git-ignored, so real-width inputs are made from a
 seed, in seconds (numpy only; no jax import).
 """

@@ -66,8 +66,8 @@ _BASELINE_MIN = 3
 # tests/test_quality.py) so the drift signals can't be silently
 # disabled by a too-small window.
 _MIN_PSI_EXAMPLES = 32
-# block() memo: /status can be scraped every 200 ms (the bench does);
-# the window statistics only need to refresh at human cadence.
+# block() memo: /status can be scraped every 200 ms; the window
+# statistics only need to refresh at human cadence.
 _BLOCK_MEMO_S = 0.5
 
 

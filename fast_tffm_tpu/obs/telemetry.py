@@ -28,8 +28,8 @@ disabling telemetry is behaviorally invisible.
 
 Enabled overhead per event is one ``perf_counter`` call plus one
 uncontended lock acquire (~100 ns); events fire per *batch* (~thousands
-of examples), not per example, so the hot-path cost is noise-level —
-``bench.py`` measures the on/off e2e ratio to keep that claim honest.
+of examples), not per example.  The benchmark's cells run with
+telemetry on (PERF.md); its cost alone is not measured on the chip.
 
 This module deliberately imports neither jax nor numpy: the data layer
 uses it, and spawned parse workers must stay jax-free.
@@ -294,7 +294,7 @@ class Telemetry:
 
     ``counter/gauge/timer`` create-or-return by dotted name (idempotent,
     thread-safe), so independent components — pipeline, prefetcher,
-    trainer, bench — agree on instruments without passing them around.
+    trainer — agree on instruments without passing them around.
     A disabled registry hands out shared no-op instruments and snapshots
     to ``{}``; callers never branch on ``enabled``.
     """
@@ -391,7 +391,7 @@ _trace_resolved = False
 def _annotation() -> Optional[Callable]:
     """``jax.profiler.TraceAnnotation`` once jax is ALREADY imported by
     someone else, else None: a jax import triggered from here would
-    make a jax-free process (a parse worker, ingest_bench) a jax
+    make a jax-free process (a parse worker) a jax
     process — one that may go on to claim the chip its parent holds —
     and with no jax there is no trace to annotate anyway."""
     global _trace_annotation, _trace_resolved

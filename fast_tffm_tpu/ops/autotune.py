@@ -28,8 +28,8 @@ selection mechanism:
 Off-TPU the candidate set collapses to ``("reference",)`` — the Mosaic
 kernels would run in interpret mode and the packed one-hot matmuls are
 a CPU pessimization, so reference provably wins at zero measurement
-cost (the ``autotune_overhead <= 1.05`` budget bench.py enforces).  On
-a TPU backend all candidates enter measurement — that is the point.
+cost (tests/test_autotune.py pins that no candidate is timed).  On a
+TPU backend all candidates enter measurement — that is the point.
 
 Offline: ``python tools/autotune.py`` pre-populates the cache for a
 config; ``--check`` validates cache self-consistency and the

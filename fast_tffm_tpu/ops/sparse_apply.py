@@ -38,9 +38,9 @@ the random-access scatter into sequential streams and MXU matmuls:
    matmul, and applies the optimizer formula on the whole tile in VPU.
 
 Per step this costs one pass over the table (streaming) plus the MXU
-placement matmuls, independent of duplicate structure — measured 2.3x
-faster than the XLA scatter path on real v5e at Criteo shapes (V=2^22,
-B=16k, F=39; TPU_RESULTS.md, against the per-occurrence scatter).  The
+placement matmuls, independent of duplicate structure.  No benchmark
+cell runs it yet, so its speed against the unique-row scatter is not
+measured (PERF.md §4 and §7 row 1: blocked on the table copy).  The
 one-hot matmuls run as two-pass bf16 hi/lo splits: a product keeps 16
 bits (a value that occurs once is off by up to 2^-17 of itself; sums of
 many occurrences average it out).  The unique-row scatter asks K1 for
@@ -57,38 +57,20 @@ correction per row (FTRL).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Block sizes, overridable via env for hardware tuning (the grid-overhead
-# vs MXU-work tradeoff is a chip property; tools/tpu_validate.py
-# --sweep-blocks measures it).  Only CHUNK and TILE must themselves be
-# multiples of 8 (sublanes).  GROUP is a plain loop trip count;
-# K1_GROUP does scale a tiled dimension ([CHUNK*K1_GROUP, lanes] payload
-# blocks — see its comment below) but needs no own multiple because
-# CHUNK keeps the product sublane-aligned.  TILE additionally gates
-# supports_tile's vocab-divisibility check.
-def _env_block(name: str, default: int, multiple: int = 8) -> int:
-    raw = os.environ.get(name, str(default))
-    try:
-        val = int(raw)
-    except ValueError:
-        raise ValueError(f"{name}={raw!r} is not an integer") from None
-    if val <= 0 or val % multiple:
-        kind = (
-            f"a positive multiple of {multiple} (sublanes)"
-            if multiple > 1 else "positive"
-        )
-        raise ValueError(f"{name}={val} must be {kind}")
-    return val
-
-
-CHUNK = _env_block("FAST_TFFM_K1_CHUNK", 512)
-TILE = _env_block("FAST_TFFM_K2_TILE", 256)
+# Block sizes.  Only CHUNK and TILE must themselves be multiples of 8
+# (sublanes).  GROUP is a plain loop trip count; K1_GROUP does scale a
+# tiled dimension ([CHUNK*K1_GROUP, lanes] payload blocks — see its
+# comment below) but needs no own multiple because CHUNK keeps the
+# product sublane-aligned.  TILE additionally gates supports_tile's
+# vocab-divisibility check.
+CHUNK = 512
+TILE = 256
 # Subtiles processed per K2/K-place grid step.  On real v5e the first
 # hardware sweep showed per-grid-step overhead (~2-3us: DMA latency not
 # overlapped, step bookkeeping) dominating the apply at V/TILE = 16k
@@ -97,13 +79,13 @@ TILE = _env_block("FAST_TFFM_K2_TILE", 256)
 # MXU-optimal [TILE, TILE] shape.  Any positive count works (it is a
 # loop trip count, not a tiled dimension); VMEM for the table blocks
 # grows linearly with it.
-GROUP = _env_block("FAST_TFFM_K2_GROUP", 8, multiple=1)
+GROUP = 8
 # Chunks per K1 grid step.  Same grid-overhead motivation, but K1's
 # grouping IS a tiled dimension (the payload input block becomes
 # [CHUNK*K1_GROUP, lanes], so pipelined VMEM grows with it), and its
 # output DMA pipelines differently (one in-flight copy, ordered: see
-# _k1_kernel) — hence a knob independent of the K2 one.
-K1_GROUP = _env_block("FAST_TFFM_K1_GROUP", 8, multiple=1)
+# _k1_kernel) — hence a constant independent of the K2 one.
+K1_GROUP = 8
 
 
 def ftrl_solve(z, n, lr, l1, l2, beta):
@@ -184,8 +166,8 @@ def _k1_kernel(starts_ref, firsts_ref, ends_ref, payload_ref, upos_ref,
             u_local = part if u_local is None else u_local + part  # [C, L]
         # Segment spanning in from the previous chunk: add its partial
         # sums to row 0 via an iota mask — `.at[0:1].add` would emit a
-        # scatter-add HLO, which Mosaic has no TPU lowering for (it
-        # aborted the round-3 bench).
+        # scatter-add HLO, which Mosaic has no TPU lowering for
+        # (tests/test_tpu_lowering.py).
         continues = (firsts_ref[cj] == 0) & (cj > 0)
         row0 = jax.lax.broadcasted_iota(jnp.int32, (chunk, lanes), 0) == 0
         u_local = u_local + jnp.where(
@@ -384,11 +366,7 @@ def _compact_auto(n_entries: int, n_groups: int) -> bool:
     """Auto-engage compact K2 only when the entry count bounds touched
     groups to <= half the table's groups — streaming the whole table is
     faster when most blocks are touched anyway (no remap indirection,
-    denser pipelining).  FAST_TFFM_K2_COMPACT=0/1 overrides the
-    heuristic (hardware sweeps A/B it on chip)."""
-    override = os.environ.get("FAST_TFFM_K2_COMPACT")
-    if override in ("0", "1"):
-        return override == "1"
+    denser pipelining)."""
     return 2 * min(n_entries, n_groups) <= n_groups
 
 

@@ -177,9 +177,9 @@ def fused_h2d_enabled(mesh: Mesh) -> bool:
     the classic per-leaf sharding semantics on a single-device,
     single-process mesh.  Within those gates the default is
     TPU-only — on CPU ``device_put`` is zero-copy, so fusing buys
-    nothing and costs one extra unpack dispatch — overridable for
-    tests/bench via ``FAST_TFFM_FUSED_H2D`` (1 forces on, 0 forces
-    off).
+    nothing and costs one extra unpack dispatch — overridable via
+    ``FAST_TFFM_FUSED_H2D`` (1 forces on, 0 forces off: tests, and
+    ``chip_smoke.py --rehearse`` on the CPU).
     """
     if mesh.size != 1 or jax.process_count() > 1:
         return False

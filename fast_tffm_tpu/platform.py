@@ -49,8 +49,8 @@ def force_compiled():
     runs at jax lowering time, so ``jax.export(..., platforms=['tpu'])``
     under this context surfaces "Unimplemented primitive in Pallas TPU
     lowering" errors on a CPU-only machine — the exact failure class that
-    interpret-mode tests structurally cannot catch (it zeroed the round-3
-    hardware bench).  Never use it to *execute* kernels off-TPU.
+    interpret-mode tests structurally cannot catch (it lost a whole
+    hardware run in round 3).  Never use it to *execute* kernels off-TPU.
     """
     global _force_compiled
     prev = _force_compiled
@@ -93,7 +93,8 @@ def ffm_compute_dtype(compute_dtype):
 # directory is the cache and nothing in this program names another (the
 # path is part of what a machine's owner provisions and finds again);
 # otherwise the ``compile_cache_dir`` cfg knob, and for the repo's own
-# scripts (chip_smoke.py, bench.py) the fixed REPO_COMPILE_CACHE_DIR.
+# scripts the fixed REPO_COMPILE_CACHE_DIR (chip_smoke.py; benchmarks/
+# names the same directory itself).
 # The monitoring listener counts hit/miss events so the
 # zero-fresh-lowers contract of a warm spawn is checkable (tests + the
 # serve log line), not assumed.

@@ -212,8 +212,7 @@ class ServeServer:
         # as measurable host latency at small requests — this timer
         # made it a measured number, and the binary transport's
         # serve.parse_bin twin is the datum that shows what removing
-        # the text parse actually buys (bench: serve_parse_p50_ms vs
-        # serve_bin_p50_ms).
+        # the text parse actually buys (PERF.md §5 has both per request).
         parse_t = tel.timer("serve.parse")
         parse_bin_t = tel.timer("serve.parse_bin")
         # The HTTP worker's two other phases (obs.Phase: a timer and a

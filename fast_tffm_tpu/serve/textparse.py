@@ -6,8 +6,7 @@ per-example cost is amortized (PAPER.md §1).  The serving endpoint
 re-introduced that class of cost: ``parse_request`` walked the request
 body one line at a time through :func:`libsvm.parse_line`, paying a
 ``tok.split(":")`` + two regex fullmatches + three list appends per
-feature token (``serve.parse`` p50 ≈ 2.7x the binary transport's
-``serve.parse_bin`` decode on the bench bodies).
+feature token.
 
 This module is the batch rewrite, in the style of ``serve/wire.py``'s
 binary decode: tokenize the WHOLE body once, validate every token with

@@ -102,9 +102,10 @@ def _rid_bytes(request_id: str) -> bytes:
 
 def encode_bin_request(ids, vals, fields=None,
                        request_id=None) -> bytes:
-    """``[n, f]`` arrays -> one request frame (the client half; tests,
-    bench and the smoke build frames with it or from the documented
-    layout directly).  ``request_id`` adds the flags-bit-1 trailer."""
+    """``[n, f]`` arrays -> one request frame (the client half; tests
+    and ``chip_smoke.py`` build frames with it, ``benchmarks/`` from the
+    documented layout directly).  ``request_id`` adds the flags-bit-1
+    trailer."""
     ids = np.ascontiguousarray(ids, np.int32)
     vals = np.ascontiguousarray(vals, np.float32)
     if ids.shape != vals.shape or ids.ndim != 2:

@@ -613,7 +613,7 @@ class Trainer:
                 interaction=self._autotune.interaction,
             )
         # The user-facing name of the impl this run trains with —
-        # surfaced in the run header and bench JSON as `kernel_impl`.
+        # surfaced in the run header as `kernel_impl`.
         self.kernel_impl = autotune_lib.USER.get(
             self._dcfg.interaction_resolved, self._dcfg.interaction_resolved
         )
@@ -679,8 +679,8 @@ class Trainer:
         # boundaries).  train() always dispatches through this
         # (steps_per_dispatch == 1 is a scan of length 1, numerically
         # identical to the single step); _train_step stays for direct
-        # single-batch callers (bench step-only mode, tests) and carries
-        # no health.
+        # single-batch callers (tests, tools/parity_probe.py) and
+        # carries no health.
         self._super_batch_sh = Batch(**mesh_lib.super_batch_sharding(self.mesh))
         step_fn_health = (
             make_sparse_train_step(dcfg, self.mesh, with_health=True)
@@ -763,8 +763,7 @@ class Trainer:
         # bytes of THIS PROCESS's device state (with tiering on, the hot
         # tables).  Summed over addressable shards with replica dedupe —
         # equal to x.nbytes single-process, and ~1/R per rank for the
-        # P(MODEL)-sharded tables of a fleet (the bench's sharded-vs-
-        # global byte assertion reads exactly this).  The truth where
+        # P(MODEL)-sharded tables of a fleet.  The truth where
         # the backend reports it (memory_stats on TPU); this is the
         # documented CPU fallback, computed once.
         def leaf_bytes(x):
@@ -1118,14 +1117,14 @@ class Trainer:
         """One fused K-step dispatch (the hot-loop entry point).
 
         Keeps the historical ``(state, batches) -> state`` surface —
-        bench step timing and the resume tests wrap exactly this — while
-        threading the health carry through ``self._health`` (monitors
-        never change the TrainState math, so scan parity with K single
-        ``_train_step`` calls stays bitwise).  With the resource plane
-        on, dispatch goes through the AOT compile cache so the compile
-        sentinel sees every (re)compilation; the executable is the same
-        lowering jit would have produced, so the math is identical
-        either way."""
+        ``benchmarks/drivers/train.py`` and the resume tests wrap exactly
+        this — while threading the health carry through ``self._health``
+        (monitors never change the TrainState math, so scan parity with
+        K single ``_train_step`` calls stays bitwise).  With the
+        resource plane on, dispatch goes through the AOT compile cache
+        so the compile sentinel sees every (re)compilation; the
+        executable is the same lowering jit would have produced, so the
+        math is identical either way."""
         if self._sentinel is not None:
             fn = self._compiled_scan(state, batches)
         else:
@@ -2307,7 +2306,7 @@ class Trainer:
                     # window (dispatch + exchange at the barrier) is
                     # exactly the cost the overlap exists to remove,
                     # so the off/on pair of exchange_frac readings is
-                    # directly comparable (bench fleet_train's A/B).
+                    # directly comparable.
                     if exchange_probe is not None:
                         probe_out = exchange_probe()
                         if self._overlap_active:

@@ -149,22 +149,12 @@ def _rows_loss_fn(
             # forward math as fm.ffm_scores_from_rows, backward via the
             # shardmap inversion's closed form instead of autodiff
             # through the einsum chain — w0 enters linearly outside.
-            # FAST_TFFM_FFM_AUTODIFF=1 forces the autodiff oracle so the
-            # hardware sweep can time both in one window.
-            import os as _os
-
-            if _os.environ.get("FAST_TFFM_FFM_AUTODIFF") == "1":
-                scores = fm.ffm_scores_from_rows(
-                    w0, rows, batch.vals, batch.fields, cfg.factor_num,
+            scores = (
+                w0.astype(jnp.float32) + interaction.ffm_interaction(
+                    rows, batch.vals, batch.fields, cfg.factor_num,
                     cfg.field_num, compute_dtype,
-                ).astype(jnp.float32)
-            else:
-                scores = (
-                    w0.astype(jnp.float32) + interaction.ffm_interaction(
-                        rows, batch.vals, batch.fields, cfg.factor_num,
-                        cfg.field_num, compute_dtype,
-                    )
                 )
+            )
         else:
             scores = w0 + interaction.fm_interaction_sharded(
                 rows.astype(compute_dtype),

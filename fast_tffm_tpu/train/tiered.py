@@ -595,7 +595,8 @@ class TieredTable:
         # transfer thread blocked waiting for a write-back fill must be
         # released (the fill will never come) or shutdown joins forever.
         self._cancelled = False
-        # Occurrence-level cache accounting (the bench's hot_hit_frac).
+        # Occurrence-level cache accounting (the tiered block's
+        # hot_hit_frac).
         self._hit_occ = 0
         self._miss_occ = 0
         self._oor_occ = 0
@@ -605,7 +606,7 @@ class TieredTable:
         self._seen_rows = 0  # distinct logical ids ever resident
         # Mirrors never touch rows, so their counters must not inflate
         # this rank's tiered.* telemetry — the per-rank numbers are the
-        # ~1/R claim the fleet bench asserts.
+        # ~1/R claim tests/test_tiered_fleet.py asserts.
         if not self.rows_enabled:
             telemetry = None
         tel = telemetry if telemetry is not None else obs.NULL
@@ -1060,8 +1061,7 @@ class TieredTable:
                 # Storage-format identity of the cold rows: the dtype
                 # string is for report readers (non-numeric values are
                 # skipped by /metrics), the bytes-per-row gauge is the
-                # compaction factor the bench's quantized_table section
-                # compares across dtypes (fp32 = 4 * D).
+                # compaction factor across dtypes (fp32 = 4 * D).
                 "cold_dtype": self.codec.dtype,
                 "cold_bytes_per_row": int(self.codec.bytes_per_row),
             }

@@ -88,8 +88,7 @@ def _write_data(path, rng, lines=160, vocab=V):
 
 class TestResolve:
     def test_cpu_auto_is_reference_with_zero_measurement(self):
-        """The near-zero-overhead contract bench.py's
-        autotune_overhead budget prices: off-TPU `auto` must win by
+        """The near-zero-overhead contract: off-TPU `auto` must win by
         construction, not by benchmark."""
         n0 = autotune.measurement_count()
         d = autotune.resolve(_cfg(interaction_impl="auto"))

@@ -53,6 +53,7 @@ if _TOOLS not in sys.path:
     sys.path.insert(0, _TOOLS)
 
 import replay  # noqa: E402
+import report  # noqa: E402
 
 V = 256
 F = 4
@@ -308,6 +309,46 @@ class TestBlackbox:
         man = json.load(open(os.path.join(out, "manifest.json")))
         assert man["files"]["metrics.prom"] is False
         assert man["files"]["records.jsonl"] is True
+
+    def test_report_renders_bundle(self, tmp_path, capsys):
+        # ``report.py --incident`` on a bundle whose rings hold several
+        # readings of a signal: every section prints, the trajectory
+        # row sparklines oldest -> newest.
+        bb = _bb(
+            tmp_path,
+            trace_tail_fn=lambda n: [{"ph": "X", "name": "step", "dur": 7}],
+        )
+        for step, rss in enumerate((100.0, 150.0, 400.0), 1):
+            bb.observe_record({
+                "record": "heartbeat", "step": step,
+                "resource": {"rss_mb": rss, "uptime_s": float(step)},
+            })
+        bb.observe_alert({
+            "record": "alert", "rule": "uptime", "action": "incident",
+            "signal": "uptime_s", "value": 3.0, "op": ">",
+            "threshold": 2.0, "step": 3,
+        })
+        out = bb.incident("alert_uptime")
+        assert report.main(["--incident", out]) == 0
+        text = capsys.readouterr().out
+        assert "incident: alert_uptime" in text
+        assert "3 record(s), 1 alert(s)" in text
+        assert "signal trajectory" in text
+        rows = {ln.split()[0]: ln for ln in text.splitlines()
+                if ln.startswith("  ") and "->" in ln}
+        assert "100 -> 400" in rows["resource.rss_mb"]
+        assert "\u2581" in rows["resource.rss_mb"]
+        assert "\u2588" in rows["resource.rss_mb"]
+        assert "1 -> 3" in rows["uptime_s"] and "1 -> 3" in rows["step"]
+        assert "trace tail critical path" in text
+
+    def test_report_flat_signal_and_missing_manifest(self, tmp_path, capsys):
+        bb = _bb(tmp_path)
+        for _ in range(2):
+            bb.observe_record({"record": "heartbeat", "step": 5})
+        assert report.main(["--incident", bb.incident("flat")]) == 0
+        assert "5 -> 5" in capsys.readouterr().out
+        assert report.main(["--incident", str(tmp_path / "absent")]) == 1
 
 
 # ----------------------------------------------------------------------

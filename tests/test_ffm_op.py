@@ -101,8 +101,8 @@ def test_ffm_op_matches_oracle_same_compute_dtype():
     operands (the self-term/cross diagonal cancellation is where an
     operand-rounding mismatch shows up).  Off-TPU both gates fall back
     to f32 via platform.ffm_compute_dtype, so this pins the shared
-    operand plumbing; the bf16-vs-bf16 comparison reruns on chip via
-    tpu_validate's FFM combos."""
+    operand plumbing; the bf16-vs-bf16 comparison has no chip run yet
+    (no field-aware cell: PERF.md §7)."""
     rows, vals, fields, g = _data(4)
     cd = jnp.bfloat16
     rows_c = rows.astype(cd)

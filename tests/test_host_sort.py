@@ -144,7 +144,7 @@ def test_trainer_attaches_meta_and_matches(tmp_path, monkeypatch):
 
     Pinned to a one-device mesh (the conftest's 8 virtual devices would
     select the sharded apply, where host meta deliberately stays off) —
-    this mirrors the single-chip TPU bench configuration."""
+    this mirrors a single-chip TPU run."""
     from jax.sharding import Mesh
 
     from fast_tffm_tpu.config import FmConfig

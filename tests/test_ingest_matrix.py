@@ -23,9 +23,15 @@ from fast_tffm_tpu.data.pipeline import BatchPipeline, EpochEnd, SuperBatch
 
 
 def _shm_listing():
+    """This process's pipeline segments only: every one carries the
+    parent's pid in its tag (procpool.make_shm_tag), so another xdist
+    worker's live ring never reads as a leak here.  Untagged ``psm_*``
+    segments are out of scope on purpose: procpool creates none (every
+    ``SharedMemory(create=True)`` there passes a tag), and one seen in
+    /dev/shm cannot be told from another worker's."""
     return {
         n for n in os.listdir("/dev/shm")
-        if n.startswith(("psm_", "tffm"))
+        if n.startswith(f"tffm{os.getpid()}p")
     }
 
 

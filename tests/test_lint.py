@@ -9,9 +9,8 @@ Three layers, all tier-1:
 * framework mechanics: baseline suppression (new vs grandfathered vs
   stale), inline ``# lint: disable=`` comments, the CLI exit code;
 * the live tree: ``lint.run(repo_root)`` must report no NEW findings
-  and no stale baseline entries — the same gate tools/verify.sh and
-  bench preflight run, so a finding introduced by any future PR fails
-  here first.
+  and no stale baseline entries — the same gate tools/verify.sh
+  runs, so a finding introduced by any future PR fails here first.
 
 Plus the lint-adjacent runtime gate: importing every package module
 must raise no deprecation-class warning attributed to package files

@@ -113,16 +113,27 @@ def test_pipeline_raises_on_missing_weight_file(data_files):
         list(pipe)
 
 
+def _raw_groups(files, batch_size, **kw):
+    """File-order groups of <= batch_size lines, sliced from windows the
+    way BatchPipeline does when it does not shuffle."""
+    from fast_tffm_tpu.data.pipeline import _iter_raw_windows
+
+    for buf, starts, ends in _iter_raw_windows(
+        files, batch_size, batch_size, **kw
+    ):
+        for i in range(0, len(starts), batch_size):
+            yield buf, starts[i:i + batch_size], ends[i:i + batch_size]
+
+
 def test_raw_groups_cross_chunk_boundaries(tmp_path):
     """Fast-ingest chunking must carry partial lines/groups across reads."""
-    from fast_tffm_tpu.data.pipeline import _iter_raw_groups
     from fast_tffm_tpu.data import native
 
     path = tmp_path / "d.libsvm"
     lines = [f"1 {i}:1.0" for i in range(257)]
     path.write_text("\n".join(lines) + "\n")
     # Absurdly small chunk size forces many boundary crossings.
-    groups = list(_iter_raw_groups([str(path)], batch_size=10, chunk_bytes=17))
+    groups = list(_raw_groups([str(path)], batch_size=10, chunk_bytes=17))
     parser = native.NativeParser(1000, 4, num_threads=1)
     got = []
     for buf, starts, ends in groups:
@@ -135,14 +146,13 @@ def test_raw_groups_cross_chunk_boundaries(tmp_path):
 def test_raw_groups_pack_across_file_boundaries(tmp_path):
     """Batches pack across files (like the line path); a missing trailing
     newline at a file boundary must not merge lines."""
-    from fast_tffm_tpu.data.pipeline import _iter_raw_groups
     from fast_tffm_tpu.data import native
 
     a = tmp_path / "a.libsvm"
     a.write_bytes(b"1 0:1.0\n1 1:1.0\n1 2:1.0")  # no trailing newline
     b = tmp_path / "b.libsvm"
     b.write_bytes(b"1 3:1.0\n1 4:1.0\n1 5:1.0\n1 6:1.0\n")
-    groups = list(_iter_raw_groups([str(a), str(b)], batch_size=4))
+    groups = list(_raw_groups([str(a), str(b)], batch_size=4))
     parser = native.NativeParser(1000, 4, num_threads=1)
     batches = [parser.parse_raw(buf, s, e, 4) for buf, s, e in groups]
     # 7 lines -> one full group of 4 (spanning the file boundary) + tail 3.

@@ -2,14 +2,14 @@
 
 Interpret-mode tests check kernel semantics but structurally cannot catch
 Mosaic lowering errors — "Unimplemented primitive in Pallas TPU lowering"
-aborted the round-3 hardware bench (scatter-add at the old
+aborted the round-3 hardware run (scatter-add at the old
 sparse_apply K1 carry add) while every interpret test passed.  Mosaic's
 jaxpr->MLIR pass runs at jax LOWERING time, so ``jax.export`` with
 ``platforms=['tpu']`` under ``platform.force_compiled()`` surfaces that
 entire failure class on this CPU-only machine.
 
 Every Pallas entry point must have a case here; a new kernel without one
-is unprotected against exactly the bug class that zeroed BENCH_r03.
+is unprotected against exactly the bug class that lost that run.
 """
 
 from __future__ import annotations

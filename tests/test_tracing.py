@@ -20,13 +20,14 @@ Pins the tentpole guarantees:
   * a crashed run's metrics stream still ends with a ``final`` record
     (exception type + partial counters) — the try/finally contract
     tools/report.py relies on;
-  * tools/check_tier1.py (the marker audit bench.py preflights) and
+  * tools/check_tier1.py (the marker audit behind lint rule T1001) and
     tools/report.py --compare behave.
 """
 
 import json
 import os
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -754,7 +755,7 @@ class TestCrashTruthfulFinal:
 
 
 # ---------------------------------------------------------------------------
-# tools/check_tier1.py — the marker audit bench.py preflights
+# tools/check_tier1.py — the marker audit behind lint rule T1001
 # ---------------------------------------------------------------------------
 
 
@@ -959,6 +960,21 @@ class TestServeTrace:
             paths.append(p)
         return paths
 
+    def _chains_of(self, fleet, rid):
+        """Merged events and the chains of one request.  The replica
+        emits ``serve.respond`` AFTER it wrote the reply (the span
+        covers the write), so the client can hold the reply before the
+        span exists: dump again until the chain closes, 5 s at most."""
+        deadline = time.monotonic() + 5.0
+        while True:
+            events, _, _ = report.merge_traces(self._dump_all(fleet))
+            mine = [c for c in report.serve_request_chains(events)
+                    if c["rid"] == rid]
+            if (mine and mine[0]["complete"]) \
+                    or time.monotonic() > deadline:
+                return events, mine
+            time.sleep(0.02)
+
     def test_sampled_request_chain_is_complete(self, fleet):
         status, body, hdrs = fleet["post"](
             "/score", b"1 3:1\n0 2:0.5\n"
@@ -967,10 +983,7 @@ class TestServeTrace:
         rid = hdrs.get("X-Request-Id")
         assert rid, "sampled request lost its id echo"
         assert len(body.decode().split()) == 2
-        paths = self._dump_all(fleet)
-        events, _, _ = report.merge_traces(paths)
-        chains = report.serve_request_chains(events)
-        mine = [c for c in chains if c["rid"] == rid]
+        events, mine = self._chains_of(fleet, rid)
         assert len(mine) == 1
         chain = mine[0]
         assert chain["complete"], (
@@ -1008,12 +1021,7 @@ class TestServeTrace:
         rid = hdrs.get("X-Request-Id")
         assert rid
         assert len(wire.decode_bin_response(body)) == 2
-        paths = self._dump_all(fleet)
-        events, _, _ = report.merge_traces(paths)
-        chains = [
-            c for c in report.serve_request_chains(events)
-            if c["rid"] == rid
-        ]
+        _, chains = self._chains_of(fleet, rid)
         assert len(chains) == 1 and chains[0]["complete"], (
             f"bin chain: {sorted(chains[0]['spans']) if chains else []}"
         )

@@ -16,8 +16,8 @@ Two modes:
   the autotuner's own invariants on the current backend —
 
   1. on CPU, ``auto`` must resolve to ``reference`` WITHOUT running a
-     single measurement (the near-zero-overhead contract the
-     ``autotune_overhead`` bench budget pins);
+     single measurement (the near-zero-overhead contract
+     tests/test_autotune.py pins);
   2. a forced multi-candidate measurement must pick a parity-gated
      winner and a second resolve must hit the cache (0 additional
      measurements);

@@ -6,8 +6,8 @@ The tier-1 filter is the repo's correctness gate (ROADMAP.md).  Its
 failure mode is silent: a test file whose every test carries (or
 inherits) ``pytest.mark.slow`` simply stops being collected — nothing
 fails, coverage just evaporates.  This tool audits the markers
-STATICALLY (AST; no imports, no jax, runs in milliseconds) so bench.py
-can run it as a preflight and CI can gate on it:
+STATICALLY (AST; no imports, no jax, runs in milliseconds) so it can
+gate before anything jax-heavy runs:
 
   python tools/check_tier1.py            # audit ./tests, exit 1 on drift
   python tools/check_tier1.py --list     # per-file tier-1/slow counts

@@ -5,7 +5,7 @@ exits nonzero on any NEW finding (one not grandfathered by
 ``tools/lint/baseline.txt``).  See LINTING.md for the rule catalog and
 how to add a rule.
 
-Programmatic use (bench preflight, tests)::
+Programmatic use (tests)::
 
     from tools import lint
     result = lint.run(root=".")          # default rules + baseline

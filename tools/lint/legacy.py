@@ -2,7 +2,7 @@
 
 tools/check_tier1.py (tier-1 marker audit) and tools/check_obs.py
 (metric-name drift) predate the framework and stay importable on their
-own (bench.py's preflight imports check_tier1 directly), but
+own (tests/test_tracing.py imports check_tier1 directly), but
 ``python -m tools.lint`` is now the one entry point: their findings
 flow through the same baseline / exit-code machinery as every other
 rule.

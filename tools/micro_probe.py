@@ -1,10 +1,10 @@
 #!/usr/bin/env python
 """On-chip micro-experiments behind the step-time hot spots.
 
-The first v5e run (TPU_RESULTS.md) showed three XLA-side costs dwarfing
-the kernels: the 640k-row table gather (16.8 ms), the id sort (10.8 ms)
-and a length-640k cumsum (4.7 ms).  Each experiment here isolates one
-design question for those:
+The first v5e run (Criteo-Kaggle shapes, before PERF.md's benchmark
+existed) showed three XLA-side costs dwarfing the kernels: the 640k-row
+table gather, the id sort and a length-640k cumsum.  Each experiment
+here isolates one design question for those:
 
   gather:  does row width (burst size) or index sortedness change the
            achieved row rate?  Decides whether packing the table to
@@ -17,20 +17,21 @@ design question for those:
            per device; 32- vs 64-bit keys tests packing id+perm into
            one key as an alternative to sort_key_val).
 
-Timing matches tools/tpu_validate.py: scalar readback drains.
+Timing: completion is forced by fetching one scalar from every output
+leaf (``bench`` / ``_drain`` below).  ``k2t_apply`` / ``k2p_apply`` are
+the apply-kernel candidates of ROADMAP.md Speed 1;
+tests/test_tpu_lowering.py keeps them lowering.
 """
 
 from __future__ import annotations
 
 import os
 import sys
+import time
 
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-
-from timing import bench, drain  # noqa: E402
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -43,6 +44,25 @@ import jax.experimental.pallas.tpu as pltpu
 
 from fast_tffm_tpu.ops import sparse_apply as sa
 from fast_tffm_tpu.platform import use_interpret
+
+
+def _drain(tree) -> None:
+    for leaf in jax.tree.leaves(tree):
+        np.asarray(jax.device_get(
+            leaf.reshape(-1)[:1] if hasattr(leaf, "reshape") else leaf
+        ))
+
+
+def bench(fn, *args, steps=20):
+    for _ in range(2):
+        _drain(fn(*args))
+    t0 = time.perf_counter()
+    r = None
+    for _ in range(steps):
+        r = fn(*args)
+    _drain(r)
+    return (time.perf_counter() - t0) * 1e3 / steps
+
 
 def _k2t_kernel(ts_ref, table_ref, acc_ref, u_hbm_ref, table_out_ref,
                 acc_out_ref, u_vmem, sem, *, tile, group, d, lr, eps):

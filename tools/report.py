@@ -36,16 +36,16 @@ Three modes (see OBSERVABILITY.md):
    straggler section attributes each chain segment (parse / stack /
    h2d / dispatch) to the slowest rank.
 
-3. ``--compare A B``: ratio-diff two runs — metrics JSONLs or bench
-   JSONs (BENCH_rN.json) — and flag regressions beyond ``--threshold``
-   (default 5%).  Rates/ratios regress when they FALL; times/fractions
-   /losses regress when they RISE.  ``--threshold`` repeats for
-   per-key overrides (``--threshold ingest_wait_frac=0.10 --threshold
-   default=0.05``), so noisy keys get slack without loosening the whole
-   gate.  Alert records (``record: alert``, the watchdog's output)
+3. ``--compare A B``: ratio-diff two runs — metrics JSONLs, or single
+   JSON objects carrying a ``metric`` key — and flag regressions beyond
+   ``--threshold`` (default 5%).  Rates/ratios regress when they FALL;
+   times/fractions/losses regress when they RISE.  ``--threshold``
+   repeats for per-key overrides (``--threshold ingest_wait_frac=0.10
+   --threshold default=0.05``), so noisy keys get slack without
+   loosening the whole gate.  Alert records (``record: alert``, the watchdog's output)
    contribute ``alerts_total`` / per-rule counts — a run that starts
    alerting is itself a regression.  Exit code 2 when any regression is
-   flagged, so the BENCH trajectory check stops being eyeball-only.
+   flagged.
 
 4. ``--incident DIR``: human summary of one blackbox forensic bundle
    (``incidents/<ts>_<reason>/``, see OBSERVABILITY.md "Incidents &
@@ -64,7 +64,6 @@ import argparse
 import bisect
 import json
 import os
-import re
 import sys
 import time
 
@@ -981,7 +980,7 @@ def trace_mode(paths: list, out: str, limit: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# --compare: ratio-diff two runs (metrics JSONLs or bench JSONs)
+# --compare: ratio-diff two runs (metrics JSONLs or single metric JSONs)
 # ---------------------------------------------------------------------------
 
 # Direction heuristics: which way is a regression?  Rates and hit
@@ -1173,10 +1172,6 @@ _DIRECTION_OVERRIDES = {
     "fleet_sharded_examples_per_sec": "high",
     "fleet_global_examples_per_sec": None,
     "fleet_tier_shards": None,
-    # Bench preflight (--timeline over the BENCH_r*.json stack): any
-    # key whose trend already crossed its threshold counts here — a
-    # new one appearing is itself a regression signal.
-    "timeline_regressions": "low",
     # Incident flight recorder (ISSUE 20): the traffic-capture cost
     # ratio (off/on qps, same paired shape as the trace/quality/fleet
     # probes) regresses when it RISES past the 1.05 budget; how many
@@ -1209,10 +1204,9 @@ def _direction(key: str):
 def _comparable_metrics(path: str) -> dict:
     """Flatten one artifact into {key: number}.
 
-    Bench JSONs (one object with a ``metric`` key, e.g. BENCH_rN.json)
-    contribute their numeric top-level keys.  Metrics JSONLs contribute
-    the final record's attribution + health and the last train record's
-    rate/loss/auc.
+    A single JSON object with a ``metric`` key contributes its numeric
+    top-level keys.  Metrics JSONLs contribute the final record's
+    attribution + health and the last train record's rate/loss/auc.
     """
     with open(path) as f:
         first = f.readline()
@@ -1221,7 +1215,7 @@ def _comparable_metrics(path: str) -> dict:
         doc = json.loads(first + rest)
     except ValueError:
         doc = None
-    if isinstance(doc, dict) and "metric" in doc:  # bench JSON
+    if isinstance(doc, dict) and "metric" in doc:
         return {
             k: float(v) for k, v in doc.items()
             if isinstance(v, (int, float)) and not isinstance(v, bool)
@@ -1321,9 +1315,8 @@ def parse_thresholds(values) -> dict:
 
     Accepted forms (repeatable, later wins): a bare float (``0.07`` —
     sets the default, the historical spelling), ``default=0.05``, and
-    per-key overrides (``ingest_wait_frac=0.10``).  The watchdog and
-    the bench gates share one regression vocabulary this way: the same
-    key names that appear in ``--compare`` output key the overrides.
+    per-key overrides (``ingest_wait_frac=0.10``).  The same key names
+    that appear in ``--compare`` output key the overrides.
     """
     out = {"default": 0.05}
     for raw in values or []:
@@ -1393,130 +1386,6 @@ def compare_mode(path_a: str, path_b: str, thresholds: dict) -> int:
     return 0
 
 
-_SPARK_BLOCKS = "▁▂▃▄▅▆▇█"
-
-
-def _sparkline(vals: list) -> str:
-    lo, hi = min(vals), max(vals)
-    if hi == lo:
-        return _SPARK_BLOCKS[3] * len(vals)
-    span = hi - lo
-    return "".join(
-        _SPARK_BLOCKS[
-            min(len(_SPARK_BLOCKS) - 1,
-                int((v - lo) / span * len(_SPARK_BLOCKS)))
-        ]
-        for v in vals
-    )
-
-
-def _bench_order(path: str):
-    """Sort key putting BENCH_r2 before BENCH_r10 (numeric round when
-    the name carries one, lexical otherwise)."""
-    m = re.search(r"_r(\d+)\D*\.json$", os.path.basename(path))
-    return (0, int(m.group(1)), path) if m else (1, 0, path)
-
-
-def _timeline_series(paths: list, log=None) -> tuple:
-    """Load a bench-JSON stack into ``(labels, {key: [(label, val),
-    ...]})`` — numeric top-level keys only, unreadable/stub rounds
-    skipped (``log`` gets one line per skip when provided)."""
-    series: dict = {}
-    labels = []
-    for path in sorted(paths, key=_bench_order):
-        try:
-            with open(path) as f:
-                doc = json.load(f)
-        except (OSError, ValueError) as e:
-            if log:
-                log(f"{path}: unreadable ({e}); skipped")
-            continue
-        if not isinstance(doc, dict) or "metric" not in doc:
-            # Harness stubs from rounds where the bench never ran
-            # (rc!=0 wrappers) carry no metric keys — skip, don't
-            # fake a flat round.
-            if log:
-                log(f"{os.path.basename(path)}: no bench metrics; "
-                    f"skipped")
-            continue
-        label = os.path.basename(path)
-        labels.append(label)
-        for key, val in doc.items():
-            if isinstance(val, (int, float)) and not isinstance(
-                val, bool
-            ):
-                series.setdefault(key, []).append((label, float(val)))
-    return labels, series
-
-
-def timeline_regressions(paths: list, thresholds: dict = None) -> dict:
-    """Machine-readable first-regression attribution over a bench-JSON
-    stack — the same adjacent-step rule ``--timeline`` prints, for
-    callers that gate on it (bench.py preflight records the count).
-    Returns ``{"rounds": N, "regressions": {key: "rA -> rB (1.23x)"}}``
-    (empty regressions when fewer than two readable rounds)."""
-    thresholds = thresholds or {}
-    default = thresholds.get("default", 0.05)
-    labels, series = _timeline_series(paths)
-    out: dict = {"rounds": len(labels), "regressions": {}}
-    if len(labels) < 2:
-        return out
-    for key in sorted(series):
-        points = series[key]
-        if len(points) < 2:
-            continue
-        direction = _direction(key)
-        threshold = thresholds.get(key, default)
-        for (lab_a, va), (lab_b, vb) in zip(points, points[1:]):
-            if va == 0 and vb == 0:
-                continue
-            ratio = vb / va if va else float("inf")
-            if (
-                (direction == "low" and ratio > 1 + threshold)
-                or (direction == "high" and ratio < 1 - threshold)
-                or (direction == "both" and not (
-                    1 - threshold <= ratio <= 1 + threshold))
-            ):
-                rs = (f"{ratio:.2f}x" if ratio != float("inf")
-                      else "inf")
-                out["regressions"][key] = f"{lab_a} -> {lab_b} ({rs})"
-                break
-    return out
-
-
-def timeline_mode(paths: list, thresholds: dict) -> int:
-    """Trend view over a stack of bench JSONs (BENCH_rN.json): one
-    sparkline row per shared key plus first-regression attribution —
-    the earliest round whose step beyond ``--threshold`` moved in the
-    regressing direction for that key (same direction vocabulary as
-    ``--compare``).  Informational: always exits 0."""
-    default = thresholds.get("default", 0.05)
-    labels, series = _timeline_series(paths, log=print)
-    if len(labels) < 2:
-        print("--timeline needs at least two readable bench JSONs")
-        return 1
-    culprits = timeline_regressions(paths, thresholds)["regressions"]
-    print(f"timeline over {len(labels)} rounds: "
-          f"{labels[0]} .. {labels[-1]} "
-          f"(step threshold {default:.0%})")
-    print(f"  {'key':34} {'trend':>{max(5, len(labels))}} "
-          f"{'first':>10} {'last':>10} {'l/f':>7}  first regression")
-    for key in sorted(series):
-        points = series[key]
-        if len(points) < 2:
-            continue
-        vals = [v for _lab, v in points]
-        # First-regression attribution: the earliest adjacent step
-        # whose ratio moved beyond the threshold the WRONG way
-        # (timeline_regressions is the single rule source).
-        culprit = culprits.get(key, "")
-        lf = vals[-1] / vals[0] if vals[0] else float("inf")
-        lfs = f"{lf:7.3f}" if lf != float("inf") else "    inf"
-        print(f"  {key:34} {_sparkline(vals):>{max(5, len(labels))}} "
-              f"{vals[0]:>10.4g} {vals[-1]:>10.4g} {lfs}  {culprit}")
-    return 0
-
-
 def _dig_numeric(rec: dict, dotted: str):
     """Resolve a dotted signal path (``serve.qps``) against one
     record; bare spellings fall back to the standard blocks the alert
@@ -1539,6 +1408,23 @@ def _dig_numeric(rec: dict, dotted: str):
     if isinstance(val, bool) or not isinstance(val, (int, float)):
         return None
     return float(val)
+
+
+_SPARK_BLOCKS = "▁▂▃▄▅▆▇█"
+
+
+def _sparkline(vals: list) -> str:
+    lo, hi = min(vals), max(vals)
+    if hi == lo:
+        return _SPARK_BLOCKS[3] * len(vals)
+    span = hi - lo
+    return "".join(
+        _SPARK_BLOCKS[
+            min(len(_SPARK_BLOCKS) - 1,
+                int((v - lo) / span * len(_SPARK_BLOCKS)))
+        ]
+        for v in vals
+    )
 
 
 def incident_mode(path: str, limit: int = 8) -> int:
@@ -1701,12 +1587,7 @@ def main(argv=None) -> int:
                          "<first>.merged.json)")
     ap.add_argument("--compare", action="store_true",
                     help="ratio-diff exactly two runs (metrics JSONLs "
-                         "or bench JSONs); exit 2 on regression")
-    ap.add_argument("--timeline", action="store_true",
-                    help="trend view over a stack of bench JSONs "
-                         "(BENCH_r*.json): per-key sparkline + "
-                         "first-regression attribution using the "
-                         "--compare direction vocabulary")
+                         "or single metric JSONs); exit 2 on regression")
     ap.add_argument("--incident", action="store_true",
                     help="treat the single path as a blackbox incident "
                          "bundle dir (incidents/<ts>_<reason>/): print "
@@ -1729,10 +1610,6 @@ def main(argv=None) -> int:
         return serve_trace_mode(args.paths, args.out, args.limit)
     if args.trace:
         return trace_mode(args.paths, args.out, args.limit)
-    if args.timeline:
-        return timeline_mode(
-            args.paths, parse_thresholds(args.threshold)
-        )
     if args.compare:
         if len(args.paths) != 2:
             ap.error("--compare takes exactly two paths")

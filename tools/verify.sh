@@ -1,9 +1,9 @@
 #!/usr/bin/env bash
 # One-command correctness gate: the static audits (tier-1 markers, obs
 # metric-name drift), the live-observability smoke, and the PINNED
-# tier-1 pytest invocation from ROADMAP.md — builders and bench
-# preflight run the exact same thing, so "it passed locally" and "the
-# gate passed" can never mean different commands.
+# tier-1 pytest selection of ROADMAP.md under the driver's flags — one
+# command, so "it passed locally" and "the gate passed" can never mean
+# different commands.
 #
 #   tools/verify.sh            # lint + obs smoke + full tier-1 suite
 #   tools/verify.sh --audit    # static analysis only (milliseconds, no jax)
@@ -14,6 +14,7 @@
 
 set -u
 cd "$(dirname "$0")/.."
+tmp="${TMPDIR:-/tmp}"
 
 echo "== static analysis (python -m tools.lint; rule catalog: LINTING.md) =="
 # All seven analyzers: thread/queue/SHM/server lifecycle, donation/
@@ -41,9 +42,9 @@ echo "== fleet parity gate (tools/parity_probe.py --fleet-gate) =="
 # Two real gloo ranks (one model column each) vs the single-process
 # (1x2) reference: per-shard table hashes must match bitwise at init
 # and after each of 3 dispatches.  Catches cross-process init drift
-# and step drift in seconds, long before a full fleet bench would.
+# and step drift in seconds, long before a full fleet run would.
 JAX_PLATFORMS=cpu python tools/parity_probe.py --fleet-gate \
-    --dispatches 3 --out /tmp/_fleet_gate.jsonl || exit 1
+    --dispatches 3 --out "$tmp/_fleet_gate.jsonl" || exit 1
 
 echo
 echo "== live observability + serving smoke (tools/obs_smoke.py) =="
@@ -69,13 +70,13 @@ echo "== quantized-table smoke (tools/quant_smoke.py) =="
 JAX_PLATFORMS=cpu python tools/quant_smoke.py || exit 1
 
 echo
-echo "== tier-1 pytest (the driver's flags: 6 xdist workers, loadfile) =="
+echo "== tier-1 pytest (the driver's flags: 6 xdist workers, --dist load) =="
 set -o pipefail
-rm -f /tmp/_t1.log
+rm -f "$tmp/_t1.log"
 timeout -k 10 1470 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
     -m 'not slow' --continue-on-collection-errors -p no:cacheprovider \
-    -p xdist -n 6 --dist loadfile -p no:randomly 2>&1 | tee /tmp/_t1.log
+    -p xdist -n 6 --dist load -p no:randomly 2>&1 | tee "$tmp/_t1.log"
 rc=${PIPESTATUS[0]}
-echo "DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log \
+echo "DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' "$tmp/_t1.log" \
     | tr -cd . | wc -c)"
 exit $rc
