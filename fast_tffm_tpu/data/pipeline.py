@@ -152,6 +152,13 @@ class _ClosableQueue:
         read of a deque length is exact enough for a gauge)."""
         return len(self._items)
 
+    def snapshot(self) -> tuple:
+        """The items queued now, oldest first, none taken: what a sole
+        consumer's next ``get`` calls return without waiting (unless a
+        ``cancel`` comes between)."""
+        with self._cv:
+            return tuple(self._items)
+
 
 def _read_weight_file(path: str) -> list[str]:
     # Keep EVERY line (even blanks) so weight line i pairs with data line i;

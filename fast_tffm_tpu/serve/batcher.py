@@ -14,14 +14,33 @@ fires.
 
 The dispatcher fills its own recycled per-rung staging buffers
 directly (one row copy per example, no per-request concatenation —
-the prefetcher's staging-pool discipline applied to requests), runs
-ONE scorer dispatch, then splits the scores back per request and
-releases the waiting client threads.  Because dispatches are serial
-and the scorer resolves its model reference once per dispatch, a hot
-swap can never interleave old and new params inside one microbatch.
+the prefetcher's staging-pool discipline applied to requests),
+LAUNCHES one scorer dispatch, and later LANDS it: reads the scores
+back, splits them per request and releases the waiting client threads.
+Because launches are serial and the scorer resolves its model reference
+once per launch, a hot swap can never interleave old and new params
+inside one microbatch.
+
+One group in flight.  The two halves of a dispatch
+(``scorer.launch_rung`` / ``scorer.read_rung``) need not be adjacent:
+with group n launched, the dispatcher looks at what the queue holds
+(without taking or waiting).  If that closes a group at once — it fills
+the max rung, or holds a request that does not fit behind those before
+it — group n+1 is coalesced, filled and launched FIRST and n is landed
+while the device runs n+1.  If not, n is landed first and only then
+does the dispatcher wait (batch deadline or empty queue): an answer that
+is ready never waits behind a wait.  At most one group is ever in
+flight when the dispatcher turns to the queue, replies leave in launch
+order, a failure in one group's read fails that group alone, and an
+oversized lone request (scored by ``scorer.score()``, blocking) and
+``close()`` land the group in flight first.  The staging buffers come
+in pairs a rung, taken in turn: the set filled last may still be feeding
+the group in flight.
 
 Instruments (all ``serve.*``, documented in OBSERVABILITY.md):
-``requests`` / ``examples`` / ``batches`` counters, the ``latency``
+``requests`` / ``examples`` / ``batches`` counters, ``overlapped``
+(groups launched while another was in flight; over ``batches`` it is the
+share of dispatches whose device time was hidden), the ``latency``
 timer (enqueue -> scores delivered; p50/p95/p99 ride every snapshot),
 the ``queue_wait`` timer (enqueue -> picked, every request), the
 ``batch_fill`` gauge (cumulative filled/dispatched slots), and the
@@ -31,8 +50,9 @@ The dispatcher's work is tiled by phases, each an ``obs.Phase`` (a
 ``serve.<phase>`` timer and a ``tffm:serve.<phase>`` annotation on the
 device trace's clock): ``coalesce`` (first request picked -> group
 closed: the batcher's own deliberate wait), ``fill`` (group -> staging
-buffers), the scorer's ``launch`` / ``readback``, ``deliver`` (scores
-split, every waiter released) and ``quality`` (the skew-sketch fold).
+buffers), the scorer's ``launch`` and — for this group or, with one in
+flight, the group before — ``readback``, ``deliver`` (scores split,
+every waiter released) and ``quality`` (the skew-sketch fold).
 The wait on an empty queue carries no annotation: a span over a wait
 for another thread would cover whole idle gaps of the device and hide
 what the working thread did.
@@ -41,10 +61,11 @@ Distributed tracing: a request carrying a request id (``rid``, from
 the ``X-Request-Id`` header or the binary frame's trailer on a SAMPLED
 request) gets per-request spans — ``serve.queue_wait`` (enqueue ->
 picked by the dispatcher), ``serve.coalesce`` (picked -> its group
-closed) and ``serve.dispatch`` (fill + the rung dispatch, with
-``launch_ms`` / ``readback_ms`` in its args and a flow step on the
-rid) — emitted AFTER the dispatch from the timestamps the phases
-already took.
+closed) and ``serve.dispatch`` (group closed -> its scores in host
+memory: fill, the rung's two halves and, with a group in flight, the
+landing of the group before; ``launch_ms`` / ``readback_ms`` of ITS
+group in the args and a flow step on the rid) — emitted AFTER the
+dispatch from the timestamps the phases already took.
 """
 
 from __future__ import annotations
@@ -110,8 +131,23 @@ class ScoreRequest:
                 log.warning("on_done release hook failed: %s", e)
 
 
+class _Group:
+    """One closed group on its way through the dispatcher: filled and
+    launched (``flight``), later landed."""
+
+    __slots__ = ("reqs", "total", "t_closed", "rung", "slots", "flight",
+                 "launch_s", "readback_s")
+
+    def __init__(self, reqs, total: int, t_closed: float):
+        self.reqs = reqs
+        self.total = total
+        self.t_closed = t_closed
+        self.flight = None
+
+
 class ServeBatcher:
-    """Coalesce requests into microbatches under a latency deadline."""
+    """Coalesce requests into microbatches under a latency deadline,
+    one group in flight (module docstring)."""
 
     def __init__(self, scorer, max_batch_wait_ms: float = 2.0,
                  queue_size: int = 1024, telemetry=None, tracer=None,
@@ -131,15 +167,17 @@ class ServeBatcher:
         self._c_requests = tel.counter("serve.requests")
         self._c_examples = tel.counter("serve.examples")
         self._c_batches = tel.counter("serve.batches")
+        self._c_overlapped = tel.counter("serve.overlapped")
         self._t_latency = tel.timer("serve.latency")
         self._t_queue_wait = tel.timer("serve.queue_wait")
         self._t_coalesce = tel.timer("serve.coalesce")
         self._t_fill = tel.timer("serve.fill")
         self._t_deliver = tel.timer("serve.deliver")
         self._t_quality = tel.timer("serve.quality")
-        # The scorer's two phase timers (same registry, same names): a
-        # sampled request's serve.dispatch span reads its dispatch's
-        # share as the difference of their totals (0 with telemetry off).
+        # The scorer's two phase timers (same registry, same names): an
+        # OVERSIZED request's serve.dispatch span reads its chunks' share
+        # as the difference of their totals (nothing is in flight then;
+        # 0 with telemetry off).  A rung's group reads its own flight.
         self._t_launch = tel.timer("serve.launch")
         self._t_readback = tel.timer("serve.readback")
         self._g_fill = tel.gauge("serve.batch_fill")
@@ -150,11 +188,11 @@ class ServeBatcher:
         self._q = _ClosableQueue(
             queue_size, hist=tel.depth_hist("serve.queue_depth")
         )
-        # The batcher's OWN recycled per-rung staging buffers.  It must
-        # not borrow the scorer's pools: those are guarded by the
-        # scorer's dispatch lock, and the dispatcher fills buffers
-        # BEFORE taking that lock — sharing them would let a direct
-        # scorer.score() caller race the fill.
+        # The batcher's OWN recycled per-rung staging buffers, two sets
+        # a rung (_pool).  It must not borrow the scorer's pools: those
+        # are guarded by the scorer's dispatch lock, and the dispatcher
+        # fills buffers BEFORE taking that lock — sharing them would let
+        # a direct scorer.score() caller race the fill.
         self._pools: dict = {}
         # Fill accounting (dispatcher thread only): real examples vs
         # padded slots over every dispatched rung.
@@ -236,23 +274,55 @@ class ServeBatcher:
         return self._filled / self._slots if self._slots else 0.0
 
     def _pool(self, b: int):
-        bufs = self._pools.get(b)
-        if bufs is None:
+        """The staging buffers to fill for rung ``b``: the set NOT
+        handed out last.  A launch returns while the transfer may still
+        read its numpy arguments, and the group before may be in flight
+        on the other set; with at most one group in flight, the set this
+        returns was read back already."""
+        pair = self._pools.get(b)
+        if pair is None:
             F = self._scorer.cfg.max_features
-            bufs = (
-                np.zeros((b, F), np.int32),
-                np.zeros((b, F), np.float32),
-                np.zeros((b, F), np.int32),
-            )
-            self._pools[b] = bufs
-        return bufs
+            pair = self._pools[b] = [
+                (
+                    np.zeros((b, F), np.int32),
+                    np.zeros((b, F), np.float32),
+                    np.zeros((b, F), np.int32),
+                )
+                for _ in range(2)
+            ]
+        pair.reverse()
+        return pair[0]
 
     # -- dispatcher thread ---------------------------------------------
+
+    def _closes_at_once(self, pending: Optional[ScoreRequest]) -> bool:
+        """Whether what is off the queue (``pending``) and on it closes
+        a group with no wait: it fills the max rung (an oversized lone
+        request does), or holds a request that does not fit behind
+        those before it.  Looks, takes nothing."""
+        max_b = self._scorer.max_rung
+        total = 0
+        waiting = self._q.snapshot()
+        if pending is not None:
+            waiting = (pending,) + waiting
+        for req in waiting:
+            if total and total + req.n > max_b:
+                return True
+            total += req.n
+            if total >= max_b:
+                return True
+        return False
 
     def _run(self) -> None:
         max_b = self._scorer.max_rung
         pending: Optional[ScoreRequest] = None
+        flying: Optional[_Group] = None  # launched, not yet read back
         while True:
+            if flying is not None and not self._closes_at_once(pending):
+                # Nothing to launch without waiting: the answer that is
+                # ready leaves before the dispatcher waits for company.
+                self._land(flying)
+                flying = None
             # The wait on an empty queue: no annotation (module docstring).
             first = pending if pending is not None else self._q.get()
             if first is _CANCELLED:
@@ -261,7 +331,7 @@ class ServeBatcher:
                 if pending is None:
                     first.t_picked = ph.t0
                 pending = None
-                group = [first]
+                reqs = [first]
                 total = first.n
                 deadline = time.monotonic() + self._wait_s
                 while total < max_b:
@@ -280,17 +350,19 @@ class ServeBatcher:
                         # group within one dispatch).
                         pending = nxt
                         break
-                    group.append(nxt)
+                    reqs.append(nxt)
                     total += nxt.n
-                ph.set(reqs=len(group), n=total)
-            self._dispatch(group, total, ph.t1)
-        # Queue cancelled: fail whatever is still outstanding (items
-        # the cancel discarded AND a pending carry-over).
+                ph.set(reqs=len(reqs), n=total)
+            flying = self._dispatch(_Group(reqs, total, ph.t1), flying)
+        # Queue cancelled: land the group in flight, then fail whatever
+        # is still outstanding (items the cancel discarded AND a pending
+        # carry-over).
+        if flying is not None:
+            self._land(flying)
         self._fail_outstanding(RuntimeError("ServeBatcher closed"))
 
-    def _trace_request(self, g: ScoreRequest, t_closed: float,
-                       t_scored: float, rung: int, total: int,
-                       launch_s: float, readback_s: float) -> None:
+    def _trace_request(self, g: ScoreRequest, group: _Group,
+                       t_scored: float) -> None:
         """Emit one sampled request's replica-side spans from the
         phases' timestamps (queue wait -> coalesce -> dispatch).  The
         flow step on the rid links the chain to the router's proxy
@@ -302,37 +374,45 @@ class ServeBatcher:
             args={"rid": g.rid},
         )
         self._tracer.emit(
-            "serve.coalesce", g.t_picked, t_closed - g.t_picked,
-            args={"rid": g.rid, "group_n": total},
+            "serve.coalesce", g.t_picked, group.t_closed - g.t_picked,
+            args={"rid": g.rid, "group_n": group.total},
         )
         self._tracer.emit(
-            "serve.dispatch", t_closed, t_scored - t_closed,
-            args={"rid": g.rid, "rung": rung, "n": total,
-                  "launch_ms": round(1e3 * launch_s, 4),
-                  "readback_ms": round(1e3 * readback_s, 4)},
+            "serve.dispatch", group.t_closed, t_scored - group.t_closed,
+            args={"rid": g.rid, "rung": group.rung, "n": group.total,
+                  "launch_ms": round(1e3 * group.launch_s, 4),
+                  "readback_ms": round(1e3 * group.readback_s, 4)},
             flow=("t", g.rid),
         )
 
-    def _dispatch(self, group, total: int, t_closed: float) -> None:
+    def _dispatch(self, group: _Group,
+                  flying: Optional[_Group]) -> Optional[_Group]:
+        """Fill and launch ``group`` and land ``flying``, the group
+        launched before it (if any), while the device runs this one.
+        Returns the group now in flight: ``group``, or None if it failed
+        or was oversized (scored in blocking chunks behind ``flying``
+        and landed here)."""
         scorer = self._scorer
+        reqs, total = group.reqs, group.total
+        scores = None  # an oversized request's, scored here in chunks
         try:
             with obs.Phase(self._t_fill, "tffm:serve.fill") as ph:
                 qwait = 0.0
-                for g in group:
+                for g in reqs:
                     wait = g.t_picked - g.t0
                     self._t_queue_wait.observe(wait)
                     qwait += wait
-                oversized = len(group) == 1 and total > scorer.max_rung
+                oversized = len(reqs) == 1 and total > scorer.max_rung
                 if oversized:
                     # One oversized request: the scorer chunks and
-                    # fills it itself.
-                    rung = scorer.max_rung
+                    # fills it itself ...
+                    group.rung = scorer.max_rung
                 else:
-                    rung = b = scorer.rung_for(total)
+                    group.rung = b = scorer.rung_for(total)
                     bi, bv, bf = self._pool(b)
                     pos = 0
-                    any_fields = any(g.fields is not None for g in group)
-                    for g in group:
+                    any_fields = any(g.fields is not None for g in reqs)
+                    for g in reqs:
                         bi[pos:pos + g.n] = g.ids
                         bv[pos:pos + g.n] = g.vals
                         if any_fields:
@@ -345,29 +425,60 @@ class ServeBatcher:
                         bv[pos:] = 0.0
                         if any_fields:
                             bf[pos:] = 0
-                ph.set(reqs=len(group), n=total, rung=rung,
+                ph.set(reqs=len(reqs), n=total, rung=group.rung,
                        qwait_us=int(1e6 * qwait))
-            launch_s = self._t_launch.total_s
-            readback_s = self._t_readback.total_s
             if oversized:
-                # ... and owns the matching slot accounting.
-                req = group[0]
+                if flying is not None:
+                    self._land(flying)
+                    flying = None
+                launch_s = self._t_launch.total_s
+                readback_s = self._t_readback.total_s
+                req = reqs[0]
                 scores = scorer.score(req.ids, req.vals, req.fields)
-                self._slots += scorer.slots_for(total)
+                # ... and owns the matching slot accounting.
+                group.slots = scorer.slots_for(total)
+                group.launch_s = self._t_launch.total_s - launch_s
+                group.readback_s = self._t_readback.total_s - readback_s
             else:
-                scores = scorer.score_rung(
-                    bi, bv, bf if any_fields else None, b
+                group.flight = scorer.launch_rung(
+                    bi, bv, bf if any_fields else None, b,
+                    inflight=int(flying is not None),
                 )
-                self._slots += b
+                group.slots = b
+                if flying is not None:
+                    self._c_overlapped.add()
+        except BaseException as e:  # noqa: BLE001 - fail the CLIENTS
+            self._fail(group, e)
+            group = None
+        if flying is not None:
+            self._land(flying)
+        if group is not None and scores is not None:
+            self._land(group, scores)
+            group = None
+        return group
+
+    def _land(self, group: _Group, scores=None) -> None:
+        """Second half of a group's dispatch: its scores read back to
+        host memory (unless handed in), every waiter released, the skew
+        fold, the parse scratch freed.  A failure here fails this
+        group's clients and no others."""
+        reqs, total = group.reqs, group.total
+        try:
+            if scores is None:
+                flight = group.flight
+                scores = self._scorer.read_rung(flight)
+                group.launch_s = flight.launch_s
+                group.readback_s = flight.readback_s
             with obs.Phase(self._t_deliver, "tffm:serve.deliver",
-                           reqs=len(group)) as ph:
+                           reqs=len(reqs)) as ph:
                 now = ph.t0
+                self._slots += group.slots
                 self._filled += total
                 self._g_fill.set(round(self.batch_fill, 6))
                 self._c_batches.add()
                 self._c_examples.add(total)
                 pos = 0
-                for g in group:
+                for g in reqs:
                     g.scores = np.asarray(
                         scores[pos:pos + g.n], np.float32
                     )
@@ -376,11 +487,7 @@ class ServeBatcher:
                     if self._slo is not None:
                         self._slo.observe(True, now - g.t0)
                     if g.rid is not None:
-                        self._trace_request(
-                            g, t_closed, now, rung, total,
-                            self._t_launch.total_s - launch_s,
-                            self._t_readback.total_s - readback_s,
-                        )
+                        self._trace_request(g, group, now)
                     with self._out_lock:
                         self._outstanding.discard(g)
                         self._g_inflight.set(len(self._outstanding))
@@ -404,37 +511,40 @@ class ServeBatcher:
                     # request traffic.
                     with obs.Phase(self._t_quality, "tffm:serve.quality",
                                    n=total):
-                        if len(group) == 1:
-                            g = group[0]
+                        if len(reqs) == 1:
+                            g = reqs[0]
                             self._quality.observe_batch(g.ids, g.vals)
                             self._quality.observe_scores(g.scores)
                         else:
                             self._quality.observe_batch(
-                                np.concatenate([g.ids for g in group]),
-                                np.concatenate([g.vals for g in group]),
+                                np.concatenate([g.ids for g in reqs]),
+                                np.concatenate([g.vals for g in reqs]),
                             )
                             self._quality.observe_scores(
                                 np.concatenate(
-                                    [g.scores for g in group]
+                                    [g.scores for g in reqs]
                                 )
                             )
                 except Exception as e:  # noqa: BLE001 - observe only
                     log.warning("skew sketching failed: %s", e)
             # Last reader done (microbatch copy + quality fold both
             # read g.ids/g.vals): release pooled parse scratch.
-            for g in group:
+            for g in reqs:
                 g.finish()
         except BaseException as e:  # noqa: BLE001 - fail the CLIENTS
-            log.warning("serve dispatch failed: %s", e)
-            for g in group:
-                g.error = e
-                if self._slo is not None:
-                    self._slo.observe(False)
-                with self._out_lock:
-                    self._outstanding.discard(g)
-                    self._g_inflight.set(len(self._outstanding))
-                g.event.set()
-                g.finish()
+            self._fail(group, e)
+
+    def _fail(self, group: _Group, e: BaseException) -> None:
+        log.warning("serve dispatch failed: %s", e)
+        for g in group.reqs:
+            g.error = e
+            if self._slo is not None:
+                self._slo.observe(False)
+            with self._out_lock:
+                self._outstanding.discard(g)
+                self._g_inflight.set(len(self._outstanding))
+            g.event.set()
+            g.finish()
 
     def _fail_outstanding(self, exc: BaseException) -> None:
         with self._out_lock:
@@ -447,8 +557,8 @@ class ServeBatcher:
             req.finish()
 
     def close(self) -> None:
-        """Stop the dispatcher and fail any queued requests.
-        Idempotent."""
+        """Stop the dispatcher: the group in flight is landed, queued
+        requests fail.  Idempotent."""
         with self._out_lock:
             self._closed = True
         self._q.cancel()

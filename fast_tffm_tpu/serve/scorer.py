@@ -85,16 +85,44 @@ def _quiet_donation():
         yield
 
 
+class _Flight:
+    """One launched rung whose scores are still on the device: what
+    :meth:`_LadderScorer.launch_rung` hands out and
+    :meth:`_LadderScorer.read_rung` takes.  ``launch_s`` / ``readback_s``
+    are the two halves' own seconds (a sampled request's span reads
+    them: timer totals interleave once two groups are under way)."""
+
+    __slots__ = ("out", "rung", "launch_s", "readback_s")
+
+    def __init__(self, out, rung: int, launch_s: float):
+        self.out = out
+        self.rung = rung
+        self.launch_s = launch_s
+        self.readback_s = 0.0
+
+
 class _LadderScorer:
     """Shared rung/pool/compile plumbing of the two scorer variants.
 
-    Thread contract: :meth:`score` / :meth:`score_rung` serialize on one
-    lock (the batcher dispatches from a single thread anyway; the lock
-    makes direct callers safe too).  :meth:`swap` may run on any thread:
-    it replaces the model REFERENCE under ``_swap_lock``, and a dispatch
-    grabs that reference once and uses it for the whole microbatch — so
-    every dispatch scores against exactly one model (old or new, never
-    torn) and a swap never waits on traffic.
+    A dispatch has two halves.  :meth:`launch_rung` resolves the model
+    reference, calls the compiled rung (the ``launch`` phase) and
+    returns the un-read result; :meth:`read_rung` blocks on it (the
+    ``readback`` phase).  :meth:`score_rung` and :meth:`score` are the
+    read of the launch; the batcher calls the halves itself, so that the
+    device runs group n+1 while the host reads and delivers group n.
+
+    Thread contract: launches serialize on one lock, which also guards
+    the scorer's own staging pools (:meth:`score` holds it for all its
+    chunks, reads included); :meth:`read_rung` takes no lock.  The
+    arrays handed to :meth:`launch_rung` stay the caller's and must not
+    be written until that launch has been READ: the transfer to the
+    device may still be reading them when the call returns.
+    :meth:`swap` may run on any thread: it replaces the model REFERENCE
+    under ``_swap_lock``, and a launch grabs that reference once and
+    uses it for the whole microbatch — so every dispatch scores against
+    exactly one model (old or new, never torn; a group in flight across
+    a swap stays on the model it was launched with) and a swap never
+    waits on traffic.
     """
 
     def __init__(self, cfg: FmConfig, mesh=None, telemetry=None,
@@ -113,6 +141,10 @@ class _LadderScorer:
         tel = telemetry if telemetry is not None else obs.NULL
         self._tel = tel
         self._t_compile = tel.timer("serve.compile")
+        # One observation a dispatch, made at its read: the launch
+        # half's seconds plus the read's — NOT launch to read on the
+        # clock, which would count the time a group sat in flight while
+        # its caller served another.
         self._t_dispatch = tel.timer("serve.dispatch")
         # The two halves of a dispatch, each also a tffm:serve.<phase>
         # annotation (obs.Phase): the rung call (implicit H2D of the
@@ -136,6 +168,8 @@ class _LadderScorer:
         self._prev = None
         self._cache: dict = {}
         self._pools: dict = {}  # rung -> (ids, vals, fields) host buffers
+        self._no_fields: dict = {}  # rung -> all-zero fields, never written
+        self._inflight = 0  # the launch under way's `inflight` stat
         self._warmed = False
         # Whether EXPECTED compiles may legitimately happen after
         # warmup: False for the dense scorer (warmup compiles the whole
@@ -285,15 +319,50 @@ class _LadderScorer:
 
     # -- scoring -------------------------------------------------------
 
-    def _launch_and_read(self, fn, args, b: int) -> np.ndarray:
-        """Call the compiled rung and read its scores back, as the
-        ``launch`` and ``readback`` phases."""
-        with obs.Phase(self._t_launch, "tffm:serve.launch", rung=b):
-            out = fn(*args)
-        # The blocking host read is part of the dispatch: the score
-        # goes back to a client, so D2H latency is request latency.
-        with obs.Phase(self._t_readback, "tffm:serve.readback", rung=b):
-            return np.asarray(out)
+    def _call_rung(self, fn, args, b: int):
+        """Call the compiled rung as the ``launch`` phase; returns its
+        un-read result (the subclasses' ``_dispatch_rung`` ends here)."""
+        with obs.Phase(self._t_launch, "tffm:serve.launch", rung=b,
+                       inflight=self._inflight):
+            return fn(*args)
+
+    def _launch(self, ids, vals, fields, b: int, inflight: int) -> _Flight:
+        """The launch half; the caller holds ``_lock``."""
+        t0 = time.perf_counter()
+        if fields is None and self._n_args == 3:
+            # A fields-less group scores against zeros of its own: a
+            # buffer nobody writes, so no group in flight can see it change.
+            fields = self._no_fields.get(b)
+            if fields is None:
+                fields = self._no_fields[b] = np.zeros(
+                    (b, self._feat), np.int32
+                )
+        self._inflight = inflight
+        out = self._dispatch_rung(ids, vals, fields, b)
+        return _Flight(out, b, time.perf_counter() - t0)
+
+    def launch_rung(self, ids: np.ndarray, vals: np.ndarray,
+                    fields: Optional[np.ndarray], b: int,
+                    inflight: int = 0) -> _Flight:
+        """First half of one dispatch of exactly-rung-shaped arrays:
+        returns once the rung is enqueued, its scores un-read.
+        ``inflight`` is how many launches the caller has not read yet
+        (a stat on the ``launch`` phase, nothing else)."""
+        t_ask = time.perf_counter()
+        with self._lock:
+            self._t_lock_wait.observe(time.perf_counter() - t_ask)
+            return self._launch(ids, vals, fields, b, inflight)
+
+    def read_rung(self, flight: _Flight) -> np.ndarray:
+        """Second half: block on the launch's scores and bring them to
+        the host.  The score goes back to a client, so the D2H is
+        request latency."""
+        with obs.Phase(self._t_readback, "tffm:serve.readback",
+                       rung=flight.rung) as ph:
+            scores = np.asarray(flight.out)
+        flight.readback_s = ph.seconds
+        self._t_dispatch.observe(flight.launch_s + flight.readback_s)
+        return scores
 
     def score(self, ids: np.ndarray, vals: np.ndarray,
               fields: Optional[np.ndarray] = None) -> np.ndarray:
@@ -323,26 +392,16 @@ class _LadderScorer:
                         bf[:c] = 0
                     if c < b:
                         bf[c:] = 0
-                scores = self._dispatch_rung(bi, bv, bf, b)
+                # Read before the next chunk refills the pool.
+                scores = self.read_rung(self._launch(bi, bv, bf, b, 0))
                 out[pos:pos + c] = scores[:c]
                 pos += c
         return out
 
     def score_rung(self, ids: np.ndarray, vals: np.ndarray,
                    fields: Optional[np.ndarray], b: int) -> np.ndarray:
-        """One dispatch of exactly-rung-shaped arrays (the batcher's
-        entry: it fills the pooled buffers itself)."""
-        t_ask = time.perf_counter()
-        with self._lock:
-            self._t_lock_wait.observe(time.perf_counter() - t_ask)
-            if fields is None:
-                fields = self._pool(b)[2]
-                if self._n_args == 3:
-                    # The pool buffer is shared across dispatches: a
-                    # fields-less group must not score against field
-                    # values a previous group left behind.
-                    fields[:] = 0
-            return self._dispatch_rung(ids, vals, fields, b)
+        """One blocking dispatch of exactly-rung-shaped arrays."""
+        return self.read_rung(self.launch_rung(ids, vals, fields, b))
 
     # -- canary promote / rollback -------------------------------------
 
@@ -379,7 +438,9 @@ class _LadderScorer:
     def _warm_rung(self, b: int) -> None:
         raise NotImplementedError
 
-    def _dispatch_rung(self, ids, vals, fields, b: int) -> np.ndarray:
+    def _dispatch_rung(self, ids, vals, fields, b: int):
+        """Resolve the model reference once and launch the rung on it:
+        returns :meth:`_call_rung`'s un-read result."""
         raise NotImplementedError
 
 
@@ -657,13 +718,12 @@ class FixedShapeScorer(_LadderScorer):
     def _warm_rung(self, b: int) -> None:
         self._compiled(b)
 
-    def _dispatch_rung(self, ids, vals, fields, b: int) -> np.ndarray:
-        with self._t_dispatch.time():
-            fn = self._compiled(b)
-            with self._swap_lock:
-                params = self._params
-            args = (params, ids, vals, fields)[:1 + self._n_args]
-            return self._launch_and_read(fn, args, b)
+    def _dispatch_rung(self, ids, vals, fields, b: int):
+        fn = self._compiled(b)
+        with self._swap_lock:
+            params = self._params
+        args = (params, ids, vals, fields)[:1 + self._n_args]
+        return self._call_rung(fn, args, b)
 
 
 class OverlayScorer(_LadderScorer):
@@ -769,21 +829,20 @@ class OverlayScorer(_LadderScorer):
         # buckets compile lazily (still expected — log-many of them).
         self._compiled(b, tiered_lib._bucket(1))
 
-    def _dispatch_rung(self, ids, vals, fields, b: int) -> np.ndarray:
-        with self._t_dispatch.time():
-            with self._swap_lock:
-                w0, store = self._model
-            vocab = self.cfg.vocabulary_size
-            flat = ids.reshape(-1).astype(np.int64, copy=False)
-            safe = np.where((flat >= 0) & (flat < vocab), flat, 0)
-            u, inv = np.unique(safe, return_inverse=True)
-            rows = tiered_lib._bucket(max(1, len(u)))
-            mini = np.zeros((rows, self._dim), np.float32)
-            mini[:len(u)] = store.gather(u)
-            local_ids = inv.astype(np.int32).reshape(ids.shape)
-            fn = self._compiled(b, rows)
-            args = (w0, mini, local_ids, vals, fields)[:2 + self._n_args]
-            return self._launch_and_read(fn, args, b)
+    def _dispatch_rung(self, ids, vals, fields, b: int):
+        with self._swap_lock:
+            w0, store = self._model
+        vocab = self.cfg.vocabulary_size
+        flat = ids.reshape(-1).astype(np.int64, copy=False)
+        safe = np.where((flat >= 0) & (flat < vocab), flat, 0)
+        u, inv = np.unique(safe, return_inverse=True)
+        rows = tiered_lib._bucket(max(1, len(u)))
+        mini = np.zeros((rows, self._dim), np.float32)
+        mini[:len(u)] = store.gather(u)
+        local_ids = inv.astype(np.int32).reshape(ids.shape)
+        fn = self._compiled(b, rows)
+        args = (w0, mini, local_ids, vals, fields)[:2 + self._n_args]
+        return self._call_rung(fn, args, b)
 
 
 # ----------------------------------------------------------------------

@@ -185,7 +185,7 @@ def test_every_phase_is_in_the_host_plane_under_its_name(session):
     assert stats[PREFIX + "respond"] == {"n"}
     assert stats[PREFIX + "coalesce"] == {"reqs", "n"}
     assert stats[PREFIX + "fill"] == {"reqs", "n", "rung", "qwait_us"}
-    assert stats[PREFIX + "launch"] == {"rung"}
+    assert stats[PREFIX + "launch"] == {"rung", "inflight"}
     assert stats[PREFIX + "readback"] == {"rung"}
     assert stats[PREFIX + "deliver"] == {"reqs"}
     assert stats[PREFIX + "quality"] == {"n"}
@@ -199,12 +199,28 @@ def test_dispatcher_phases_tile_one_thread(session):
     assert names == {PREFIX + p for p in DISPATCHER_PHASES + ("compile",)}
     for (_, _, end, _), (name, start, _, _) in zip(line, line[1:]):
         assert start >= end, f"{name} starts inside the span before it"
-    # a dispatch is coalesce, fill, launch, readback, deliver, quality,
-    # in that order (the compile between fill and launch, once)
+    # A dispatch is coalesce, fill, launch | readback, deliver, quality,
+    # each half in that order (the compile between fill and launch,
+    # once).  The halves of one group are adjacent unless the queue
+    # closed the next group at once: then that group's first half comes
+    # between them, and never more than one.
     order = [n[len(PREFIX):] for n, *_ in line if n != PREFIX + "compile"]
     assert len(order) % len(DISPATCHER_PHASES) == 0
-    for i in range(0, len(order), len(DISPATCHER_PHASES)):
-        assert tuple(order[i:i + 6]) == DISPATCHER_PHASES
+    first, second = DISPATCHER_PHASES[:3], DISPATCHER_PHASES[3:]
+    inflight = [st["inflight"] for n, _, _, st in line
+                if n == PREFIX + "launch"]
+    unread = 0
+    for i in range(0, len(order), 3):
+        half = tuple(order[i:i + 3])
+        assert half in (first, second), (i, half)
+        if half == first:
+            # the stat on `launch`: a group not yet read back is ahead
+            assert inflight.pop(0) == unread
+            unread += 1
+        else:
+            unread -= 1
+        assert 0 <= unread <= 2
+    assert unread == 0 and not inflight
     # no HTTP worker's phase is on the dispatcher's thread, nor the
     # other way round
     for ln in session["lines"]:

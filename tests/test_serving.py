@@ -25,6 +25,7 @@ from __future__ import annotations
 import json
 import threading
 import time
+import types
 import urllib.error
 import urllib.request
 
@@ -216,11 +217,15 @@ class _FakeScorer:
     def slots_for(self, n):
         return n
 
-    def score_rung(self, ids, vals, fields, b):
+    def launch_rung(self, ids, vals, fields, b, inflight=0):
         if self._delay:
             time.sleep(self._delay)
         self.dispatches.append(b)
-        return vals.sum(axis=1)
+        return types.SimpleNamespace(
+            scores=vals.sum(axis=1), launch_s=0.0, readback_s=0.0)
+
+    def read_rung(self, flight):
+        return flight.scores
 
     def score(self, ids, vals, fields=None):
         self.dispatches.append(len(ids))
@@ -327,6 +332,395 @@ class TestBatcher:
             assert "p99_ms" in snap["timers"]["serve.latency"]
         finally:
             bat.close()
+
+
+# ----------------------------------------------------------------------
+# one group in flight: launch n+1, then read n (ISSUE 32)
+# ----------------------------------------------------------------------
+
+
+class _GatedScorer(_FakeScorer):
+    """A stub whose halves the test holds and releases.  A group is
+    named by its first id; ``log`` is the order of the halves.  The
+    model is taken at the launch (as the real scorer takes its
+    reference): ``"new"`` adds 1000 to every score."""
+
+    def __init__(self):
+        super().__init__()
+        self.log: list = []
+        self.model = "old"
+        self.hold_launches = threading.Event()  # set = launches go
+        self.hold_launches.set()
+        self.reads: dict = {}  # group name -> Event the read waits on
+        self.bad_reads: set = set()
+
+    def gate(self, name):
+        return self.reads.setdefault(name, threading.Event())
+
+    def launch_rung(self, ids, vals, fields, b, inflight=0):
+        assert self.hold_launches.wait(10)
+        name = int(ids[0, 0])
+        self.log.append(("launch", name, inflight))
+        scores = vals.sum(axis=1) + (1000.0 if self.model == "new" else 0.0)
+        return types.SimpleNamespace(
+            name=name, scores=scores, launch_s=0.0, readback_s=0.0)
+
+    def read_rung(self, flight):
+        assert self.gate(flight.name).wait(10)
+        self.log.append(("read", flight.name))
+        if flight.name in self.bad_reads:
+            raise RuntimeError(f"read of group {flight.name} failed")
+        return flight.scores
+
+    def score(self, ids, vals, fields=None):
+        self.log.append(("score", int(ids[0, 0])))
+        return vals.sum(axis=1)
+
+
+def _named(rng, name, n):
+    """``n`` examples whose every id is ``name``."""
+    _, vals = _examples(rng, n)
+    return np.full((n, F), name, np.int32), vals
+
+
+def _wait_for(cond, what, timeout=10.0):
+    t_end = time.time() + timeout
+    while not cond():
+        assert time.time() < t_end, f"timed out waiting for {what}"
+        time.sleep(0.002)
+
+
+class TestOneGroupInFlight:
+    """The dispatcher launches group n+1 before it reads group n back
+    when the queue closes n+1 with no wait, and not otherwise."""
+
+    def _start(self, wait_ms=2000.0):
+        fake = _GatedScorer()
+        tel = obs.Telemetry()
+        bat = ServeBatcher(fake, max_batch_wait_ms=wait_ms, telemetry=tel)
+        return fake, tel, bat
+
+    @staticmethod
+    def _counter(tel, name):
+        return tel.snapshot()["counters"].get(name, 0)
+
+    @pytest.mark.parametrize("second", ["full_rung", "does_not_fit"])
+    def test_queued_group_launches_before_the_read(self, rng, second):
+        """(a) and (g): with a group the queue closes at once, n+1's
+        launch comes before n's read returns; replies leave in launch
+        order; serve.overlapped counts it."""
+        fake, tel, bat = self._start()
+        try:
+            fake.hold_launches.clear()
+            a_ids, a_vals = _named(rng, 1, 64)
+            a = bat.submit(a_ids, a_vals)
+            _wait_for(lambda: bat._q.qsize() == 0, "A picked")
+            if second == "full_rung":
+                rest = [bat.submit(*_named(rng, 2, 64))]
+            else:  # 40 + 40 > 64: the second closes the first's group
+                rest = [bat.submit(*_named(rng, 2, 40)),
+                        bat.submit(*_named(rng, 3, 40))]
+            b = rest[0]
+            fake.hold_launches.set()
+            _wait_for(lambda: len(fake.log) >= 2, "B launched")
+            # B went out while A's read had not returned
+            assert fake.log[:2] == [("launch", 1, 0), ("launch", 2, 1)]
+            assert not a.event.is_set() and not b.event.is_set()
+            fake.gate(1).set()
+            np.testing.assert_allclose(
+                bat.result(a, timeout=10), a_vals.sum(axis=1))
+            assert not b.event.is_set()  # in launch order, A first
+            for name in (2, 3):
+                fake.gate(name).set()
+            for r in rest:
+                np.testing.assert_allclose(
+                    bat.result(r, timeout=10), r.vals.sum(axis=1))
+            assert [e[:2] for e in fake.log[:4]] == [
+                ("launch", 1), ("launch", 2), ("read", 1), ("read", 2)]
+            assert self._counter(tel, "serve.overlapped") == 1
+            assert self._counter(tel, "serve.batches") == len(rest) + 1
+            assert bat.batch_fill == pytest.approx(
+                (64 + sum(r.n for r in rest)) / (64 * (len(rest) + 1)))
+            assert bat.inflight == 0
+        finally:
+            for name in (1, 2, 3):
+                fake.gate(name).set()
+            fake.hold_launches.set()
+            bat.close()
+
+    def test_ready_answer_never_waits_behind_the_batch_wait(self, rng):
+        """(b) and (g): nothing queued after n's launch, so n is read
+        and delivered before the dispatcher waits -- a lone request does
+        not pay the 2 s batch wait; nor does n wait for a request that
+        cannot close a group.  serve.overlapped stays 0."""
+        fake, tel, bat = self._start()
+        try:
+            fake.gate(1).set()
+            ids, vals = _named(rng, 1, 64)  # a full rung: no coalesce wait
+            t0 = time.perf_counter()
+            got = bat.score(ids, vals, timeout=10)
+            assert time.perf_counter() - t0 < 1.0
+            np.testing.assert_allclose(got, vals.sum(axis=1))
+            # B cannot close a group alone: A is landed first, B's
+            # launch comes after the read of A
+            fake.hold_launches.clear()
+            a = bat.submit(*_named(rng, 4, 64))
+            _wait_for(lambda: bat._q.qsize() == 0, "A picked")
+            b = bat.submit(*_named(rng, 5, 2))
+            fake.gate(4).set()
+            fake.gate(5).set()
+            t0 = time.perf_counter()
+            fake.hold_launches.set()
+            bat.result(a, timeout=10)
+            assert time.perf_counter() - t0 < 1.0  # not B's 2 s wait
+            assert not b.event.is_set()
+            assert fake.log[-2:] == [("launch", 4, 0), ("read", 4)]
+            bat.result(b, timeout=10)  # ... which B then sits out
+            assert fake.log[-2:] == [("launch", 5, 0), ("read", 5)]
+            assert self._counter(tel, "serve.overlapped") == 0
+            assert self._counter(tel, "serve.batches") == 3
+        finally:
+            fake.hold_launches.set()
+            bat.close()
+
+    def test_group_in_flight_keeps_the_model_it_was_launched_on(self, rng):
+        """(c) through the batcher: a swap between n's launch and its
+        read leaves n on the old model, n+1 on the new."""
+        fake, tel, bat = self._start()
+        try:
+            a_ids, a_vals = _named(rng, 1, 64)
+            a = bat.submit(a_ids, a_vals)
+            _wait_for(lambda: fake.log == [("launch", 1, 0)], "A launched")
+            fake.model = "new"  # A is in flight, its read held
+            b_ids, b_vals = _named(rng, 2, 64)
+            b = bat.submit(b_ids, b_vals)
+            fake.gate(1).set()
+            fake.gate(2).set()
+            np.testing.assert_allclose(
+                bat.result(a, timeout=10), a_vals.sum(axis=1))
+            np.testing.assert_allclose(
+                bat.result(b, timeout=10), b_vals.sum(axis=1) + 1000.0)
+        finally:
+            fake.gate(1).set()
+            bat.close()
+
+    def test_failed_read_fails_its_own_group_only(self, rng):
+        """(d): the read of n raises; n's clients get the error, n+1,
+        already launched, is read and delivered."""
+        fake, tel, bat = self._start()
+        try:
+            fake.bad_reads.add(1)
+            fake.hold_launches.clear()
+            a = bat.submit(*_named(rng, 1, 64))
+            _wait_for(lambda: bat._q.qsize() == 0, "A picked")
+            b_ids, b_vals = _named(rng, 2, 64)
+            b = bat.submit(b_ids, b_vals)
+            released = []
+            a.on_done = lambda: released.append(1)
+            fake.gate(1).set()
+            fake.gate(2).set()
+            fake.hold_launches.set()
+            with pytest.raises(RuntimeError, match="group 1 failed"):
+                bat.result(a, timeout=10)
+            np.testing.assert_allclose(
+                bat.result(b, timeout=10), b_vals.sum(axis=1))
+            assert [e[:2] for e in fake.log] == [
+                ("launch", 1), ("launch", 2), ("read", 1), ("read", 2)]
+            assert released == [1]  # the failed group's scratch is freed
+            assert self._counter(tel, "serve.batches") == 1
+            assert bat.inflight == 0
+        finally:
+            fake.hold_launches.set()
+            bat.close()
+
+    def test_close_lands_the_group_in_flight(self, rng):
+        """(e): close() with a group in flight delivers it; what is
+        still queued fails."""
+        fake, tel, bat = self._start()
+        fake.hold_launches.clear()
+        a_ids, a_vals = _named(rng, 1, 64)
+        a = bat.submit(a_ids, a_vals)
+        _wait_for(lambda: bat._q.qsize() == 0, "A picked")
+        b = bat.submit(*_named(rng, 2, 8))  # queued, never picked
+        closer = threading.Thread(target=bat.close)
+        closer.start()
+        _wait_for(lambda: bat._closed, "close() under way")
+        fake.hold_launches.set()
+        _wait_for(lambda: fake.log == [("launch", 1, 0)], "A launched")
+        assert closer.is_alive() and not a.event.is_set()
+        fake.gate(1).set()
+        closer.join(10)
+        assert not closer.is_alive()
+        np.testing.assert_allclose(
+            bat.result(a, timeout=1), a_vals.sum(axis=1))
+        with pytest.raises(RuntimeError, match="closed"):
+            bat.result(b, timeout=1)
+        assert fake.log == [("launch", 1, 0), ("read", 1)]
+
+    def test_oversized_request_waits_for_the_group_in_flight(self, rng):
+        """(e): an oversized lone request (scorer.score(), blocking) is
+        scored after the group in flight has been landed."""
+        fake, tel, bat = self._start()
+        try:
+            fake.hold_launches.clear()
+            a_ids, a_vals = _named(rng, 1, 64)
+            a = bat.submit(a_ids, a_vals)
+            _wait_for(lambda: bat._q.qsize() == 0, "A picked")
+            c_ids, c_vals = _named(rng, 3, 200)
+            c = bat.submit(c_ids, c_vals)
+            fake.hold_launches.set()
+            _wait_for(lambda: len(fake.log) >= 1, "A launched")
+            time.sleep(0.05)
+            assert fake.log == [("launch", 1, 0)]  # C not scored yet
+            fake.gate(1).set()
+            np.testing.assert_allclose(
+                bat.result(a, timeout=10), a_vals.sum(axis=1))
+            np.testing.assert_allclose(
+                bat.result(c, timeout=10), c_vals.sum(axis=1))
+            assert fake.log == [("launch", 1, 0), ("read", 1), ("score", 3)]
+            assert self._counter(tel, "serve.overlapped") == 0
+        finally:
+            fake.gate(1).set()
+            fake.hold_launches.set()
+            bat.close()
+
+
+class TestLaunchAndRead:
+    """The real scorer's two halves at toy size."""
+
+    def test_swap_between_launch_and_read(self, rng):
+        """(c): the reference is taken at the launch."""
+        cfg = _cfg_mem()
+        pa, pb = _params(cfg, seed=0), _params(cfg, seed=1)
+        sc = FixedShapeScorer(cfg, pa)
+        sc.warmup()
+        ids, vals = _examples(rng, 64)
+        ref_a = sc.score(ids, vals)
+        ref_b = FixedShapeScorer(cfg, pb).score(ids, vals)
+        assert not np.array_equal(ref_a, ref_b)
+        first = sc.launch_rung(ids.copy(), vals.copy(), None, 64)
+        sc.swap(fm.FmParams(*[np.asarray(x) for x in pb]), step=2)
+        second = sc.launch_rung(ids.copy(), vals.copy(), None, 64,
+                                inflight=1)
+        np.testing.assert_array_equal(sc.read_rung(first), ref_a)
+        np.testing.assert_array_equal(sc.read_rung(second), ref_b)
+        assert sc.steady_compiles == 0
+
+    def test_dispatch_timer_is_the_two_halves_not_the_time_in_flight(
+            self, rng):
+        tel = obs.Telemetry()
+        cfg = _cfg_mem()
+        sc = FixedShapeScorer(cfg, _params(cfg), telemetry=tel)
+        sc.warmup()
+        ids, vals = _examples(rng, 32)
+        flight = sc.launch_rung(ids, vals, None, 32)
+        time.sleep(0.2)  # in flight while the caller serves another
+        sc.read_rung(flight)
+        t = tel.snapshot()["timers"]
+        assert t["serve.dispatch"]["count"] == 1
+        assert t["serve.dispatch"]["total_s"] == pytest.approx(
+            flight.launch_s + flight.readback_s, abs=1e-5)
+        assert t["serve.dispatch"]["total_s"] < 0.2
+        assert (t["serve.launch"]["total_s"]
+                + t["serve.readback"]["total_s"]
+                <= t["serve.dispatch"]["total_s"] + 1e-5)
+
+    @pytest.mark.parametrize("field_num", [0, 3])
+    def test_back_to_back_groups_of_one_rung_get_their_own_scores(
+            self, rng, field_num):
+        """(f) the staging buffers: group n+1 is filled and launched
+        while n has not been read; each gets the scores of its own
+        rows (field-aware too, where one group carries no fields)."""
+        cfg = _cfg_mem(field_num=field_num) if field_num else _cfg_mem()
+        tel = obs.Telemetry()
+        sc = FixedShapeScorer(cfg, _params(cfg), telemetry=tel)
+        sc.warmup()
+        groups = []
+        for k in range(6):
+            ids, vals = _examples(rng, 64)
+            fields = (rng.integers(0, field_num, ids.shape).astype(np.int32)
+                      if field_num and k % 2 == 0 else None)
+            groups.append((ids, vals, fields, sc.score(ids, vals, fields)))
+        assert not np.array_equal(groups[0][3], groups[1][3])
+        bat = ServeBatcher(sc, max_batch_wait_ms=1.0, telemetry=tel)
+        # hold the first launch until all six full rungs are queued, so
+        # that every later one is launched with the one before in flight
+        go = threading.Event()
+        launch = sc.launch_rung
+
+        def held(*a, **kw):
+            assert go.wait(10)
+            return launch(*a, **kw)
+
+        sc.launch_rung = held
+        try:
+            reqs = [bat.submit(i, v, f) for i, v, f, _ in groups]
+            go.set()
+            for req, (_, _, _, want) in zip(reqs, groups):
+                np.testing.assert_array_equal(
+                    bat.result(req, timeout=30), want)
+        finally:
+            go.set()
+            bat.close()
+        counters = tel.snapshot()["counters"]
+        assert counters["serve.overlapped"] == 5
+        assert sc.steady_compiles == 0
+        # two sets a rung, taken in turn
+        first, second = bat._pool(64), bat._pool(64)
+        assert first[0] is not second[0] and bat._pool(64)[0] is first[0]
+
+
+    def test_many_clients_each_get_their_own_scores(self, rng):
+        """Stress: more client threads than cores, a short switch
+        interval, sizes that close groups at once and sizes that wait;
+        every reply is the scores of its own rows, whichever staging
+        set and whichever side of an overlap its group took."""
+        import sys
+
+        cfg = _cfg_mem()
+        tel = obs.Telemetry()
+        sc = FixedShapeScorer(cfg, _params(cfg), telemetry=tel)
+        sc.warmup()
+        sizes = (1, 7, 30, 33, 40, 64)
+        pool = []
+        for k in range(24):
+            ids, vals = _examples(rng, sizes[k % len(sizes)])
+            pool.append((ids, vals, sc.score(ids, vals)))
+        bat = ServeBatcher(sc, max_batch_wait_ms=0.3, telemetry=tel)
+        wrong: list = []
+        t_end = time.time() + 2.0
+
+        def client(k):
+            i = k
+            while time.time() < t_end and not wrong:
+                ids, vals, want = pool[i % len(pool)]
+                try:
+                    got = bat.score(ids, vals, timeout=30)
+                except Exception as e:  # noqa: BLE001 - reported below
+                    wrong.append(repr(e))
+                    return
+                if not np.array_equal(got, want):
+                    wrong.append((i % len(pool), got, want))
+                i += 5
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=client, args=(k,))
+                       for k in range(24)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+            assert not any(t.is_alive() for t in threads)
+        finally:
+            sys.setswitchinterval(old)
+            bat.close()
+        assert not wrong, wrong[:1]
+        counters = tel.snapshot()["counters"]
+        assert counters["serve.batches"] > 24
+        assert 0 < counters["serve.overlapped"] < counters["serve.batches"]
+        assert bat.inflight == 0 and sc.steady_compiles == 0
 
 
 # ----------------------------------------------------------------------
