@@ -18,8 +18,14 @@ script exits nonzero — nothing is caught and carried past):
             the scatter path (sort, K1 sums, unique-row scatter; Adagrad
             and FTRL) and of the tile kernels vs the per-occurrence sums
             in float64 on the host
-4. predict  ``cli.main(["predict", cfg])``
-5. serve    ``serve(cfg, port=0)``; text and binary requests over the socket
+4. ffm      the width the field-aware cell runs (39 fields, k=4: 157
+            floats a row, a payload of three 128-lane tiles), at a small
+            V: the interaction's forward and closed-form backward at
+            float32 vs the double sum over pairs in float64 on the host,
+            and one scatter-path apply (Adagrad and FTRL) vs the host's
+            per-occurrence sums
+5. predict  ``cli.main(["predict", cfg])``
+6. serve    ``serve(cfg, port=0)``; text and binary requests over the socket
             must match the predict scores; zero steady-state compiles
 
 ``--chips 4`` runs ONLY the 2 data x 2 model row-sharded step
@@ -86,6 +92,13 @@ TILE_ATOL = 2e-5
 # bf16 compute rounds the interaction operands to 8 mantissa bits;
 # the repo's own bf16 tests (tests/test_bf16.py) hold this tolerance.
 BF16_TOL = {"rtol": 0.05, "atol": 0.02}
+# The field-aware phase: LIBFFM's Criteo shape at a vocabulary that keeps
+# three float64 tables on the host small.
+FFM_FIELDS, FFM_K, FFM_VOCAB, FFM_EXAMPLES = 39, 4, 1 << 16, 512
+# Float32-true interaction against float64: scores of O(1) and gradients
+# of O(0.1) summed from 741 pairs; one bf16 pass on the MXU (what float32
+# operands get without a stated precision) reads ~1e-3 on both.
+FFM_ATOL = 2e-5
 # Served vs predicted probabilities: both print %.6f, and a request is
 # padded to another ladder rung than predict's batch — same math per row.
 SERVE_ATOL = 2e-6
@@ -399,7 +412,7 @@ def _passed(cmp: dict) -> bool:
 
 
 def _applies_vs_host(cfg, seed: int) -> dict:
-    """One optimizer apply at the cfg's own V, B x F and k (Zipf ids, so
+    """One optimizer apply at the cfg's own V, B x F and row (Zipf ids, so
     the hottest row has thousands of occurrences) against every
     occurrence added singly in float64 ON THE HOST — the one side of
     this phase that shares no sort, payload or K1 with the program:
@@ -408,7 +421,9 @@ def _applies_vs_host(cfg, seed: int) -> dict:
       apply (``scatter_apply_unique``), its additive and its
       gather-update-set form;
     * ``tile_adagrad``: the tile kernels (K1 + K2), whose step-level
-      oracle (``sparse_apply=scatter``) runs the same prep since PR 27.
+      oracle (``sparse_apply=scatter``) runs the same prep since PR 27;
+      left out at a row they do not hold (their payload ``[g | g^2 |
+      lrow]`` is one 128-lane tile: 63 floats a row at most).
     """
     from functools import partial
 
@@ -418,7 +433,7 @@ def _applies_vs_host(cfg, seed: int) -> dict:
     from fast_tffm_tpu.ops import sparse_apply
     from fast_tffm_tpu.train import sparse as sparse_lib
 
-    v, d = cfg.vocabulary_size, 1 + cfg.factor_num
+    v, d = cfg.vocabulary_size, cfg.embedding_dim
     n = cfg.batch_size * cfg.max_features
     lr, eps = cfg.learning_rate, sparse_lib.ADAGRAD_EPS
     l1, l2, beta = cfg.ftrl_l1, cfg.ftrl_l2, cfg.ftrl_beta
@@ -465,7 +480,7 @@ def _applies_vs_host(cfg, seed: int) -> dict:
             sparse_apply.ftrl_update, lr=lr, l1=l1, l2=l2, beta=beta),
             False), [table, z, acc], ftrl_ref),
     }
-    if sparse_apply.supports_tile(v, "adagrad"):
+    if 2 * d + 1 <= 128 and sparse_apply.supports_tile(v, "adagrad"):
         cases["tile_adagrad"] = (tile, [table, acc], adagrad_ref)
     out = {
         "occurrences": n, "unique_rows": int(len(uniq)),
@@ -545,6 +560,86 @@ def phase_kernels(out: Out, cfg, size: Size, work: str, seed: int):
     # A comparison of two untrained tables would pass vacuously.
     require(acc_max > cfg.adagrad_initial_accumulator,
             "the optimizer state did not move over the steps")
+
+
+def _ffm_vs_host(cfg, seed: int) -> dict:
+    """The field-aware interaction (closed-form op, float32) on the
+    device against the double sum over pairs in float64 on the host:
+    scores and the gradient w.r.t. the gathered rows.  Slots draw their
+    field at random, so fields repeat and are absent in an example."""
+    import jax
+    import jax.numpy as jnp
+
+    from fast_tffm_tpu.ops import interaction
+
+    p, k, f, b = cfg.field_num, cfg.factor_num, cfg.max_features, FFM_EXAMPLES
+    rng = np.random.default_rng(seed + 1)
+    rows = rng.uniform(-0.3, 0.3, size=(b, f, 1 + p * k)).astype(np.float32)
+    vals = rng.uniform(0.1, 1.0, size=(b, f)).astype(np.float32)
+    fields = rng.integers(0, p, size=(b, f)).astype(np.int32)
+    co = rng.normal(size=(b,)).astype(np.float32)  # the scores' cotangent
+
+    def loss(r):
+        s = interaction.ffm_interaction(
+            r, jnp.asarray(vals), jnp.asarray(fields), k, p, jnp.float32)
+        return jnp.sum(s * co), s
+
+    grad, scores = jax.jit(jax.grad(loss, has_aux=True))(jnp.asarray(rows))
+    r64, x = rows.astype(np.float64), vals.astype(np.float64)
+    v = r64[..., 1:].reshape(b, f, p, k)
+    e = np.arange(b)[:, None, None]
+    # held[e, i, j] = V[i, f_j]: what feature i holds for feature j's field
+    held = v[e, np.arange(f)[None, :, None], fields[:, None, :]]
+    dots = np.einsum("eijc,ejic->eij", held, held)
+    xx = x[:, :, None] * x[:, None, :]
+    upper = np.triu(np.ones((f, f)), 1)
+    want = (r64[..., 0] * x).sum(1) + (dots * xx * upper).sum((1, 2))
+    # d/dV[i, q] = co x_i sum_{j != i, f_j = q} V[j, f_i] x_j
+    off = 1.0 - np.eye(f)
+    dv = np.zeros((b, f, p, k))
+    contrib = np.swapaxes(held, 1, 2) * (xx * off)[..., None]  # [e,i,j,:]
+    np.add.at(dv, (e, np.arange(f)[None, :, None], fields[:, None, :]),
+              contrib)
+    want_g = np.concatenate(
+        [x[..., None], dv.reshape(b, f, p * k)], -1) * co[:, None, None]
+    return {
+        "examples": b, "atol": FFM_ATOL,
+        "scores_max_abs": float(np.abs(np.asarray(scores) - want).max()),
+        "grad_max_abs": float(np.abs(np.asarray(grad) - want_g).max()),
+        "scores_abs_mean": float(np.abs(want).mean()),
+        "grad_abs_max": float(np.abs(want_g).max()),
+    }
+
+
+def phase_ffm(out: Out, cfg, seed: int):
+    """The row the field-aware cell runs, D = 1 + 39 * 4 = 157: a
+    payload of 2 D + 2 = 316 floats, three lane tiles through K1."""
+    import jax
+
+    ffm = dataclasses.replace(
+        cfg, field_num=FFM_FIELDS, factor_num=FFM_K,
+        max_features=FFM_FIELDS,
+        vocabulary_size=min(cfg.vocabulary_size, FFM_VOCAB),
+    )
+    inter = _ffm_vs_host(ffm, seed)
+    applies = _applies_vs_host(ffm, seed)
+    out.emit({"phase": "ffm", "row_floats": ffm.embedding_dim,
+              "payload_lanes": -(-(2 * ffm.embedding_dim + 2) // 128) * 128,
+              "interaction_vs_host": inter, "applies_vs_host": applies,
+              "memory": _mem(jax.devices()[0])})
+    require(max(inter["scores_max_abs"], inter["grad_max_abs"])
+            <= inter["atol"],
+            f"the float32 field-aware interaction differs from the "
+            f"host's float64 pairs: {inter}")
+    for name in ("unique_adagrad", "unique_ftrl"):
+        got = applies[name]
+        require(
+            got["rows_written"] == applies["unique_rows"]
+            and got["untouched_rows_changed"] == 0
+            and max(got["max_abs"]) <= got["atol"],
+            f"the {name} apply at {ffm.embedding_dim} floats a row "
+            f"differs from the host's per-occurrence sums: {got}",
+        )
 
 
 def phase_predict(out: Out, cfg_path: str, cfg, size: Size):
@@ -731,6 +826,7 @@ def run(chips: int = 1, rehearse: bool = False, seed: int = 0,
         else:
             phase_train(out, cfg_path, cfg, size, rehearse)
             phase_kernels(out, cfg, size, work, seed)
+            phase_ffm(out, cfg, seed)
             predicted = phase_predict(out, cfg_path, cfg, size)
             phase_serve(out, cfg, predicted)
         stats = platform.compile_cache_stats()

@@ -92,12 +92,15 @@ def ffm_scores_from_rows(
     naive [B,F,F,k] pairwise tensor (a ~800MB intermediate at Criteo
     shapes, built by row gathers) with two einsum-matmuls over [B,P,P,k].
     """
-    from fast_tffm_tpu.platform import ffm_compute_dtype
+    from fast_tffm_tpu.platform import (
+        ffm_compute_dtype, ffm_matmul_precision,
+    )
 
     # Off-TPU the einsum operands fall back to f32 (XLA:CPU cannot run
     # bf16 dots) — see platform.ffm_compute_dtype, the one copy of that
     # gate.
     compute_dtype = ffm_compute_dtype(compute_dtype)
+    prec = ffm_matmul_precision(compute_dtype)
     rows = rows.astype(compute_dtype)
     vals = vals.astype(compute_dtype)
     b, f = vals.shape
@@ -109,12 +112,13 @@ def ffm_scores_from_rows(
         fields[..., None] == jnp.arange(field_num, dtype=fields.dtype)
     ).astype(compute_dtype)  # [B, F, P] pure field one-hot
     s = jnp.einsum(
-        "bfp,bfqk->bpqk", oh * vals[..., None], v,
+        "bfp,bfqk->bpqk", oh * vals[..., None], v, precision=prec,
         preferred_element_type=jnp.float32,
     )
-    cross = jnp.einsum("bpqk,bqpk->b", s, s)  # s is f32
+    cross = jnp.einsum("bpqk,bqpk->b", s, s, precision=prec)  # s is f32
     v_own = jnp.einsum(
-        "bfq,bfqk->bfk", oh, v, preferred_element_type=jnp.float32
+        "bfq,bfqk->bfk", oh, v, precision=prec,
+        preferred_element_type=jnp.float32,
     )  # v_i^{f_i}
     self_term = jnp.sum(
         jnp.sum(v_own * v_own, axis=-1)

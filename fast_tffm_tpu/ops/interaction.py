@@ -18,7 +18,11 @@ import jax.numpy as jnp
 from fast_tffm_tpu.ops import fm_pallas
 
 
-from fast_tffm_tpu.platform import use_interpret as _use_interpret
+from fast_tffm_tpu.platform import (
+    ffm_compute_dtype,
+    ffm_matmul_precision,
+    use_interpret as _use_interpret,
+)
 
 
 def _scores_jnp(rows, vals):
@@ -188,16 +192,16 @@ fm_interaction.defvjp(_fwd, _bwd)
 # test-enforced (tests/test_ffm_op.py).
 
 
-def _ffm_parts(rows, vals, fields, factor_num, field_num, compute_dtype):
-    """Shared forward math: (linear, s, self_term).
+@jax.named_scope("tffm.ffm_interaction_fwd")
+def _ffm_forward(rows, vals, fields, factor_num, field_num, compute_dtype):
+    """The forward math: (scores without w0, S).
 
     Mirrors models.fm.ffm_scores_from_rows operand-for-operand —
     including which products see the bf16-ROUNDED operands — so the two
     forwards agree to accumulation order in every compute_dtype.
     """
-    from fast_tffm_tpu.platform import ffm_compute_dtype
-
     cd = ffm_compute_dtype(compute_dtype)  # f32 off-TPU: CPU can't bf16-dot
+    prec = ffm_matmul_precision(cd)
     rows = rows.astype(cd)
     vals_c = vals.astype(cd)
     b, f = vals.shape
@@ -208,11 +212,12 @@ def _ffm_parts(rows, vals, fields, factor_num, field_num, compute_dtype):
         fields[..., None] == jnp.arange(field_num, dtype=fields.dtype)
     ).astype(cd)  # [B, F, P]
     s = jnp.einsum(
-        "bfp,bfqk->bpqk", oh * vals_c[..., None], v,
+        "bfp,bfqk->bpqk", oh * vals_c[..., None], v, precision=prec,
         preferred_element_type=jnp.float32,
     )  # [B, P, P, k] field-grouped sums, f32
     v_own = jnp.einsum(
-        "bfq,bfqk->bfk", oh, v, preferred_element_type=jnp.float32
+        "bfq,bfqk->bfk", oh, v, precision=prec,
+        preferred_element_type=jnp.float32,
     )  # v_i^{f_i}
     # The rounded vals square here must match the rounded diagonal of
     # `cross` or the i = j cancellation leaves a bf16-eps residual.
@@ -220,7 +225,8 @@ def _ffm_parts(rows, vals, fields, factor_num, field_num, compute_dtype):
         jnp.sum(v_own * v_own, axis=-1) * (vals_c * vals_c),
         axis=-1, dtype=jnp.float32,
     )
-    return linear, s, self_term
+    cross = jnp.einsum("bpqk,bqpk->b", s, s, precision=prec)
+    return linear + 0.5 * (cross - self_term), s
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -229,51 +235,52 @@ def ffm_interaction(rows, vals, fields, factor_num, field_num,
     """Per-example FFM interaction scores (without w0), differentiable
     w.r.t. ``rows`` only.  Same numeric contract as
     models.fm.ffm_scores_from_rows minus the w0 term: bf16 mode rounds
-    the operands, accumulation and scores stay f32."""
-    linear, s, self_term = _ffm_parts(
+    the operands, accumulation and scores stay f32; float32 mode is
+    float32 on the MXU too (platform.ffm_matmul_precision)."""
+    return _ffm_forward(
         rows, vals, fields, factor_num, field_num, compute_dtype
-    )
-    cross = jnp.einsum("bpqk,bqpk->b", s, s)
-    return linear + 0.5 * (cross - self_term)
+    )[0]
 
 
 def _ffm_fwd(rows, vals, fields, factor_num, field_num, compute_dtype):
-    linear, s, self_term = _ffm_parts(
+    scores, s = _ffm_forward(
         rows, vals, fields, factor_num, field_num, compute_dtype
     )
-    cross = jnp.einsum("bpqk,bqpk->b", s, s)
     # Residuals: save only the inputs + S (what autodiff would keep
     # anyway); oh/v_own are cheap one-hot recomputes in the backward.
-    return linear + 0.5 * (cross - self_term), (rows, vals, fields, s)
+    return scores, (rows, vals, fields, s)
 
 
 def _ffm_bwd(factor_num, field_num, compute_dtype, res, g):
-    from fast_tffm_tpu.platform import ffm_compute_dtype
-
     rows, vals, fields, s = res
     b, f = vals.shape
     # Same operand rounding as the forward/autodiff: products see the
     # cd-rounded rows/vals, accumulation stays f32.
     cd = ffm_compute_dtype(compute_dtype)
-    v = rows[..., 1:].astype(cd).reshape(b, f, field_num, factor_num)
-    vals32 = vals.astype(cd).astype(jnp.float32)
-    oh = (
-        fields[..., None] == jnp.arange(field_num, dtype=fields.dtype)
-    ).astype(cd)
-    v_own = jnp.einsum(
-        "bfq,bfqk->bfk", oh, v, preferred_element_type=jnp.float32
-    )
-    oh32 = oh.astype(jnp.float32)
-    gx = g[:, None] * vals32  # [B, F]
-    # T[b,f,q,:] = S[b, q, f_i, :]: gather S's second field axis by each
-    # occurrence's own field, as a one-hot matmul.
-    t = jnp.einsum("bqpk,bfp->bfqk", s, oh32)
-    dv = gx[..., None, None] * (
-        t - oh32[..., None] * v_own[:, :, None, :] * vals32[..., None, None]
-    )  # [B, F, P, k]
-    drows = jnp.concatenate(
-        [gx[..., None], dv.reshape(b, f, field_num * factor_num)], axis=-1
-    ).astype(rows.dtype)
+    prec = ffm_matmul_precision(cd)
+    with jax.named_scope("tffm.ffm_interaction_bwd"):
+        v = rows[..., 1:].astype(cd).reshape(b, f, field_num, factor_num)
+        vals32 = vals.astype(cd).astype(jnp.float32)
+        oh = (
+            fields[..., None] == jnp.arange(field_num, dtype=fields.dtype)
+        ).astype(cd)
+        v_own = jnp.einsum(
+            "bfq,bfqk->bfk", oh, v, precision=prec,
+            preferred_element_type=jnp.float32,
+        )
+        oh32 = oh.astype(jnp.float32)
+        gx = g[:, None] * vals32  # [B, F]
+        # T[b,f,q,:] = S[b, q, f_i, :]: gather S's second field axis by
+        # each occurrence's own field, as a one-hot matmul.
+        t = jnp.einsum("bqpk,bfp->bfqk", s, oh32, precision=prec)
+        dv = gx[..., None, None] * (
+            t - oh32[..., None] * v_own[:, :, None, :]
+            * vals32[..., None, None]
+        )  # [B, F, P, k]
+        drows = jnp.concatenate(
+            [gx[..., None], dv.reshape(b, f, field_num * factor_num)],
+            axis=-1,
+        ).astype(rows.dtype)
     return drows, None, None  # no gradients w.r.t. vals/fields
 
 

@@ -215,6 +215,20 @@ def _k1_kernel(starts_ref, firsts_ref, ends_ref, payload_ref, upos_ref,
 
 def _k1_dedup(payload, upos, starts, firsts, ends, n_out, passes=2):
     n, lanes = payload.shape
+    if lanes > 128:
+        # One call a 128-lane tile.  The output windows land at dynamic
+        # row offsets, and only a [rows, 128] float32 array is row-
+        # contiguous in HBM's (8, 128) tiling: a wider one keeps a row's
+        # lane tiles apart, and Mosaic refuses the unaligned window
+        # ("Failed to prove that a tile index in dimension 0 is
+        # divisible by the tiling (8)": field-aware FM's 2 * 157 + 2
+        # payload columns, three tiles).  The slices and the
+        # concatenation are whole tiles, so they move no lane.
+        return jnp.concatenate([
+            _k1_dedup(payload[:, j:j + 128], upos, starts, firsts, ends,
+                      n_out, passes)
+            for j in range(0, lanes, 128)
+        ], axis=1)
     chunk = CHUNK
     group = _group_for(n // chunk, K1_GROUP)
     block = chunk * group
@@ -679,13 +693,14 @@ def scatter_apply_unique(update, tables, ids, g_rows, *, additive=False):
         raise ValueError(
             f"vocab {vocab} + stream cap {cap} overflows int32 row ids"
         )
-    rows, pay, count = unique_entries(
-        ids, g_rows, vocab=vocab, cap=cap, pad_first=True,
-        segment_sums=(
-            _xla_segment_sums if _use_interpret()
-            else functools.partial(_k1_dedup, passes=_EXACT_PASSES)
-        ),
-    )
+    with jax.named_scope("tffm.apply_prep"):  # sort, payload, K1
+        rows, pay, count = unique_entries(
+            ids, g_rows, vocab=vocab, cap=cap, pad_first=True,
+            segment_sums=(
+                _xla_segment_sums if _use_interpret()
+                else functools.partial(_k1_dedup, passes=_EXACT_PASSES)
+            ),
+        )
     chunk = min(SCATTER_CHUNK, cap)
     pad = -cap % chunk
     if pad:  # the last trip must not run off the stream
@@ -713,7 +728,8 @@ def scatter_apply_unique(update, tables, ids, g_rows, *, additive=False):
         )
 
     trips = (count + chunk - 1) // chunk
-    tables = jax.lax.fori_loop(0, trips, body, tuple(tables))
+    with jax.named_scope("tffm.apply_write"):
+        tables = jax.lax.fori_loop(0, trips, body, tuple(tables))
     return tables, count
 
 

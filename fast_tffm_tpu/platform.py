@@ -84,6 +84,20 @@ def ffm_compute_dtype(compute_dtype):
     return compute_dtype
 
 
+def ffm_matmul_precision(compute_dtype):
+    """What FFM's einsums ask of the MXU for operands of
+    ``compute_dtype``: float32 goes through whole (``HIGHEST``; the
+    TPU's default is ONE bf16 pass, which is what
+    ``compute_dtype=bfloat16`` asks for and float32 does not); bf16
+    keeps the default, its products being exact in the f32 accumulator
+    either way.  The ONE copy: the closed-form op
+    (ops.interaction) and the oracle (models.fm) share it."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.lax.Precision.HIGHEST if compute_dtype == jnp.float32 else None
+
+
 # ------------------------------------------------- persistent compile cache
 #
 # jax's on-disk compilation cache: a restart (or a replica spawn on the

@@ -1764,6 +1764,8 @@ class Trainer:
         # Reset IN PLACE so external references to trainer.telemetry
         # stay live.
         self.telemetry.reset()
+        # floats a table row holds: 1 + k, or 1 + field_num * k
+        self.telemetry.gauge("train.row_floats").set(cfg.embedding_dim)
         self.tracer.reset()
         # Fresh health carry + host cache per run; the nan_policy check
         # below reads the PREVIOUS dispatch's scalars (async-copied right

@@ -436,7 +436,8 @@ def sparse_step(
     ops.sparse_apply.make_entries_prefetch) — only legal on the sharded
     entries path, where it lifts the deduped-stream all-gather off the
     critical path (compute-overlapped exchange)."""
-    rows = params.table[batch.ids]  # [B, F, D]
+    with jax.named_scope("tffm.row_gather"):
+        rows = params.table[batch.ids]  # [B, F, D]
     loss_fn = _rows_loss_fn(
         cfg, batch, mesh, data_axis, compute_dtype=cfg.compute_jnp_dtype
     )

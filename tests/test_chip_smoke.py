@@ -70,7 +70,7 @@ def test_rehearsal_runs_every_phase_in_process(
     assert lines[-1] == final  # the result is the LAST line
     phases = [x["phase"] for x in lines[:-1]]
     want = (["device", "inputs", "sharded", "compile_cache"] if chips == 4
-            else ["device", "inputs", "train", "kernels", "predict",
+            else ["device", "inputs", "train", "kernels", "ffm", "predict",
                   "serve", "compile_cache"])
     assert phases == want
     assert lines[-2]["dir"] == cache_env  # the environment placed the cache

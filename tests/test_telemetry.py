@@ -405,6 +405,8 @@ class TestTrainerTelemetry:
         final = [json.loads(line) for line in open(mf)][-1]
         assert final["record"] == "final"
         gauges = final["stages"]["gauges"]
+        # the width the run trained, whatever the mesh: 1 + factor_num
+        assert gauges["train.row_floats"] == cfg.embedding_dim
         if devices == 1:
             assert 0.0 < gauges["train.apply_unique_frac"] <= 50 / 128
         else:

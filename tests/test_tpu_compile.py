@@ -175,6 +175,52 @@ def test_unique_scatter_apply(one_chip, no_persistent_cache, optimizer):
     assert "while" in compiled.as_text()
 
 
+D_FFM = 1 + 39 * 4  # LIBFFM's Criteo row: 39 fields, k = 4
+
+
+def test_unique_scatter_apply_at_three_payload_tiles(
+        one_chip, no_persistent_cache):
+    """The same apply at field-aware FM's row: [g | g^2 | lrow | tidx]
+    is 2 * 157 + 2 = 316 floats, three 128-lane tiles.  K1's output
+    windows land at dynamic row offsets, which Mosaic takes only in a
+    128-lane array ("Failed to prove that a tile index in dimension 0
+    is divisible by the tiling (8)" at 384 lanes): one call a tile."""
+    tab = _s((V_APPLY, D_FFM))
+    n = 16 * sparse_apply.CHUNK  # the sort's compile grows with n
+    compiled = compile_for(
+        one_chip,
+        lambda i, gr, *t: sparse_apply.scatter_apply_unique(
+            functools.partial(sparse_apply.adagrad_update, lr=0.2, eps=1e-7),
+            t, i, gr, additive=True),
+        _s((n,), jnp.int32), _s((n, D_FFM)), tab, tab,
+    )
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3 and "while" in text
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_field_aware_interaction_at_libffm_criteo_shape(
+        one_chip, no_persistent_cache, dtype):
+    """Forward and closed-form backward of the field-aware interaction
+    at 39 fields, k = 4, B = 4,096: the [B, 39, 39, 4] field-grouped
+    sums must not be laid out with k = 4 padded to 128 lanes (10 GB at
+    the cell's B = 16,384)."""
+    b = 4096
+
+    def loss(rows, vals, fields):
+        return jnp.sum(interaction.ffm_interaction(
+            rows, vals, fields, 4, 39, dtype))
+
+    structs = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+               for s in (_s((b, F, D_FFM)), _s((b, F)),
+                         _s((b, F), jnp.int32))]
+    with pf.force_compiled():
+        compiled = jax.jit(jax.grad(loss)).lower(*structs).compile()
+    mem = compiled.memory_analysis()
+    # rows in, gradient out: 0.1 GB each; everything between under 1.5 GB
+    assert mem.temp_size_in_bytes < 1.5 * 2**30, mem
+
+
 def test_whole_tile_step_at_criteo_kaggle_shape(topo, no_persistent_cache):
     """The program the trainer really dispatches for
     examples/criteo_kaggle.cfg on one chip: the scan-fused tile step
