@@ -89,6 +89,10 @@ ACC_TOL = {"rtol": 5e-4, "atol": 1e-5}
 # the hottest row (15k occurrences) read 7.5e-6, the weights 1.1e-6.
 SCATTER_ATOL = 3e-6
 TILE_ATOL = 2e-5
+# what _applies_vs_host may report (a case is left out where the
+# vocabulary or the row does not allow it)
+APPLY_CASES = ("unique_adagrad", "unique_ftrl", "stream_adagrad",
+               "stream_ftrl", "tile_adagrad")
 # bf16 compute rounds the interaction operands to 8 mantissa bits;
 # the repo's own bf16 tests (tests/test_bf16.py) hold this tolerance.
 BF16_TOL = {"rtol": 0.05, "atol": 0.02}
@@ -418,8 +422,10 @@ def _applies_vs_host(cfg, seed: int) -> dict:
     this phase that shares no sort, payload or K1 with the program:
 
     * ``unique_adagrad`` / ``unique_ftrl``: the single-device scatter
-      apply (``scatter_apply_unique``), its additive and its
-      gather-update-set form;
+      apply (``scatter_apply_unique``) through its scatter writer, its
+      additive and its gather-update-set form;
+    * ``stream_adagrad`` / ``stream_ftrl``: the same apply through its
+      transposed tile-stream writer (a vocabulary of whole subtiles);
     * ``tile_adagrad``: the tile kernels (K1 + K2), whose step-level
       oracle (``sparse_apply=scatter``) runs the same prep since PR 27;
       left out at a row they do not hold (their payload ``[g | g^2 |
@@ -463,23 +469,26 @@ def _applies_vs_host(cfg, seed: int) -> dict:
         z_ref, acc_ref,
     ]
 
-    def unique(update, additive):
+    def unique(update, additive, stream=False):
         def apply(i, gr, *tabs):
             return sparse_apply.scatter_apply_unique(
-                update, tabs, i, gr, additive=additive)
+                update, tabs, i, gr, additive=additive, stream=stream)
         return apply
 
     def tile(i, gr, t, a):
         return sparse_apply.adagrad_apply(t, a, i, gr, lr=lr, eps=eps), None
 
+    adagrad = partial(sparse_apply.adagrad_update, lr=lr, eps=eps)
+    ftrl = partial(sparse_apply.ftrl_update, lr=lr, l1=l1, l2=l2, beta=beta)
     cases = {
-        "unique_adagrad": (unique(partial(
-            sparse_apply.adagrad_update, lr=lr, eps=eps), True),
-            [table, acc], adagrad_ref),
-        "unique_ftrl": (unique(partial(
-            sparse_apply.ftrl_update, lr=lr, l1=l1, l2=l2, beta=beta),
-            False), [table, z, acc], ftrl_ref),
+        "unique_adagrad": (unique(adagrad, True), [table, acc], adagrad_ref),
+        "unique_ftrl": (unique(ftrl, False), [table, z, acc], ftrl_ref),
     }
+    if v % sparse_apply.TILE == 0:
+        cases["stream_adagrad"] = (
+            unique(adagrad, True, True), [table, acc], adagrad_ref)
+        cases["stream_ftrl"] = (
+            unique(ftrl, False, True), [table, z, acc], ftrl_ref)
     if 2 * d + 1 <= 128 and sparse_apply.supports_tile(v, "adagrad"):
         cases["tile_adagrad"] = (tile, [table, acc], adagrad_ref)
     out = {
@@ -547,7 +556,7 @@ def phase_kernels(out: Out, cfg, size: Size, work: str, seed: int):
     })
     require(_passed(f32), f"kernels differ from the XLA oracle: {f32}")
     require(_passed(b16), f"bf16 compute differs from f32: {b16}")
-    for name in ("unique_adagrad", "unique_ftrl", "tile_adagrad"):
+    for name in APPLY_CASES:
         got = applies.get(name)  # no tile case at a vocabulary it refuses
         require(
             got is None or (
@@ -623,7 +632,15 @@ def phase_ffm(out: Out, cfg, seed: int):
     )
     inter = _ffm_vs_host(ffm, seed)
     applies = _applies_vs_host(ffm, seed)
+    from fast_tffm_tpu.train import sparse as sparse_lib
+
+    # What gauge train.apply_stream reads on this backend at the
+    # benchmark's criteo-ffm-train shape (a fact of shapes: no table).
+    cell = dataclasses.replace(
+        ffm, vocabulary_size=1 << 22, batch_size=16384,
+        sparse_apply="scatter")
     out.emit({"phase": "ffm", "row_floats": ffm.embedding_dim,
+              "apply_stream_at_cell_shape": sparse_lib.apply_stream(cell),
               "payload_lanes": -(-(2 * ffm.embedding_dim + 2) // 128) * 128,
               "interaction_vs_host": inter, "applies_vs_host": applies,
               "memory": _mem(jax.devices()[0])})
@@ -631,12 +648,13 @@ def phase_ffm(out: Out, cfg, seed: int):
             <= inter["atol"],
             f"the float32 field-aware interaction differs from the "
             f"host's float64 pairs: {inter}")
-    for name in ("unique_adagrad", "unique_ftrl"):
-        got = applies[name]
+    for name in APPLY_CASES:
+        got = applies.get(name)  # no tile case at this row
         require(
-            got["rows_written"] == applies["unique_rows"]
-            and got["untouched_rows_changed"] == 0
-            and max(got["max_abs"]) <= got["atol"],
+            got is None or (
+                got["rows_written"] == applies["unique_rows"]
+                and got["untouched_rows_changed"] == 0
+                and max(got["max_abs"]) <= got["atol"]),
             f"the {name} apply at {ffm.embedding_dim} floats a row "
             f"differs from the host's per-occurrence sums: {got}",
         )

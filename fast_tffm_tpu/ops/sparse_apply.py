@@ -1,5 +1,5 @@
-"""Sparse optimizer apply on the TPU: sort + dedup, then tile kernels or
-a unique-row scatter.
+"""Sparse optimizer apply on the TPU: sort + dedup, then a unique-row
+write (a transposed tile stream or a scatter loop) or the tile kernels.
 
 The reference applies sparse updates with TF's SparseApplyAdagrad/-Ftrl over
 ``IndexedSlices`` (SURVEY.md §2 #8, §3.2): per step it updates only the rows
@@ -9,16 +9,28 @@ other, ~0.1 us each on v5e whatever the data (640k occurrences: 73 ms;
 2.56 M: 262 ms), and sparse Adagrad needed two such scatters and a re-gather
 of the accumulator, each over every occurrence.
 
-Both replacements here start from the same prep (``_prep``: sort the
+The replacements here start from the same prep (``_prep``: sort the
 occurrence ids, K1 sums ``g`` and ``g^2`` per unique row):
 
-* ``scatter_apply_unique`` — the ``scatter`` apply mode on one device.
-  Gathers, updates and scatters only the UNIQUE rows (sorted, unique
-  indices) into the un-padded ``[V, D]`` tables, in chunks, as many as the
-  batch's unique rows need.  Works at any V the chip holds.
+* ``scatter_apply_unique`` — the ``scatter`` apply mode on one device,
+  into the un-padded ``[V, D]`` tables, at any V the chip holds.  Which
+  of its two writers moves the unique rows is decided from static shapes
+  (``stream_wins``; gauge ``train.apply_stream``):
+
+  - the **stream** (``_stream_call``): every table whole through VMEM
+    once, as ``table.T`` — how ``[V, D]`` rests on the TPU, so no copy —
+    in blocks of TILE * group rows, the block's unique entries placed by
+    float32-exact one-hot matmuls.  Cost follows V * D: 29–30 ms in both
+    train cells of the benchmark (PERF.md §5), where the kernels run
+    compiled and V is whole subtiles;
+  - the **scatter loop**: gathers, updates and scatters the unique rows
+    with XLA's scatter, in chunks, as many as the batch's unique rows
+    need.  Cost follows the unique rows, 11–20 ns an element: a small
+    batch over a huge table, a vocabulary that is not whole subtiles,
+    and every run whose kernels would be interpreted.
 * the tile kernels — the ``tile`` / ``sharded`` apply modes, which need
   a TILE-aligned vocabulary and today a table small enough to be copied
-  into the kernels' layout (PERF.md §4).
+  into the kernels' row-major layout (PERF.md §4).
 
 The tile path replaces the scatter with a sort + two Pallas kernels, turning
 the random-access scatter into sequential streams and MXU matmuls:
@@ -213,22 +225,31 @@ def _k1_kernel(starts_ref, firsts_ref, ends_ref, payload_ref, upos_ref,
     prev_cp.wait()
 
 
+def _k1_tiles(payload, upos, starts, firsts, ends, n_out, passes=2):
+    """K1 over a payload of any width: one call a 128-lane tile, the
+    outputs as a list.  The output windows land at dynamic row offsets,
+    and only a [rows, 128] float32 array is row-contiguous in HBM's
+    (8, 128) tiling: a wider one keeps a row's lane tiles apart, and
+    Mosaic refuses the unaligned window ("Failed to prove that a tile
+    index in dimension 0 is divisible by the tiling (8)": field-aware
+    FM's 2 * 157 + 2 payload columns, three tiles).  The slices are
+    whole tiles, so they move no lane.  The stream writer windows the
+    tiles as they are (the same refusal meets a read)."""
+    return [
+        _k1_dedup(payload[:, j:j + 128], upos, starts, firsts, ends,
+                  n_out, passes)
+        for j in range(0, payload.shape[1], 128)
+    ]
+
+
 def _k1_dedup(payload, upos, starts, firsts, ends, n_out, passes=2):
     n, lanes = payload.shape
     if lanes > 128:
-        # One call a 128-lane tile.  The output windows land at dynamic
-        # row offsets, and only a [rows, 128] float32 array is row-
-        # contiguous in HBM's (8, 128) tiling: a wider one keeps a row's
-        # lane tiles apart, and Mosaic refuses the unaligned window
-        # ("Failed to prove that a tile index in dimension 0 is
-        # divisible by the tiling (8)": field-aware FM's 2 * 157 + 2
-        # payload columns, three tiles).  The slices and the
-        # concatenation are whole tiles, so they move no lane.
-        return jnp.concatenate([
-            _k1_dedup(payload[:, j:j + 128], upos, starts, firsts, ends,
-                      n_out, passes)
-            for j in range(0, lanes, 128)
-        ], axis=1)
+        # Whole tiles side by side again: moves no lane either.
+        return jnp.concatenate(
+            _k1_tiles(payload, upos, starts, firsts, ends, n_out, passes),
+            axis=1,
+        )
     chunk = CHUNK
     group = _group_for(n // chunk, K1_GROUP)
     block = chunk * group
@@ -284,6 +305,10 @@ def _placed_sums(u, cnt, d, tile):
     return dense[:, :d], dense[:, d:2 * d]  # sum(g), sum(g^2) per row
 
 
+def _round_up(x: int, multiple: int) -> int:
+    return -(-x // multiple) * multiple
+
+
 def _group_for(n_tiles: int, want: int | None = None) -> int:
     """Largest group <= want (default GROUP) dividing the tile count."""
     group = max(1, min(GROUP if want is None else want, n_tiles))
@@ -295,8 +320,8 @@ def _group_for(n_tiles: int, want: int | None = None) -> int:
 def _window_loop_raw(ts_ref, u_hbm_ref, u_vmem, sem, *, tile, group, body,
                      base=None):
     """Double-buffered entry-window loop — the ONE copy of the
-    slot/semaphore rotation protocol (layout-prototype kernels in
-    tools/micro_probe.py reuse it too; keep it that way).
+    slot/semaphore rotation protocol (the packed-layout prototype in
+    tools/micro_probe.py reuses it too; keep it that way).
 
     Walks ``group`` subtiles, DMA-ing each one's entry window while the
     previous subtile's compute runs (subtile j+1's copy is in flight
@@ -652,26 +677,304 @@ def _xla_segment_sums(payload, upos, starts, firsts, ends, n_out):
     )
 
 
-def scatter_apply_unique(update, tables, ids, g_rows, *, additive=False):
-    """XLA row-scatter optimizer apply that writes each touched row ONCE.
+# --------------------------------- the two writers of the one-device apply
+#
+# The tables do not rest row-major on the TPU: the compiler lays
+# f32[V, D] out {0,1:T(8,128)} — V on the 128 lanes, a row's floats on
+# sublanes — so XLA's scatter writes a "row" as D separate elements
+# (11 ns an element at D = 9, 20 ns at D = 157; PERF.md §5, PR 27 / 33)
+# and a row-major kernel costs whole-table copies there and back.  Read
+# the other way round that layout IS f32[D, V]{1,0:T(8,128)}, Mosaic's
+# default: a kernel handed ``table.T`` gets it without a copy, and a
+# [D, TILE * group] block of it is dense on the lanes.  So the apply has
+# two ways to move the same bytes, one algorithm and one update formula:
+#
+# * the scatter loop (scatter_apply_unique's second half): cost follows
+#   the batch's unique rows;
+# * the stream (_stream_call): every table whole through VMEM, once,
+#   the unique entries placed by one-hot matmuls: cost follows V.
+#
+# stream_wins() chooses from static shapes.
+
+
+# Entries a trip of the stream writer places: one 128 x 128 transpose a
+# payload lane tile, and a one-hot whose contraction fills a 128-deep
+# MXU pass.  A grid step's TILE * group rows hold ~50 unique entries in
+# both train cells (PERF.md §6, PR 34), so one trip a step is the rule
+# and the counted loop is for the fuller block.
+STREAM_WINDOW = 128
+
+
+def _placed_sums_t(u, cnt, first_tile, d, block):
+    """Window entries -> the block's dense per-row sums, TRANSPOSED
+    ([2 D to 16 sublanes, block]: rows 0..D-1 sum g, D..2D-1 sum g^2),
+    and how many entries hit each row ([1, block]).
+
+    ``u`` holds the window of each 128-lane stream, [streams, E, 128]
+    (the payload columns [g | g^2 | lrow | tidx | 0...] cut into lane
+    tiles); its first ``cnt`` entries belong to this block, whose first
+    TILE-row subtile is ``first_tile``.  ``dense_t[L, R] = u^T[L, E] .
+    onehot[E, R]`` with u as three bf16 terms, each rounding what the
+    ones before left over: an output column has ONE non-zero product a
+    term, so the three partial results add up to the float32 bit for
+    bit (as _EXACT_PASSES asks of K1; two terms would be a different
+    result).
+    """
+    window = u.shape[1]
+
+    def column(c):  # a metadata column as int32 [E, 1]: tpu.iota is
+        part, lane = divmod(c, 128)  # integer-only, the f32 an exact int
+        return u[part][:, lane:lane + 1].astype(jnp.int32)
+
+    # The window's tail belongs to later blocks or was never written:
+    # whatever it converts to is masked.
+    row = (column(2 * d + 1) - first_tile) * TILE + column(2 * d)
+    e_col = jax.lax.broadcasted_iota(jnp.int32, (window, 1), 0)
+    r_iota = jax.lax.broadcasted_iota(jnp.int32, (window, block), 1)
+    match = (row == r_iota) & (e_col < cnt)  # [entry, row of the block]
+    hits = jnp.sum(match.astype(jnp.float32), axis=0, keepdims=True)
+    p = match.astype(jnp.bfloat16)
+    # u^T, only the 2 D rows that hold sums (to whole bf16 sublane tiles).
+    ut = jnp.concatenate([
+        u[k].T[:min(128, _round_up(2 * d - 128 * k, 16))]
+        for k in range(-(-2 * d // 128))
+    ], axis=0)  # [L, E]
+    e_row = jax.lax.broadcasted_iota(jnp.int32, (1, window), 1)
+    # where(), not a multiply: the tail may hold NaN garbage.
+    rest = jnp.where(e_row < cnt, ut, 0.0)
+    terms = []
+    for _ in range(_EXACT_PASSES):
+        terms.append(rest.astype(jnp.bfloat16))
+        rest = rest - terms[-1].astype(jnp.float32)
+    lp = ut.shape[0]
+    if _EXACT_PASSES * lp <= 128:
+        # A narrow row: the one-hot is loaded into the MXU once for the
+        # three terms stacked on the rows, not three times.
+        placed = jax.lax.dot(
+            jnp.concatenate(terms, axis=0), p,
+            preferred_element_type=jnp.float32)
+        parts = [placed[i * lp:(i + 1) * lp] for i in range(_EXACT_PASSES)]
+    else:
+        parts = [jax.lax.dot(t, p, preferred_element_type=jnp.float32)
+                 for t in terms]
+    dense = parts[0]
+    for part in parts[1:]:
+        dense = dense + part
+    return dense, hits
+
+
+def _stream_kernel(gs_ref, *args, n_tables, n_streams, tile, group, d,
+                   update, additive):
+    """One grid step of the stream writer: a block of TILE * group rows
+    of every (transposed) table.  The block's unique entries, stream
+    positions ``gs_ref[t] .. gs_ref[t + 1]``, are placed STREAM_WINDOW
+    at a time (double-buffered DMA windows) into a VMEM accumulator;
+    then the update runs over the block a subtile at a time.  ``update``
+    and ``additive`` as scatter_apply_unique takes them (the weights'
+    new value is the old one PLUS what ``update`` makes of zeros: the
+    scatter writer's arithmetic operation for operation); a row the
+    batch did not touch keeps its bits whatever ``update`` would make of
+    zero sums (FTRL's recompute is NOT leaned on)."""
+    ins = args[:n_tables]
+    u_hbm_refs = args[n_tables:n_tables + n_streams]
+    outs = args[n_tables + n_streams:2 * n_tables + n_streams]
+    u_vmem, sem, sums_ref, hits_ref = args[2 * n_tables + n_streams:]
+    t = pl.program_id(0)
+    start, end = gs_ref[t], gs_ref[t + 1]
+    window, block = STREAM_WINDOW, tile * group
+    trips = (end - start + window - 1) // window
+
+    def copies(k, slot):
+        return [
+            pltpu.make_async_copy(
+                ref.at[pl.ds(start + k * window, window)],
+                u_vmem.at[slot, s], sem.at[slot, s],
+            )
+            for s, ref in enumerate(u_hbm_refs)
+        ]
+
+    @pl.when(trips > 0)
+    def _():
+        for cp in copies(0, 0):
+            cp.start()
+
+    sums_ref[...] = jnp.zeros_like(sums_ref)
+    hits_ref[...] = jnp.zeros_like(hits_ref)
+
+    def trip(k, carry):
+        slot = k % 2
+
+        @pl.when(k + 1 < trips)
+        def _():
+            for cp in copies(k + 1, 1 - slot):
+                cp.start()
+
+        for cp in copies(k, slot):
+            cp.wait()
+        dense, hits = _placed_sums_t(
+            u_vmem[slot], end - start - k * window, t * group, d, block)
+        sums_ref[...] += dense
+        hits_ref[...] += hits
+        return carry
+
+    jax.lax.fori_loop(0, trips, trip, 0)
+    for j in range(group):  # unrolled: all slices static
+        cols = pl.ds(j * tile, tile)
+        sums = sums_ref[:, cols]
+        g1, g2 = sums[:d], sums[d:2 * d]
+        touched = hits_ref[:, cols] > 0.0
+        old = [r[:, cols] for r in ins]
+        if additive:
+            new = update(g1, g2, jnp.zeros_like(old[0]), *old[1:])
+            new = (old[0] + new[0],) + tuple(new[1:])
+        else:
+            new = update(g1, g2, *old)
+        for out_ref, val, keep in zip(outs, new, old):
+            out_ref[:, cols] = jnp.where(touched, val, keep)
+
+
+# VMEM the stream writer's table blocks may take: each table has a
+# [D to 8 sublanes, TILE * group] float32 block in and one out, double-
+# buffered.  Half of the 16 MiB a v5e kernel gets by default; the
+# accumulator, the one-hot and the matmul's operands take 3 MiB more at
+# D = 157.
+_STREAM_BLOCK_BYTES = 8 << 20
+
+
+# Subtiles a grid step of the stream writer, at most.  FM cell's shape
+# on v5e, the write alone (my chip runs, PR 34): 2 -> 85.0 ms, 4 ->
+# 55.3, 8 -> 37.6, 16 -> 29.0, 32 -> 32.1 (a block of 8,192 rows holds
+# ~197 entries: two trips of STREAM_WINDOW every time).
+STREAM_GROUP = 16
+
+
+def _stream_group(n_tiles, d, n_tables):
+    """Subtiles a grid step of the stream writer: as _group_for, within
+    STREAM_GROUP and _STREAM_BLOCK_BYTES (16 at D = 9; 4 at D = 157,
+    two or three tables)."""
+    per_subtile = _round_up(d, 8) * TILE * 4 * 4 * n_tables
+    return _group_for(n_tiles, max(1, min(
+        STREAM_GROUP, _STREAM_BLOCK_BYTES // per_subtile)))
+
+
+def _stream_call(update, group_start, u_tiles, tables, additive, group):
+    """Stream ``tables`` whole through the transposed apply kernel.
+
+    ``u_tiles``: the unique-entry stream as its 128-lane tiles
+    (_k1_tiles), ``group_start`` the first entry of each block of
+    TILE * ``group`` rows (_tile_starts).  Each table goes in as ``t.T``
+    — a bitcast of how [V, D] rests on the TPU — aliased in place, and
+    comes back as ``out.T``: no whole-table copy, no write loop.
+    """
+    v, d = tables[0].shape
+    n_tables, n_streams = len(tables), len(u_tiles)
+    block = TILE * group
+    spec = pl.BlockSpec((d, block), lambda t, *_: (0, t))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(v // block,),
+        in_specs=[spec] * n_tables
+        + [pl.BlockSpec(memory_space=pl.ANY)] * n_streams,
+        out_specs=[spec] * n_tables,
+        scratch_shapes=[
+            pltpu.VMEM((2, n_streams, STREAM_WINDOW, 128), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, n_streams)),
+            pltpu.VMEM((_round_up(2 * d, 16), block), jnp.float32),
+            pltpu.VMEM((1, block), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _stream_kernel, n_tables=n_tables, n_streams=n_streams,
+            tile=TILE, group=group, d=d, update=update, additive=additive,
+        ),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((d, v), t.dtype) for t in tables],
+        input_output_aliases={1 + i: i for i in range(n_tables)},
+        interpret=_use_interpret(),
+    )(group_start, *(t.T for t in tables), *u_tiles)
+    return tuple(o.T for o in out)
+
+
+# The rule's two constants, v5e (my chip runs, PR 34; PERF.md §6).
+# The stream: the write alone over the table bytes it reads and writes —
+# FM cell's shape (V = 2^25, D = 9, two tables: 8.59e9 B) 29.0 ms =
+# 0.0034 ns a byte; FFM cell's (V = 2^22, D = 157: 10.74e9 B) 30.0 ms =
+# 0.0028; the slower one.
+_STREAM_NS_PER_TABLE_BYTE = 0.0034
+# The scatter loop: its time over occurrences x D x tables at the
+# cells' 0.31-0.34 unique rows an occurrence — FM cell 179.5 ms /
+# (2,555,904 x 9 x 2) = 3.9 ns (11 ns an element written); FFM cell
+# 1,442 ms / (638,976 x 157 x 2) = 7.2 ns (20 ns); the cheaper one, so
+# that a batch with fewer repeats than Zipf(1.1) only widens the
+# stream's lead where it is taken.
+_SCATTER_NS_PER_ELEMENT = 3.9
+
+
+def stream_wins(n_occurrences: int, vocab: int, d: int,
+                n_tables: int) -> bool:
+    """Which writer the one-device apply takes, from static shapes: True
+    = the stream.  The stream reads and writes every table whole,
+    ``vocab * (D to 8 sublanes) * 4 B * 2`` a table, whatever the batch;
+    the scatter writes the batch's unique rows an element at a time, at
+    most one row an occurrence.  Both train cells take the stream (FM:
+    29 against 179 ms, FFM: 37 against 783 by this count); 40k
+    occurrences over 2^25 rows keep the scatter (2.8 against 29 ms: the
+    crossover there is 417k occurrences).  The stream needs whole
+    subtiles (vocab % TILE == 0)."""
+    if vocab % TILE or vocab < TILE:
+        return False
+    stream_ns = (
+        vocab * _round_up(d, 8) * 4 * 2 * n_tables
+        * _STREAM_NS_PER_TABLE_BYTE
+    )
+    scatter_ns = (
+        min(n_occurrences, vocab) * d * n_tables * _SCATTER_NS_PER_ELEMENT
+    )
+    return stream_ns < scatter_ns
+
+
+def takes_stream(n_occurrences: int, vocab: int, d: int,
+                 n_tables: int) -> bool:
+    """What scatter_apply_unique does with ``stream=None``: the rule,
+    where the kernels run compiled (interpreted, the stream is a
+    correctness tool, as K1 is: its XLA stand-in and the scatter loop
+    run instead)."""
+    return not _use_interpret() and stream_wins(
+        n_occurrences, vocab, d, n_tables)
+
+
+def scatter_apply_unique(update, tables, ids, g_rows, *, additive=False,
+                         stream=None):
+    """One-device optimizer apply that writes each touched row ONCE.
 
     The per-occurrence scatter costs ~0.1 us a row on v5e whatever the
     data (PERF.md §5), and hashed CTR batches repeat ids heavily (69% of
     the occurrences of a Zipf(1.1) Criteo batch are duplicates).  So:
     sort + segment-sum the occurrences into the unique stream
     (:func:`unique_entries`: ``sum g`` and the per-occurrence ``sum g²``
-    per row), then gather -> ``update`` -> scatter only the unique rows,
-    with sorted, unique indices.  ``update(g1, g2, *table_rows) ->
-    new_table_rows`` is one of the shared elementwise formulas
-    (adagrad_update / ftrl_update / sgd_update), exactly as K2 takes it.
+    per row), then write only the unique rows.  ``update(g1, g2,
+    *table_rows) -> new_table_rows`` is one of the shared elementwise
+    formulas (adagrad_update / ftrl_update / sgd_update), exactly as K2
+    takes it.
 
-    ``additive``: ``update``'s first output is ``tables[0]`` minus a
-    term that does not read it (Adagrad, SGD).  The weights are then not
-    gathered: ``update`` sees zeros in their place and what it returns
-    is scatter-ADDed, the same float32 subtraction done by the scatter.
+    ``stream``: which of the two writers above moves the rows — None =
+    :func:`stream_wins` decides from the shapes, where the kernels run
+    compiled (an interpreted stream is a correctness tool: tests force
+    it with True).  Both run the same prep and the same ``update`` and
+    agree bit for bit.
 
-    The stream has the static length ``cap`` but only ``count`` real
-    entries; its tail is padded with DISTINCT out-of-range rows
+    The scatter writer gathers, updates and scatters the unique rows
+    with sorted, unique indices.  ``additive``: ``update``'s first
+    output is ``tables[0]`` minus a term that does not read it (Adagrad,
+    SGD).  The weights are then not gathered: ``update`` sees zeros in
+    their place and what it returns is scatter-ADDed, the same float32
+    subtraction done by the scatter.  (The stream reads every table
+    anyway, and does the same two operations.)
+
+    The unique stream has the static length ``cap`` but only ``count``
+    real entries; for the scatter its tail is padded with DISTINCT
+    out-of-range rows
     (``vocab, vocab+1, ...`` — so ``unique_indices`` stays true) that
     ``mode="drop"`` never writes.  A dropped row costs what a written
     one does, so the apply walks the stream in SCATTER_CHUNK pieces and
@@ -688,6 +991,10 @@ def scatter_apply_unique(update, tables, ids, g_rows, *, additive=False):
     ``(new_tables, count)``.
     """
     vocab, d = tables[0].shape
+    if stream is None:
+        stream = takes_stream(ids.shape[0], vocab, d, len(tables))
+    if stream:
+        return _stream_apply_unique(update, tables, ids, g_rows, additive)
     cap = entries_cap(ids.shape[0], vocab)
     if vocab + cap >= 1 << 31:
         raise ValueError(
@@ -730,6 +1037,34 @@ def scatter_apply_unique(update, tables, ids, g_rows, *, additive=False):
     trips = (count + chunk - 1) // chunk
     with jax.named_scope("tffm.apply_write"):
         tables = jax.lax.fori_loop(0, trips, body, tuple(tables))
+    return tables, count
+
+
+def _stream_apply_unique(update, tables, ids, g_rows, additive):
+    """scatter_apply_unique through the stream writer: the same sort,
+    payload and three-pass K1 (its XLA stand-in where interpreted); the
+    entries are found per block of TILE * group rows by ``group_start``
+    and placed by their lrow / tidx columns, not recovered as rows."""
+    vocab, d = tables[0].shape
+    with jax.named_scope("tffm.apply_prep"):  # sort, payload, K1
+        payload, upos, starts, firsts, ends, sidx, n_pad = _prep(
+            ids, g_rows, vocab, pad_first=True
+        )
+        if _use_interpret():
+            u = _xla_segment_sums(
+                payload, upos, starts, firsts, ends, n_pad + TILE)
+            u_tiles = [u[:, j:j + 128] for j in range(0, u.shape[1], 128)]
+        else:
+            u_tiles = _k1_tiles(payload, upos, starts, firsts, ends,
+                                n_pad + TILE, _EXACT_PASSES)
+        group = _stream_group(vocab // TILE, d, len(tables))
+        bounds = jnp.arange(0, vocab + 1, TILE * group, dtype=sidx.dtype)
+        # the binary search unrolled: the step holds no loop of XLA's
+        group_start = _tile_starts(sidx, upos, bounds, "scan_unrolled")
+        count = group_start[-1]  # uniques among real (non-sentinel) rows
+    with jax.named_scope("tffm.apply_write"):
+        tables = _stream_call(
+            update, group_start, u_tiles, tables, additive, group)
     return tables, count
 
 
@@ -856,11 +1191,12 @@ def make_entries_prefetch(mesh, data_axis, model_axis, vocab):
 # ------------------------------------------------------------ orchestration
 
 
-def _tile_starts(sidx, upos, boundaries):
-    """Unique-entry index of the first id >= each row boundary."""
+def _tile_starts(sidx, upos, boundaries, method="scan"):
+    """Unique-entry index of the first id >= each row boundary
+    (``method`` as jnp.searchsorted takes it)."""
     n_unique = upos[-1] + 1
     upos_ext = jnp.concatenate([upos, n_unique[None]])
-    ss = jnp.searchsorted(sidx, boundaries)
+    ss = jnp.searchsorted(sidx, boundaries, method=method)
     return upos_ext[ss].astype(jnp.int32)
 
 

@@ -1766,6 +1766,11 @@ class Trainer:
         self.telemetry.reset()
         # floats a table row holds: 1 + k, or 1 + field_num * k
         self.telemetry.gauge("train.row_floats").set(cfg.embedding_dim)
+        # which writer the compiled step's one-device apply holds:
+        # 1 = the transposed tile stream, 0 = the scatter loop (or
+        # another apply mode altogether)
+        self.telemetry.gauge("train.apply_stream").set(int(
+            self.sparse and sparse_lib.apply_stream(self._dcfg, self.mesh)))
         self.tracer.reset()
         # Fresh health carry + host cache per run; the nan_policy check
         # below reads the PREVIOUS dispatch's scalars (async-copied right

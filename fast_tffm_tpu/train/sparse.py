@@ -89,6 +89,21 @@ def apply_mode(cfg: FmConfig, mesh=None) -> str:
     return "scatter"
 
 
+def apply_stream(cfg: FmConfig, mesh=None) -> bool:
+    """Whether the step compiled for ``cfg`` on ``mesh`` writes its
+    touched rows with the transposed tile stream (gauge
+    ``train.apply_stream``): the one-device scatter apply, where
+    ops.sparse_apply's rule takes the stream for the step's shapes."""
+    if (mesh is not None and mesh.size > 1) or not supports_sparse(cfg):
+        return False
+    if apply_mode(cfg, mesh) != "scatter":
+        return False
+    return sparse_apply.takes_stream(
+        cfg.batch_size * cfg.max_features, cfg.vocabulary_size,
+        cfg.embedding_dim, {"adagrad": 2, "ftrl": 3, "sgd": 1}[cfg.optimizer],
+    )
+
+
 class SparseAdagradState(NamedTuple):
     acc: fm.FmParams  # per-weight squared-gradient accumulators
 

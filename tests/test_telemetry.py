@@ -407,6 +407,9 @@ class TestTrainerTelemetry:
         gauges = final["stages"]["gauges"]
         # the width the run trained, whatever the mesh: 1 + factor_num
         assert gauges["train.row_floats"] == cfg.embedding_dim
+        # which writer the compiled step holds: interpreted kernels
+        # (this CPU) and a mesh both keep the scatter loop
+        assert gauges["train.apply_stream"] == 0
         if devices == 1:
             assert 0.0 < gauges["train.apply_unique_frac"] <= 50 / 128
         else:

@@ -154,9 +154,10 @@ def test_sparse_apply_kernels(one_chip, no_persistent_cache, case):
 
 @pytest.mark.parametrize("optimizer", ["adagrad", "ftrl"])
 def test_unique_scatter_apply(one_chip, no_persistent_cache, optimizer):
-    """The single-device scatter apply: sort, K1 with the three passes
-    it asks for, and the counted loop of unique-row gathers and scatters
-    (additive under Adagrad, gather-update-set under FTRL)."""
+    """The single-device scatter apply through its scatter writer:
+    sort, K1 with the three passes it asks for, and the counted loop of
+    unique-row gathers and scatters (additive under Adagrad,
+    gather-update-set under FTRL)."""
     tab, ids, g = _s((V_APPLY, D)), _s((N_OCC,), jnp.int32), _s((N_OCC, D))
     if optimizer == "ftrl":
         update = functools.partial(
@@ -169,7 +170,8 @@ def test_unique_scatter_apply(one_chip, no_persistent_cache, optimizer):
     compiled = compile_for(
         one_chip,
         lambda i, gr, *t: sparse_apply.scatter_apply_unique(
-            update, t, i, gr, additive=optimizer == "adagrad"),
+            update, t, i, gr, additive=optimizer == "adagrad",
+            stream=False),
         ids, g, *tables,
     )
     assert "while" in compiled.as_text()
@@ -191,11 +193,60 @@ def test_unique_scatter_apply_at_three_payload_tiles(
         one_chip,
         lambda i, gr, *t: sparse_apply.scatter_apply_unique(
             functools.partial(sparse_apply.adagrad_update, lr=0.2, eps=1e-7),
-            t, i, gr, additive=True),
+            t, i, gr, additive=True, stream=False),
         _s((n,), jnp.int32), _s((n, D_FFM)), tab, tab,
     )
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3 and "while" in text
+
+
+@pytest.mark.parametrize("d,vocab", [(D, 1 << 25), (D_FFM, 1 << 22)])
+@pytest.mark.parametrize("optimizer", ["adagrad", "ftrl"])
+def test_stream_apply_holds_no_table_copy_and_no_loop(
+        one_chip, no_persistent_cache, optimizer, d, vocab):
+    """The same apply through the stream writer, at the two train
+    cells' rows and vocabularies (the tables are shapes: nothing is
+    allocated).  [V, D] rests {0,1:T(8,128)}, which is [D, V] in
+    Mosaic's default layout: ``table.T`` must reach the kernel as a
+    bitcast — no copy of a whole table in either shape — the tables
+    aliased from the step's arguments through the kernel to its
+    results, and no loop anywhere (the scatter writer's ``while``; the
+    tile-start search is unrolled)."""
+    import re
+
+    n = 16 * sparse_apply.CHUNK  # the sort's compile grows with n
+    if optimizer == "ftrl":
+        update = functools.partial(
+            sparse_apply.ftrl_update, lr=0.1, l1=0.01, l2=0.01, beta=1.0)
+    else:
+        update = functools.partial(
+            sparse_apply.adagrad_update, lr=0.1, eps=1e-7)
+    n_tables = 3 if optimizer == "ftrl" else 2
+    compiled = compile_for(
+        one_chip,
+        lambda i, gr, *t: sparse_apply.scatter_apply_unique(
+            update, t, i, gr, additive=optimizer == "adagrad", stream=True),
+        _s((n,), jnp.int32), _s((n, d)), *[_s((vocab, d))] * n_tables,
+        donate_argnums=tuple(range(2, 2 + n_tables)),
+    )
+    text = compiled.as_text()
+    table = rf"f32\[({vocab},{d}|{d},{vocab})\]"
+    moved = [ln.strip()[:160] for ln in text.splitlines()
+             if re.search(rf"= {table}\S* (copy|copy-start|transpose)\(", ln)]
+    assert not moved, moved
+    assert not re.search(r"\bwhile\(", text)
+    # K1 a payload lane tile, and the writer
+    assert text.count("tpu_custom_call") == -(-(2 * d + 2) // 128) + 1
+    header = text.splitlines()[0]
+    for k in range(n_tables):  # argument 2 + k is result k, in place
+        assert f"{{{k}}}: ({2 + k}, {{}}, may-alias)" in header, header
+    writer = next(ln for ln in text.splitlines()
+                  if "custom-call(" in ln and "tffm.apply_write" in ln)
+    assert writer.count(f"{d},{vocab}") >= 2 * n_tables, writer
+    for k in range(n_tables):
+        assert f"{{{k}}}: ({1 + k}, {{}})" in writer, writer
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 << 20, mem  # a table is >= 2 GiB
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

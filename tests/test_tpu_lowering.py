@@ -297,20 +297,16 @@ class TestFullStepLowering:
         lower_tpu(step, params, opt, batch)
 
 
-def test_transposed_k2_probe_lowers():
-    """The micro_probe's transposed-K2 prototype must pass Mosaic
-    lowering so it cannot waste hardware-window time (its column-block
-    specs (9, block) differ structurally from production's (block, 9))."""
-    import os
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
-                                    "tools"))
-    import micro_probe
-
+def test_transposed_stream_writer_lowers():
+    """The transposed tile-stream writer of the one-device apply (grown
+    from the micro_probe's transposed-K2 prototype, which it replaced)
+    must pass Mosaic lowering: its column-block specs (9, block) differ
+    structurally from the row-major K2's (block, 9)."""
+    update = functools.partial(sparse_apply.adagrad_update, lr=0.05, eps=1e-7)
     lower_tpu(
-        functools.partial(micro_probe.k2t_apply, lr=0.05, eps=1e-7),
-        _s((D, V)), _s((D, V)), _s((N,), jnp.int32), _s((N, D)),
+        lambda i, g, *t: sparse_apply.scatter_apply_unique(
+            update, t, i, g, additive=True, stream=True),
+        _s((N,), jnp.int32), _s((N, D)), _s((V, D)), _s((V, D)),
     )
 
 

@@ -215,3 +215,165 @@ def test_payload_padded_first_is_the_plain_payload_bit_for_bit(n):
     )(g, perm, lrow, tidx)
     assert got.shape == want.shape == (n, 128)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# ----------------------------------------------- the two writers (PR 34)
+#
+# scatter_apply_unique moves the unique rows either by XLA's scatter loop
+# or by the transposed tile stream (a Pallas kernel, interpreted here).
+# Same prep, same update formula, same float32 operations: bit for bit.
+
+_STREAM_V = 2048  # eight 256-row subtiles, two grid steps at D = 157
+
+
+def _writer_ids(rng, scenario):
+    v, tile = _STREAM_V, 256
+    if scenario == "heavy_duplicates":
+        return rng.zipf(1.1, size=2048) % v
+    if scenario == "subtile_edges":
+        # first and last row of subtiles, of the table, and repeats
+        edge = np.array([0, 255, 256, 511, 1023, 1024, v - 256, v - 1])
+        return np.concatenate([edge, edge[::2], rng.integers(0, v, 100)])
+    if scenario == "empty_subtiles":
+        # nothing lands in subtile 2 nor in the last one
+        ids = rng.integers(0, v, size=1500)
+        return ids[(ids // tile != 2) & (ids // tile != v // tile - 1)]
+    assert scenario == "full_subtile"
+    # every row of subtile 1 (a window of 256 entries), some twice
+    return np.concatenate([
+        np.arange(tile, 2 * tile), rng.integers(tile, 2 * tile, 64),
+        rng.integers(0, v, 200),
+    ])
+
+
+def _writer_case(optimizer, d, rng):
+    """(update, additive, tables): tables no optimizer would have made
+    (the weights are NOT ftrl_solve(z, n)), so a recomputed untouched
+    row would show."""
+    from functools import partial
+
+    from fast_tffm_tpu.ops import sparse_apply
+
+    v = _STREAM_V
+    w = rng.uniform(-0.1, 0.1, size=(v, d)).astype(np.float32)
+    s1 = rng.uniform(-1.0, 1.0, size=(v, d)).astype(np.float32)
+    s2 = rng.uniform(0.1, 0.5, size=(v, d)).astype(np.float32)
+    if optimizer == "adagrad":
+        return (partial(sparse_apply.adagrad_update, lr=0.1, eps=1e-7),
+                True, [w, s2])
+    if optimizer == "sgd":
+        return partial(sparse_apply.sgd_update, lr=0.1), True, [w]
+    return (partial(sparse_apply.ftrl_update, lr=0.1, l1=0.01, l2=0.001,
+                    beta=1.0), False, [w, s1, s2])
+
+
+def _run_writer(update, additive, tables, ids, g, stream):
+    from fast_tffm_tpu.ops import sparse_apply
+
+    out, count = jax.jit(
+        lambda i, gr, *t: sparse_apply.scatter_apply_unique(
+            update, t, i, gr, additive=additive, stream=stream)
+    )(jnp.asarray(ids, jnp.int32), jnp.asarray(g), *tables)
+    return [np.asarray(x) for x in out], int(count)
+
+
+@pytest.mark.parametrize("scenario", [
+    "heavy_duplicates", "subtile_edges", "empty_subtiles", "full_subtile",
+])
+@pytest.mark.parametrize("d", [9, 157])  # one payload lane tile, three
+@pytest.mark.parametrize("optimizer", ["adagrad", "ftrl", "sgd"])
+def test_stream_writer_is_the_scatter_writer_bit_for_bit(
+        optimizer, d, scenario):
+    rng = np.random.default_rng([len(optimizer), d, len(scenario)])
+    ids = _writer_ids(rng, scenario)
+    g = (rng.normal(size=(len(ids), d)) * 0.1).astype(np.float32)
+    update, additive, tables = _writer_case(optimizer, d, rng)
+    want, n_want = _run_writer(update, additive, tables, ids, g, False)
+    got, n_got = _run_writer(update, additive, tables, ids, g, True)
+    assert n_got == n_want == len(np.unique(ids))
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(
+            a.view(np.uint32), b.view(np.uint32))
+    if scenario == "full_subtile":
+        assert len(np.unique(ids[(ids >= 256) & (ids < 512)])) == 256
+
+
+@pytest.mark.parametrize("d", [9, 157])
+@pytest.mark.parametrize("optimizer", ["adagrad", "ftrl", "sgd"])
+def test_stream_writer_leaves_untouched_rows_their_bits(optimizer, d):
+    """The stream reads and writes EVERY row; a row the batch did not
+    touch must come back as it went in, under FTRL too, whose update of
+    zero sums would recompute the weight from (z, n)."""
+    from fast_tffm_tpu.ops import sparse_apply
+
+    rng = np.random.default_rng([d, len(optimizer)])
+    ids = rng.integers(0, _STREAM_V, size=700)
+    g = (rng.normal(size=(len(ids), d)) * 0.1).astype(np.float32)
+    update, additive, tables = _writer_case(optimizer, d, rng)
+    if optimizer == "ftrl":  # the seeds are such a state
+        w, z, n = tables
+        solved = np.asarray(sparse_apply.ftrl_solve(
+            jnp.asarray(z), jnp.asarray(n), 0.1, 0.01, 0.001, 1.0))
+        assert np.mean(solved != w) > 0.99
+    got, count = _run_writer(update, additive, tables, ids, g, True)
+    touched = np.unique(ids)
+    rest = np.setdiff1d(np.arange(_STREAM_V), touched)
+    assert count == len(touched) and len(rest) > 1000
+    for a, old in zip(got, tables):
+        np.testing.assert_array_equal(
+            a[rest].view(np.uint32), old[rest].view(np.uint32))
+        assert np.all(np.any(a[touched] != old[touched], axis=1))
+
+
+@pytest.mark.parametrize("n,vocab,d,tables,stream", [
+    # the two train cells of the benchmark (PERF.md §4): the stream
+    (65536 * 39, 1 << 25, 9, 2, True),
+    (16384 * 39, 1 << 22, 157, 2, True),
+    (16384 * 39, 1 << 22, 157, 3, True),  # the same under FTRL
+    # a small batch over a huge table: 2.8 ms of scatter, 29 of stream
+    (40_000, 1 << 25, 9, 2, False),
+    # a vocabulary that is not whole subtiles: never the stream
+    (65536 * 39, (1 << 25) + 8, 9, 2, False),
+    (16384 * 39, 255, 157, 2, False),
+    # Criteo-Kaggle as examples/criteo_kaggle.cfg runs it
+    (4096 * 39, 1 << 22, 9, 2, True),
+])
+def test_the_rule_between_the_two_writers(n, vocab, d, tables, stream):
+    """stream_wins is a pure function of static shapes; takes_stream is
+    the rule where the kernels run compiled, and the scatter loop where
+    they would be interpreted (here)."""
+    from fast_tffm_tpu import platform as pf
+    from fast_tffm_tpu.ops import sparse_apply
+
+    assert sparse_apply.stream_wins(n, vocab, d, tables) is stream
+    assert sparse_apply.takes_stream(n, vocab, d, tables) is False
+    with pf.force_compiled():
+        assert sparse_apply.takes_stream(n, vocab, d, tables) is stream
+
+
+@pytest.mark.parametrize("case,want", [
+    ("one_device_scatter", True), ("mesh_of_eight", False),
+    ("tile_mode", False), ("dense_optimizer", False),
+])
+def test_apply_stream_names_the_writer_the_step_holds(case, want, tmp_path):
+    """train.sparse.apply_stream (gauge ``train.apply_stream``): 1 only
+    for the one-device scatter apply at shapes where the rule takes the
+    stream, and never where the kernels would be interpreted."""
+    from fast_tffm_tpu import platform as pf
+
+    kw = dict(vocabulary_size=1 << 22, batch_size=16384, max_features=39,
+              factor_num=4, field_num=39, sparse_apply="scatter",
+              model_file=str(tmp_path / "unused"))
+    mesh = None
+    if case == "mesh_of_eight":
+        kw.update(mesh_data=4, mesh_model=2)
+    elif case == "tile_mode":
+        kw.update(sparse_apply="tile")
+    elif case == "dense_optimizer":
+        kw.update(optimizer="adam")
+    cfg = FmConfig(**kw)
+    if case == "mesh_of_eight":
+        mesh = mesh_lib.make_mesh(cfg)
+    assert sparse.apply_stream(cfg, mesh) is False  # interpreted here
+    with pf.force_compiled():
+        assert sparse.apply_stream(cfg, mesh) is want

@@ -18,9 +18,10 @@ here isolates one design question for those:
            one key as an alternative to sort_key_val).
 
 Timing: completion is forced by fetching one scalar from every output
-leaf (``bench`` / ``_drain`` below).  ``k2t_apply`` / ``k2p_apply`` are
-the apply-kernel candidates of ROADMAP.md Speed 1;
-tests/test_tpu_lowering.py keeps them lowering.
+leaf (``bench`` / ``_drain`` below).  ``k2p_apply`` is the packed-layout
+apply-kernel candidate of ROADMAP.md Speed 1; tests/test_tpu_lowering.py
+keeps it lowering.  (The transposed candidate, ``k2t_apply``, became
+the one-device apply's stream writer: ops/sparse_apply.py, PR 34.)
 """
 
 from __future__ import annotations
@@ -62,62 +63,6 @@ def bench(fn, *args, steps=20):
         r = fn(*args)
     _drain(r)
     return (time.perf_counter() - t0) * 1e3 / steps
-
-
-def _k2t_kernel(ts_ref, table_ref, acc_ref, u_hbm_ref, table_out_ref,
-                acc_out_ref, u_vmem, sem, *, tile, group, d, lr, eps):
-    def body(j, u, cnt):
-        e_iota = jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
-        u = jnp.where(e_iota < cnt, u, 0.0)
-        lrow = u[:, 2 * d:2 * d + 1].astype(jnp.int32)
-        r_iota = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
-        p = ((lrow == r_iota) & (e_iota < cnt)).astype(jnp.bfloat16)
-        u_hi = u.astype(jnp.bfloat16)
-        u_lo = (u - u_hi.astype(jnp.float32)).astype(jnp.bfloat16)
-        dn = (((0,), (0,)), ((), ()))  # contract entries -> [L, R]
-        dense_t = (
-            jax.lax.dot_general(u_hi, p, dn,
-                                preferred_element_type=jnp.float32)
-            + jax.lax.dot_general(u_lo, p, dn,
-                                  preferred_element_type=jnp.float32)
-        )
-        g1t = dense_t[:d, :]  # [D, R]
-        g2t = dense_t[d:2 * d, :]
-        cols = pl.ds(j * tile, tile)
-        acc_new = acc_ref[:, cols] + g2t
-        table_out_ref[:, cols] = table_ref[:, cols] - lr * g1t * (
-            jax.lax.rsqrt(acc_new + eps))
-        acc_out_ref[:, cols] = acc_new
-
-    sa._window_loop_raw(
-        ts_ref, u_hbm_ref, u_vmem, sem, tile=tile, group=group, body=body
-    )
-
-def k2t_apply(table_t, acc_t, ids_, g_rows, *, lr, eps):
-    vocab = table_t.shape[1]
-    d = table_t.shape[0]
-    u, tile_start = sa._dedup_and_starts(ids_, g_rows, vocab)
-    tile, group = sa.TILE, sa._group_for(vocab // sa.TILE)
-    block = tile * group
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(vocab // block,),
-        in_specs=[pl.BlockSpec((d, block), lambda t, *_: (0, t))] * 2
-        + [pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=[pl.BlockSpec((d, block), lambda t, *_: (0, t))] * 2,
-        scratch_shapes=[
-            pltpu.VMEM((2, tile, u.shape[1]), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    return pl.pallas_call(
-        _partial(_k2t_kernel, tile=tile, group=group, d=d, lr=lr,
-                 eps=eps),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((d, vocab), jnp.float32)] * 2,
-        input_output_aliases={1: 0, 2: 1},
-        interpret=use_interpret(),
-    )(tile_start, table_t, acc_t, u)
 
 
 def _k2p_kernel(ts_ref, table_ref, acc_ref, u_hbm_ref, table_out_ref,
@@ -406,64 +351,18 @@ def main() -> int:
             f"sorted {ms_s:7.3f} ms", flush=True)
         del tb, g
 
-    # ---- transposed-K2 prototype --------------------------------------
-    # The production K2 streams the [V, 9] table whose HBM rows are
-    # 128-lane padded (~14x physical traffic if the memory_stats probe
-    # above confirms tiling).  This prototype streams a TRANSPOSED
-    # [9, V] table in column blocks (dense minor dim; sublanes pad
-    # 9->16, only ~1.8x) with the placement matmul transposed to match.
-    # If it wins by the traffic ratio, the table-layout redesign is
-    # justified; adagrad only, same windowed u stream as production K2.
+    # ---- packed-K2 prototype ------------------------------------------
+    # A layout option: [V/8, 128] super-rows (8 rows x 16 lanes).
+    # Physical stream ~1.8x logical (16/9) with a dense 128-lane minor
+    # dim — vs ~14x for lane-padded [V, 9].  Costs two extra lane-spread
+    # matmuls per subtile; whether that trade wins is exactly what this
+    # times against production's row-major K2.
     d9 = 9
     gk = jax.device_put(
         jnp.asarray(rng.uniform(-1e-2, 1e-2, (N, d9)), jnp.float32))
     tbl = jax.device_put(
         jnp.asarray(rng.uniform(-0.1, 0.1, (V, d9)), jnp.float32))
     accv = jnp.full((V, d9), 0.1, jnp.float32)
-    k2t = jax.jit(lambda tt, at, i, g: k2t_apply(
-        tt, at, i, g, lr=0.05, eps=1e-7))
-    try:
-        # Correctness vs the scatter reference (transposed back).
-        if jax.default_backend() == "cpu":
-            # Interpret mode runs the grid in Python: tiny shapes only.
-            vs, ns = 4096, 2048
-            tbs = jnp.asarray(rng.uniform(-0.1, 0.1, (vs, d9)), jnp.float32)
-            acs = jnp.full((vs, d9), 0.1, jnp.float32)
-            idss = jnp.asarray(rng.integers(0, vs, (ns,)), jnp.int32)
-            gs = jnp.asarray(
-                rng.uniform(-1e-2, 1e-2, (ns, d9)), jnp.float32)
-            t_t, a_t = k2t(tbs.T, acs.T, idss, gs)
-            a_ref2 = acs.at[idss].add(gs * gs)
-            t_ref2 = tbs.at[idss].add(
-                -0.05 * gs * jax.lax.rsqrt(a_ref2[idss] + 1e-7))
-            errt = float(jnp.max(jnp.abs(t_t.T - t_ref2)))
-            print(f"  K2-transposed parity err {errt:.2e} (interpret, "
-                  f"V={vs} n={ns})", flush=True)
-        else:
-            t_t, a_t = k2t(tbl.T, accv.T, ids, gk)
-            a_ref2 = accv.at[ids].add(gk * gk)
-            t_ref2 = tbl.at[ids].add(
-                -0.05 * gk * jax.lax.rsqrt(a_ref2[ids] + 1e-7))
-            errt = float(jnp.max(jnp.abs(t_t.T - t_ref2)))
-            ms_t = bench(k2t, tbl.T, accv.T, ids, gk)
-            prod = jax.jit(lambda tb, a, i, g: sa.adagrad_apply(
-                tb, a, i, g, lr=0.05, eps=1e-7))
-            ms_p = bench(prod, tbl, accv, ids, gk)
-            print(
-                f"  K2 transposed [9,V]: {ms_t:7.3f} ms vs production "
-                f"[V,9]: {ms_p:7.3f} ms (parity err {errt:.2e})",
-                flush=True)
-        del t_t, a_t
-    except Exception as exc:  # noqa: BLE001 — a probe must not die here
-        print(f"  K2-transposed probe FAILED: {type(exc).__name__}: "
-              f"{str(exc).splitlines()[0][:140]}", flush=True)
-
-    # ---- packed-K2 prototype ------------------------------------------
-    # Third layout option: [V/8, 128] super-rows (8 rows x 16 lanes).
-    # Physical stream ~1.8x logical (16/9) with a dense 128-lane minor
-    # dim — vs ~14x for lane-padded [V, 9].  Costs two extra lane-spread
-    # matmuls per subtile; whether that trade wins is exactly what this
-    # times against production and the transposed prototype.
     k2p = jax.jit(_partial(k2p_apply, lr=0.05, eps=1e-7))
     try:
         if jax.default_backend() == "cpu":
@@ -491,7 +390,7 @@ def main() -> int:
             ms_pk = bench(k2p, tp, ap, ids, gk)
             print(
                 f"  K2 packed [V/8,128]: {ms_pk:7.3f} ms (parity err "
-                f"{errp:.2e}); compare transposed/production above",
+                f"{errp:.2e}); compare the production K2",
                 flush=True)
         del t_p, a_p
     except Exception as exc:  # noqa: BLE001 — a probe must not die here
