@@ -30,7 +30,9 @@ script exits nonzero — nothing is caught and carried past):
 
 ``--chips 4`` runs ONLY the 2 data x 2 model row-sharded step
 (``lookup=shardmap``, both sparse exchanges) against the one-device step on
-the same batches, and checks that placement is real.
+the same batches, and checks that placement is real; then the entries
+step once more at a shard of 2^24 rows, where the row-major K2 does not
+compile and the merged stream has to go through the stream writer.
 
 Without a TPU the script fails: it never continues on the CPU.
 ``--rehearse`` is the sandbox rehearsal — the same phases at a toy size on
@@ -103,6 +105,9 @@ FFM_FIELDS, FFM_K, FFM_VOCAB, FFM_EXAMPLES = 39, 4, 1 << 16, 512
 # of O(0.1) summed from 741 pairs; one bf16 pass on the MXU (what float32
 # operands get without a stated precision) reads ~1e-3 on both.
 FFM_ATOL = 2e-5
+# --chips 4: rows a model shard of the big case holds -- the first size
+# at which the compiler refuses the row-major K2's whole-shard copies.
+BIG_SHARD_ROWS = 1 << 24
 # Served vs predicted probabilities: both print %.6f, and a request is
 # padded to another ladder rung than predict's batch — same math per row.
 SERVE_ATOL = 2e-6
@@ -761,6 +766,7 @@ def phase_sharded(out: Out, cfg, size: Size, work: str):
     import jax
 
     from fast_tffm_tpu.parallel import mesh as mesh_lib
+    from fast_tffm_tpu.train import sparse as sparse_lib
 
     require(cfg.mesh_data == 2 and cfg.mesh_model == 2
             and cfg.lookup == "shardmap", "cfg does not ask for 2x2 shardmap")
@@ -777,32 +783,65 @@ def phase_sharded(out: Out, cfg, size: Size, work: str):
             devices=jax.devices()[:1],
         ),
     )
+    one_compile_s, one_placement = one["compile_s"], one["placement"]
     results, ok = {}, True
-    want_place = {
-        "shard_rows": [cfg.vocabulary_size // 2], "shard_devices": [4],
-        "batch_shard_rows": [cfg.batch_size // 2],
-    }
-    for exchange in ("entries", "dense"):
-        got = _k_steps(
-            dataclasses.replace(base, sparse_exchange=exchange),
-            cfg.train_files, k,
-        )
-        cmp = _compare(got, one, F32_TOL, ACC_TOL)
+    on_chip = jax.default_backend() == "tpu"
+
+    def case(name, cfg_case, ref):
+        nonlocal ok
+        got = _k_steps(cfg_case, cfg.train_files, k)
+        cmp = _compare(got, ref, F32_TOL, ACC_TOL)
         place = got["placement"]
-        results[exchange] = {
+        want_place = {
+            "shard_rows": [cfg_case.vocabulary_size // 2],
+            "shard_devices": [4],
+            "batch_shard_rows": [cfg.batch_size // 2],
+        }
+        results[name] = {
             "vs_one_device": cmp, "placement": place,
+            "want_placement": want_place,
+            # what gauge train.apply_stream reads for this step
+            "apply_stream": sparse_lib.apply_stream(
+                cfg_case, mesh_lib.make_mesh(cfg_case)),
             "compile_s": got["compile_s"], "wall_s": got["wall_s"],
         }
         ok = ok and _passed(cmp) and all(
             place[key] == val for key, val in want_place.items()
         )
+
+    for exchange in ("entries", "dense"):
+        case(exchange, dataclasses.replace(base, sparse_exchange=exchange),
+             one)
+    del one
+    # A shard the row-major K2 cannot hold (the compiler refuses its
+    # whole-shard copies from 2^24 rows, PERF.md section 4): the entries
+    # step must take the merged stream through the stream writer there.
+    # The one-device side holds the same vocabulary by the scatter apply.
+    big = dataclasses.replace(
+        base, sparse_exchange="entries", sparse_apply="scatter",
+        vocabulary_size=(2 * BIG_SHARD_ROWS if on_chip
+                         else 4 * cfg.vocabulary_size),
+    )
+    one_big = _k_steps(
+        dataclasses.replace(big, mesh_data=1, mesh_model=1, lookup="auto"),
+        cfg.train_files, k,
+        mesh=mesh_lib.make_mesh(
+            dataclasses.replace(big, mesh_data=1, mesh_model=1),
+            devices=jax.devices()[:1],
+        ),
+    )
+    case("entries_big_shard", big, one_big)
+    del one_big
+    if on_chip:
+        ok = ok and all(results[name]["apply_stream"]
+                        for name in ("entries", "entries_big_shard"))
     out.emit({
         "phase": "sharded", "mesh": {"data": 2, "model": 2},
         "vocabulary_size": cfg.vocabulary_size, "steps": k,
         "f32_tol": F32_TOL, "acc_tol": ACC_TOL,
-        "one_device_placement": one["placement"],
-        "one_device_compile_s": one["compile_s"], **results,
-        "want_placement": want_place,
+        "one_device_placement": one_placement,
+        "one_device_compile_s": one_compile_s, **results,
+        "big_shard_rows": big.vocabulary_size // 2,
         "memory": [_mem(d) for d in jax.devices()[:4]],
     })
     require(ok, f"sharded step: mismatch or unreal placement: {results}")

@@ -664,6 +664,12 @@ SCATTER_CHUNK = 4096
 _EXACT_PASSES = 3
 
 
+def _k1_exact(payload, upos, starts, firsts, ends, n_out):
+    """K1 in _EXACT_PASSES (``segment_sums``' signature)."""
+    return _k1_dedup(payload, upos, starts, firsts, ends, n_out,
+                     _EXACT_PASSES)
+
+
 def _xla_segment_sums(payload, upos, starts, firsts, ends, n_out):
     """K1's stand-in where Pallas kernels run interpreted (off the
     TPU): the same per-segment sums of the sorted payload by XLA's
@@ -1004,8 +1010,7 @@ def scatter_apply_unique(update, tables, ids, g_rows, *, additive=False,
         rows, pay, count = unique_entries(
             ids, g_rows, vocab=vocab, cap=cap, pad_first=True,
             segment_sums=(
-                _xla_segment_sums if _use_interpret()
-                else functools.partial(_k1_dedup, passes=_EXACT_PASSES)
+                _xla_segment_sums if _use_interpret() else _k1_exact
             ),
         )
     chunk = min(SCATTER_CHUNK, cap)
@@ -1106,14 +1111,94 @@ def k2_apply(update, tile_start, u, tables, compact=None):
                     compact=compact)
 
 
+def merge_entries_stream(rows, pay, *, vocab, group, segment_sums=None):
+    """merge_entries in the stream writer's form: ``(u_tiles,
+    group_start)`` as _stream_call takes them.
+
+    The concatenated, all-gathered per-shard streams (``rows`` [N],
+    ``pay`` [N, 2 D] = sum g | sum g^2 per row and shard) are sorted
+    again and K1 sums the <= S partial contributions per row, as
+    merge_entries does — but the payload carries BOTH placement columns
+    (lrow, tidx: the writer places an entry by them, in a block of
+    TILE * ``group`` rows), K1 runs _EXACT_PASSES so that the tile index
+    comes back whole at any shard size, and the starts are a block's,
+    not a subtile's.  The rows are lane-padded before they are permuted
+    (see _payload_padded_first: N is two or more batches' worth).
+    ``segment_sums`` stands in for K1 (default: three-pass K1 compiled,
+    XLA's sorted segment sum interpreted, as the one-device stream).
+    """
+    n = rows.shape[0]
+    if n % CHUNK:
+        raise ValueError(f"merged stream length {n} not a CHUNK multiple")
+    if segment_sums is None:
+        segment_sums = _xla_segment_sums if _use_interpret() else _k1_exact
+    with jax.named_scope("tffm.exchange_merge"):  # sort, payload, K1
+        sidx, perm = jax.lax.sort_key_val(
+            rows, jnp.arange(n, dtype=jnp.int32))
+        upos, last, starts, firsts, ends = _sorted_stream_meta(sidx)
+        lrow = (sidx % TILE).astype(jnp.float32)
+        tidx = (sidx // TILE).astype(jnp.float32)
+        payload = _padded_then_permuted(
+            [pay], perm, [lrow * last, tidx * last])
+        u = segment_sums(payload, upos, starts, firsts, ends, n + TILE)
+        # whole lane tiles: the slices move nothing (see _k1_tiles)
+        u_tiles = [u[:, j:j + 128] for j in range(0, u.shape[1], 128)]
+        bounds = jnp.arange(0, vocab + 1, TILE * group, dtype=sidx.dtype)
+        group_start = _tile_starts(sidx, upos, bounds, "scan_unrolled")
+    return u_tiles, group_start
+
+
+def merged_stream_apply(update, tables, rows, pay):
+    """Apply ``update`` from the concatenated per-shard entry streams
+    through the stream writer (_stream_call): every table of the shard
+    whole through VMEM once as ``table.T``, no row-major copy.  Returns
+    ``(new_tables, count)``, ``count`` the merged stream's real entries
+    (the rows written)."""
+    vocab, d = tables[0].shape
+    group = _stream_group(vocab // TILE, d, len(tables))
+    u_tiles, group_start = merge_entries_stream(
+        rows, pay, vocab=vocab, group=group)
+    with jax.named_scope("tffm.apply_write"):
+        tables = _stream_call(
+            update, group_start, u_tiles, tuple(tables), False, group)
+    return tables, group_start[-1]
+
+
+def gather_entries(lids, g_rows, *, vocab_local, data_axis, rows_all=None):
+    """First half of the entries exchange (shard_map body): dedupe the
+    LOCAL-coordinate occurrences and all-gather the touched-entry
+    streams over ``data_axis``.  Returns the concatenated ``(rows [S *
+    cap], pay [S * cap, 2 D])``.  K1 runs _EXACT_PASSES: the rows are
+    recovered as ``tidx * TILE + lrow``, and two bf16 passes carry the
+    tile index only while ``vocab_local / TILE <= 2^17`` — a shard of
+    2^25 rows puts its sentinel exactly there.  ``rows_all``: see
+    entries_exchange."""
+    with jax.named_scope("tffm.exchange"):
+        cap = entries_cap(lids.shape[0], vocab_local)
+        # pad_first: the same payload, bit for bit, lane-padded before
+        # it is permuted (a data shard's occurrences are a whole batch's
+        # on one device: _payload_padded_first's side of its reading)
+        rows_e, pay_e, _ = unique_entries(
+            lids, g_rows, vocab=vocab_local, cap=cap, pad_first=True,
+            segment_sums=_k1_exact,
+        )
+        if rows_all is None:
+            rows_all = jax.lax.all_gather(
+                rows_e, data_axis, axis=0, tiled=True
+            )
+        pay_all = jax.lax.all_gather(pay_e, data_axis, axis=0, tiled=True)
+    return rows_all, pay_all
+
+
 def entries_exchange(lids, g_rows, *, vocab_local, data_axis,
                      data_shards, rows_all=None):
     """The ONE copy of the entries-exchange protocol (shard_map body):
     dedupe LOCAL-coordinate occurrences (off-shard ids pre-mapped to the
     sentinel ``vocab_local``, their payloads zeroed), all-gather the
-    touched-entry streams over ``data_axis``, merge.  Returns the
-    K2-ready ``(u, tile_start)``.  Both the shardmap step and the GSPMD
-    sharded apply call this — keep it the only copy.
+    touched-entry streams over ``data_axis`` (gather_entries), merge.
+    Returns the K2-ready ``(u, tile_start)``; entries_exchange_apply is
+    the exchange with its apply, and what both the shardmap step and the
+    GSPMD sharded apply call — keep it the only copy.
 
     ``data_shards`` (static) short-circuits the degenerate pure
     model-parallel case: with one data shard there is nothing to
@@ -1132,16 +1217,44 @@ def entries_exchange(lids, g_rows, *, vocab_local, data_axis,
     """
     if data_shards == 1:
         return _dedup_and_starts(lids, g_rows, vocab_local)
-    cap = entries_cap(lids.shape[0], vocab_local)
-    rows_e, pay_e, _ = unique_entries(
-        lids, g_rows, vocab=vocab_local, cap=cap
+    rows_all, pay_all = gather_entries(
+        lids, g_rows, vocab_local=vocab_local, data_axis=data_axis,
+        rows_all=rows_all,
     )
-    if rows_all is None:
-        rows_all = jax.lax.all_gather(
-            rows_e, data_axis, axis=0, tiled=True
-        )
-    pay_all = jax.lax.all_gather(pay_e, data_axis, axis=0, tiled=True)
     return merge_entries(rows_all, pay_all, vocab=vocab_local)
+
+
+def exchange_takes_stream(data_shards: int) -> bool:
+    """Which apply entries_exchange_apply ends in: the merged stream goes
+    through the stream writer wherever kernels run compiled (interpreted,
+    the writer is a correctness tool and the row-major K2 stays) and the
+    data axis has more than one shard (one keeps its short cut into K2).
+    Both callers hand over shards of whole subtiles
+    (supports_tile_sharded), which is all the writer asks."""
+    return data_shards > 1 and not _use_interpret()
+
+
+def entries_exchange_apply(update, tables, lids, g_rows, *, vocab_local,
+                           data_axis, data_shards, rows_all=None):
+    """The entries exchange and the apply on top of it (shard_map body):
+    ``update(g1, g2, *table_rows) -> new_table_rows`` applied to the
+    shard's ``tables`` from every data shard's occurrences of its rows.
+    Returns ``(new_tables, count)``, ``count`` the merged stream's real
+    entries.  Where exchange_takes_stream says no, the row-major K2
+    (whole-table copies into its layout: the compiler refuses them from
+    2^24 rows a shard, PERF.md §4)."""
+    tables = tuple(tables)
+    if not exchange_takes_stream(data_shards):
+        u, tile_start = entries_exchange(
+            lids, g_rows, vocab_local=vocab_local, data_axis=data_axis,
+            data_shards=data_shards, rows_all=rows_all,
+        )
+        return tuple(k2_apply(update, tile_start, u, tables)), tile_start[-1]
+    rows_all, pay_all = gather_entries(
+        lids, g_rows, vocab_local=vocab_local, data_axis=data_axis,
+        rows_all=rows_all,
+    )
+    return merged_stream_apply(update, tables, rows_all, pay_all)
 
 
 def make_entries_prefetch(mesh, data_axis, model_axis, vocab):
@@ -1175,7 +1288,8 @@ def make_entries_prefetch(mesh, data_axis, model_axis, vocab):
         cap = entries_cap(lids.shape[0], vocab_local)
         zeros = jnp.zeros((lids.shape[0], 1), jnp.float32)
         rows_e, _, _ = unique_entries(
-            lids, zeros, vocab=vocab_local, cap=cap
+            lids, zeros, vocab=vocab_local, cap=cap,
+            segment_sums=_k1_exact,
         )
         return jax.lax.all_gather(rows_e, data_axis, axis=0, tiled=True)
 
@@ -1294,15 +1408,23 @@ def _payload_padded_first(g_rows, perm, lrow_last, tidx_last):
     against 85.4 ms for the whole prep.  Not so at the tile path's
     n = 640k, where this order read 1.5 ms slower (53.7 against 52.3 ms
     an apply), so that path keeps _payload (PERF.md §6, PR 27)."""
-    n, d = g_rows.shape
-    meta = [lrow_last, tidx_last]
+    return _padded_then_permuted(
+        [g_rows, g_rows * g_rows], perm, [lrow_last, tidx_last])
+
+
+def _padded_then_permuted(cols, perm, meta):
+    """``[cols...[perm] | meta...]`` lane-padded, the padding done
+    before the permutation (see _payload_padded_first): ``cols`` the
+    unsorted column groups, ``meta`` the already sorted metadata columns
+    that follow them."""
+    n = cols[0].shape[0]
+    width = sum(c.shape[1] for c in cols)
     p = _pad_lanes(jnp.concatenate(
-        [g_rows, g_rows * g_rows, jnp.zeros((n, len(meta)), g_rows.dtype)],
-        axis=1,
+        cols + [jnp.zeros((n, len(meta)), cols[0].dtype)], axis=1,
     ))[perm]
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, p.shape[1]), 1)
     for k, col in enumerate(meta):
-        p = jnp.where(lane == 2 * d + k, col[:, None], p)
+        p = jnp.where(lane == width + k, col[:, None], p)
     return p
 
 
@@ -1481,18 +1603,17 @@ def _sharded_call(update_fn, mesh, data_axis, model_axis, tables, ids,
                 in_range, ids_l - row_lo, vocab_local
             ).astype(jnp.int32)
             g_masked = jnp.where(in_range[:, None], g_l, 0.0)
-            u2, ts2 = entries_exchange(
-                lids, g_masked, vocab_local=vocab_local,
-                data_axis=data_axis, data_shards=mesh.shape[data_axis],
-                rows_all=rows_in,
-            )
-            # k2_apply expects update -> tuple; the single-table (sgd)
+            # the apply expects update -> tuple; the single-table (sgd)
             # wrapper returns a bare array.
             upd = (
                 update_fn if n_tables > 1
                 else (lambda g1, g2, *t: (update_fn(g1, g2, *t),))
             )
-            out = k2_apply(upd, ts2, u2, tuple(tables_l))
+            out, _ = entries_exchange_apply(
+                upd, tables_l, lids, g_masked, vocab_local=vocab_local,
+                data_axis=data_axis, data_shards=mesh.shape[data_axis],
+                rows_all=rows_in,
+            )
             return tuple(out) if n_tables > 1 else out[0]
         dense = dense_delta(
             ids_l, g_l, vocab=vocab,

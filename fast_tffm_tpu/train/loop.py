@@ -105,8 +105,10 @@ class HealthState(NamedTuple):
     # 2^24 events per step-increment, which is plenty for a monitor.
     touch_events: jax.Array  # f32: cumulative real feature occurrences
     # uint32[2], cumulative and wrapping: rows the deduped scatter apply
-    # wrote, and the occurrences it merged them from (zeros on every
-    # other apply path).  The host reads the difference of two
+    # wrote, and the occurrences it merged them from; under the
+    # hand-sharded entries exchange the merged stream's real entries and
+    # its all-gathered capacity (zeros on every other apply path).  The
+    # host reads the difference of two
     # dispatches, which the wrap leaves exact.
     apply_rows: jax.Array
     rows_touched: jax.Array  # bool[vocab]: rows ever touched this run
@@ -1771,6 +1773,17 @@ class Trainer:
         # another apply mode altogether)
         self.telemetry.gauge("train.apply_stream").set(int(
             self.sparse and sparse_lib.apply_stream(self._dcfg, self.mesh)))
+        # the exchange over the data axis the compiled step holds:
+        # 1 = entries (touched rows all-gathered), 0 = dense (a whole
+        # shard's delta psum'd); absent where the step exchanges nothing
+        exchange = (sparse_lib.exchange_mode(self._dcfg, self.mesh)
+                    if self.sparse else None)
+        if exchange is not None:
+            self.telemetry.gauge("train.exchange_mode").set(
+                int(exchange == "entries"))
+        # what the health carry's apply_rows pair is the ratio of: the
+        # hand-sharded entries step reports its merged stream's fill
+        exchange_fills = exchange == "entries" and cfg.lookup == "shardmap"
         self.tracer.reset()
         # Fresh health carry + host cache per run; the nan_policy check
         # below reads the PREVIOUS dispatch's scalars (async-copied right
@@ -1845,10 +1858,14 @@ class Trainer:
             # this dispatch's own share (uint32 differences wrap back)
             written, merged = (int(x) for x in ap - apply_rows_seen)
             apply_rows_seen = ap
-            if merged:  # the deduped scatter apply ran (train.sparse)
-                self.telemetry.gauge("train.apply_unique_frac").set(
-                    round(written / merged, 6)
-                )
+            if merged:  # the deduped scatter apply ran (train.sparse),
+                # or the entries exchange: real merged entries over the
+                # all-gathered capacity (train.shardmap_step)
+                frac = round(written / merged, 6)
+                if exchange_fills:
+                    self.telemetry.gauge("train.exchange_fill").set(frac)
+                else:
+                    self.telemetry.gauge("train.apply_unique_frac").set(frac)
             self._health_host["grad_norm"] = round(
                 float(np.sqrt(gs)) if np.isfinite(gs) else gs, 6
             )

@@ -50,6 +50,7 @@ from fast_tffm_tpu.train.sparse import (
     ADAGRAD_EPS,
     SparseAdagradState,
     SparseFtrlState,
+    resolve_exchange,
 )
 
 
@@ -61,27 +62,6 @@ def supports_shardmap(cfg: FmConfig, mesh) -> bool:
     model_shards = mesh.shape[MODEL_AXIS]
     return sparse_apply.supports_tile_sharded(
         cfg.vocabulary_size, cfg.optimizer, model_shards
-    )
-
-
-def exchange_mode(cfg: FmConfig, mesh, n_local_occ: int) -> str:
-    """Resolve cfg.sparse_exchange for these static shapes.
-
-    "dense" psums a [vocab_local, 2D] delta over the data axis — bytes
-    grow with vocab, independent of the batch.  "entries" all-gathers
-    the deduped touched-row streams — bytes grow with the batch,
-    independent of vocab (the reference PS design's IndexedSlices
-    scaling, SURVEY.md §3.2).  "auto" picks whichever moves fewer ring
-    words per device, weighing the dense all-reduce at 2x its buffer
-    (reduce-scatter + all-gather phases — see
-    sparse_apply.resolve_exchange).
-    """
-    return sparse_apply.resolve_exchange(
-        cfg.sparse_exchange,
-        n_local_occ=n_local_occ,
-        vocab_local=cfg.vocabulary_size // mesh.shape[MODEL_AXIS],
-        d=cfg.embedding_dim,
-        data_shards=mesh.shape[DATA_AXIS],
     )
 
 
@@ -127,13 +107,17 @@ def sparse_step_shardmap(cfg: FmConfig, params, opt_state, batch: Batch,
     plus a ``(grad_sq, nonfinite_count)`` health aux when ``health=True``
     — each quantity reduced locally from the shard's own (masked)
     occurrence grads and psum'd over BOTH mesh axes, so the monitor is
-    global at the cost of two extra scalar collectives per step."""
+    global at the cost of two extra scalar collectives per step.  Under
+    the entries exchange the aux ends in ``[merged real entries,
+    capacity]`` summed over the model shards (gauge
+    ``train.exchange_fill``)."""
     model_shards = mesh.shape[MODEL_AXIS]
+    data_shards = mesh.shape[DATA_AXIS]
     vocab_local = cfg.vocabulary_size // model_shards
     k = cfg.factor_num
     n_opt = len(_opt_tables(cfg, opt_state))
-    b_local = batch.vals.shape[0] // mesh.shape[DATA_AXIS]
-    exchange = exchange_mode(cfg, mesh, b_local * batch.vals.shape[1])
+    b_local = batch.vals.shape[0] // data_shards
+    exchange = resolve_exchange(cfg, mesh, b_local * batch.vals.shape[1])
 
     cd = cfg.compute_jnp_dtype
 
@@ -258,17 +242,14 @@ def sparse_step_shardmap(cfg: FmConfig, params, opt_state, batch: Batch,
         if exchange == "entries":
             # Batch-proportional update exchange: dedupe locally, move
             # only the touched entries over the data axis, merge the S
-            # sorted streams, apply via K2.  Comms are independent of
-            # vocab — the reference's IndexedSlices scaling property.
-            # (ids_flat is already local-coordinate with off-shard ->
-            # sentinel, the helper's contract; drows already masked.)
-            u2, ts2 = sparse_apply.entries_exchange(
-                ids_flat.astype(jnp.int32), g_flat,
-                vocab_local=vocab_local, data_axis=DATA_AXIS,
-                data_shards=mesh.shape[DATA_AXIS],
-            )
-            w_new, new_tables = _apply_stream(
-                cfg, ts2, u2, table_l, opt_tables_l
+            # sorted streams, apply through the stream writer (or K2).
+            # Comms are independent of vocab — the reference's
+            # IndexedSlices scaling property.  (ids_flat is already
+            # local-coordinate with off-shard -> sentinel, the helper's
+            # contract; drows already masked.)
+            w_new, new_tables, merged = _apply_stream(
+                cfg, ids_flat.astype(jnp.int32), g_flat, table_l,
+                opt_tables_l, data_shards=data_shards,
             )
         else:
             delta = sparse_apply.dense_delta(
@@ -300,12 +281,17 @@ def sparse_step_shardmap(cfg: FmConfig, params, opt_state, batch: Batch,
                 (MODEL_AXIS, DATA_AXIS),
             )
             outs = outs + (gsq, nonfin)
+            if exchange == "entries":
+                # every data replica of a model shard holds the same
+                # merged stream: count a shard's once
+                outs = outs + (jax.lax.psum(merged, MODEL_AXIS),)
         return outs
 
     out_specs = (
         (P(MODEL_AXIS, None), P(DATA_AXIS), P())
         + (P(MODEL_AXIS, None),) * n_opt
         + ((P(), P()) if health else ())
+        + ((P(),) if health and exchange == "entries" else ())
     )
     from jax import shard_map
 
@@ -324,16 +310,23 @@ def sparse_step_shardmap(cfg: FmConfig, params, opt_state, batch: Batch,
         batch.fields, batch.weights, *_opt_tables(cfg, opt_state),
     )
     table_new, scores, dw0 = outs[0], outs[1], outs[2]
-    new_opt_tables = outs[3:-2] if health else outs[3:]
+    new_opt_tables = outs[3:3 + n_opt]
     w0_new, opt_new = _rebuild_opt(
         cfg, opt_state, new_opt_tables, dw0, params.w0
     )
     new_params = fm.FmParams(w0=w0_new, table=table_new)
     if health:
-        gsq, nonfin = outs[-2], outs[-1]
+        gsq, nonfin = outs[3 + n_opt], outs[4 + n_opt]
         grad_sq = gsq + jnp.square(dw0)
         nonfin = nonfin + (~jnp.isfinite(dw0)).astype(jnp.int32)
-        return new_params, opt_new, scores, (grad_sq, nonfin)
+        aux = (grad_sq, nonfin)
+        if exchange == "entries":
+            cap = sparse_apply.entries_cap(
+                b_local * batch.vals.shape[1], vocab_local)
+            aux += (jnp.stack(
+                [outs[5 + n_opt], model_shards * data_shards * cap]
+            ).astype(jnp.uint32),)
+        return new_params, opt_new, scores, aux
     return new_params, opt_new, scores
 
 
@@ -370,33 +363,30 @@ def make_exchange_probe(mesh):
     return probe
 
 
-def _apply_stream(cfg, tile_start, u, w_l, opt_tables_l):
-    """Optimizer update from a merged K2 entry stream (entries exchange).
-
-    Same formulas as _apply_delta, fused in the K2 tile kernel — only
-    streamed/touched tiles are rewritten, so untouched rows pass through
-    by aliasing (bit-identical to the dense path's identity update)."""
+def _apply_stream(cfg, lids, g_flat, w_l, opt_tables_l, *, data_shards):
+    """The entries exchange and the optimizer update on top of it
+    (sparse_apply.entries_exchange_apply): same formulas as
+    _apply_delta, run inside the stream writer (or the K2 tile kernel)
+    over the shard's own tables — rows no data shard touched keep their
+    bits.  Returns ``(table, opt tables, merged real entries)``."""
     lr = cfg.learning_rate
     if cfg.optimizer == "adagrad":
         upd = functools.partial(
             sparse_apply.adagrad_update, lr=lr, eps=ADAGRAD_EPS
         )
-        w_new, acc_new = sparse_apply.k2_apply(
-            upd, tile_start, u, (w_l, opt_tables_l[0])
-        )
-        return w_new, (acc_new,)
-    if cfg.optimizer == "ftrl":
+    elif cfg.optimizer == "ftrl":
         upd = functools.partial(
             sparse_apply.ftrl_update,
             lr=lr, l1=cfg.ftrl_l1, l2=cfg.ftrl_l2, beta=cfg.ftrl_beta,
         )
-        w_new, z_new, n_new = sparse_apply.k2_apply(
-            upd, tile_start, u, (w_l,) + tuple(opt_tables_l)
-        )
-        return w_new, (z_new, n_new)
-    upd = functools.partial(sparse_apply.sgd_update, lr=lr)
-    (w_new,) = sparse_apply.k2_apply(upd, tile_start, u, (w_l,))
-    return w_new, ()
+    else:
+        upd = functools.partial(sparse_apply.sgd_update, lr=lr)
+    (w_new, *opt_new), merged = sparse_apply.entries_exchange_apply(
+        upd, (w_l,) + tuple(opt_tables_l), lids, g_flat,
+        vocab_local=w_l.shape[0], data_axis=DATA_AXIS,
+        data_shards=data_shards,
+    )
+    return w_new, tuple(opt_new), merged
 
 
 def _apply_delta(cfg, g1, g2, w_l, opt_tables_l):

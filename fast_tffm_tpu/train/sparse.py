@@ -89,13 +89,62 @@ def apply_mode(cfg: FmConfig, mesh=None) -> str:
     return "scatter"
 
 
+def resolve_exchange(cfg: FmConfig, mesh, n_local_occ=None) -> str:
+    """``cfg.sparse_exchange`` resolved for ``mesh``: the one rule that
+    the hand-sharded step, the GSPMD 'sharded' apply, the overlap and
+    the gauges all read.
+
+    "dense" psums a [vocab_local, 2D] delta over the data axis — bytes
+    grow with vocab, independent of the batch.  "entries" all-gathers
+    the deduped touched-row streams — bytes grow with the batch,
+    independent of vocab (the reference PS design's IndexedSlices
+    scaling, SURVEY.md §3.2).  "auto" picks whichever moves fewer ring
+    words per device, weighing the dense all-reduce at 2x its buffer
+    (reduce-scatter + all-gather phases — see
+    sparse_apply.resolve_exchange).
+
+    ``n_local_occ``: a data shard's occurrences a step; a step being
+    traced passes its batch's own, everything else leaves it to the
+    cfg's batch (the same number wherever the batch is the cfg's)."""
+    data_shards = mesh.shape[mesh_lib.DATA_AXIS]
+    if n_local_occ is None:
+        n_local_occ = cfg.batch_size * cfg.max_features // data_shards
+    return sparse_apply.resolve_exchange(
+        cfg.sparse_exchange,
+        n_local_occ=n_local_occ,
+        vocab_local=cfg.vocabulary_size // mesh.shape[mesh_lib.MODEL_AXIS],
+        d=cfg.embedding_dim,
+        data_shards=data_shards,
+    )
+
+
+def exchange_mode(cfg: FmConfig, mesh=None):
+    """The exchange over the data axis that the step compiled for
+    ``cfg`` on ``mesh`` holds (gauge ``train.exchange_mode``):
+    resolve_exchange's "entries" or "dense" under the hand-sharded step
+    (``lookup=shardmap``) and the GSPMD 'sharded' apply, None where the
+    step exchanges nothing (one device, the per-occurrence GSPMD
+    scatter, a dense optimizer)."""
+    if mesh is None or mesh.size == 1 or not supports_sparse(cfg):
+        return None
+    if cfg.lookup != "shardmap" and apply_mode(cfg, mesh) != "sharded":
+        return None
+    return resolve_exchange(cfg, mesh)
+
+
 def apply_stream(cfg: FmConfig, mesh=None) -> bool:
     """Whether the step compiled for ``cfg`` on ``mesh`` writes its
     touched rows with the transposed tile stream (gauge
     ``train.apply_stream``): the one-device scatter apply, where
-    ops.sparse_apply's rule takes the stream for the step's shapes."""
-    if (mesh is not None and mesh.size > 1) or not supports_sparse(cfg):
+    ops.sparse_apply's rule takes the stream for the step's shapes; on
+    a mesh, the entries exchange wherever its merged stream goes through
+    the stream writer (sparse_apply.exchange_takes_stream)."""
+    if not supports_sparse(cfg):
         return False
+    if mesh is not None and mesh.size > 1:
+        return exchange_mode(cfg, mesh) == "entries" and (
+            sparse_apply.exchange_takes_stream(
+                mesh.shape[mesh_lib.DATA_AXIS]))
     if apply_mode(cfg, mesh) != "scatter":
         return False
     return sparse_apply.takes_stream(
@@ -190,17 +239,6 @@ def _rows_loss_fn(
     return loss_fn
 
 
-def _sharded_exchange(cfg, mesh, ids, g_rows) -> str:
-    """Resolve cfg.sparse_exchange for the GSPMD 'sharded' apply mode."""
-    return sparse_apply.resolve_exchange(
-        cfg.sparse_exchange,
-        n_local_occ=ids.shape[0] // mesh.shape[mesh_lib.DATA_AXIS],
-        vocab_local=cfg.vocabulary_size // mesh.shape[mesh_lib.MODEL_AXIS],
-        d=g_rows.shape[1],
-        data_shards=mesh.shape[mesh_lib.DATA_AXIS],
-    )
-
-
 def overlap_active(cfg: FmConfig, mesh=None) -> bool:
     """Resolve ``cfg.sparse_exchange_overlap`` against the path actually
     taken: compute-overlapped exchange needs the entries exchange's id
@@ -219,17 +257,7 @@ def overlap_active(cfg: FmConfig, mesh=None) -> bool:
     if ok:
         ok = supports_sparse(cfg) and apply_mode(cfg, mesh) == "sharded"
     if ok:
-        n_occ = cfg.batch_size * cfg.max_features
-        resolved = sparse_apply.resolve_exchange(
-            cfg.sparse_exchange,
-            n_local_occ=n_occ // mesh.shape[mesh_lib.DATA_AXIS],
-            vocab_local=(
-                cfg.vocabulary_size // mesh.shape[mesh_lib.MODEL_AXIS]
-            ),
-            d=cfg.embedding_dim,
-            data_shards=mesh.shape[mesh_lib.DATA_AXIS],
-        )
-        ok = resolved == "entries"
+        ok = resolve_exchange(cfg, mesh) == "entries"
     if cfg.sparse_exchange_overlap == "on" and not ok:
         raise ValueError(
             "sparse_exchange_overlap=on requires the sharded sparse apply "
@@ -253,7 +281,8 @@ def _apply_adagrad(cfg, params, opt, ids, g_rows, dw0, w_rows,
             params.table, opt.acc.table, ids, g_rows,
             lr=lr, eps=ADAGRAD_EPS, mesh=mesh,
             data_axis=mesh_lib.DATA_AXIS, model_axis=mesh_lib.MODEL_AXIS,
-            exchange=_sharded_exchange(cfg, mesh, ids, g_rows),
+            exchange=resolve_exchange(
+                cfg, mesh, ids.shape[0] // mesh.shape[mesh_lib.DATA_AXIS]),
             rows_all=rows_all,
         )
     elif mode == "tile":
@@ -296,7 +325,8 @@ def _apply_ftrl(cfg, params, opt, ids, g_rows, dw0, w_rows,
             params.table, opt.z.table, opt.n.table, ids, g_rows,
             lr=lr, l1=l1, l2=l2, beta=beta, mesh=mesh,
             data_axis=mesh_lib.DATA_AXIS, model_axis=mesh_lib.MODEL_AXIS,
-            exchange=_sharded_exchange(cfg, mesh, ids, g_rows),
+            exchange=resolve_exchange(
+                cfg, mesh, ids.shape[0] // mesh.shape[mesh_lib.DATA_AXIS]),
             rows_all=rows_all,
         )
     elif mode == "tile":
@@ -361,7 +391,8 @@ def _apply_sgd(cfg, params, opt, ids, g_rows, dw0, w_rows,
         table = sparse_apply.sgd_apply_sharded(
             params.table, ids, g_rows, lr=lr, mesh=mesh,
             data_axis=mesh_lib.DATA_AXIS, model_axis=mesh_lib.MODEL_AXIS,
-            exchange=_sharded_exchange(cfg, mesh, ids, g_rows),
+            exchange=resolve_exchange(
+                cfg, mesh, ids.shape[0] // mesh.shape[mesh_lib.DATA_AXIS]),
             rows_all=rows_all,
         )
     elif mode == "tile":

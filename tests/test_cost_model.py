@@ -119,12 +119,12 @@ def test_auto_exchange_picks_by_bytes():
         batch_size=64, lookup="shardmap",
     )
     n_occ = 64 // 2 * 8  # per-device occurrences on the (2, 4) mesh
-    assert shardmap_step.exchange_mode(small, mesh, n_occ) == "dense"
-    assert shardmap_step.exchange_mode(big, mesh, n_occ) == "entries"
+    assert sparse_lib.resolve_exchange(small, mesh, n_occ) == "dense"
+    assert sparse_lib.resolve_exchange(big, mesh, n_occ) == "entries"
     forced = FmConfig(**{**small.__dict__, "sparse_exchange": "entries",
                          "train_files": [], "weight_files": [],
                          "validation_files": [], "predict_files": []})
-    assert shardmap_step.exchange_mode(forced, mesh, n_occ) == "entries"
+    assert sparse_lib.resolve_exchange(forced, mesh, n_occ) == "entries"
 
 
 def test_auto_exchange_allreduce_weighting():
