@@ -14,7 +14,9 @@ rotation for multi-hour runs; ``obs.StatusServer`` serves ``/metrics``
 process (status.py); ``obs.AlertEngine`` evaluates declarative alert
 rules against the heartbeat stream (alerts.py); ``obs.CompileSentinel``
 / ``obs.read_rss`` are the resource plane (resource.py) — component
-memory ledgers, process RSS, and train-step compile accounting.  See
+memory ledgers, process RSS, and train-step compile accounting, whose
+compile record carries the compiled step's instruction -> scope table
+(scopes.py).  See
 telemetry.py for the shared design constraints (thread-safety,
 near-zero hot-path overhead, no jax or numpy imports).
 """

@@ -25,7 +25,8 @@ module answers the two questions they are blind to:
    trainer's AOT cache performs: wall time (``train.compile`` timer —
    its count IS the compile count), XLA ``cost_analysis()`` /
    ``memory_analysis()`` captured at compile time (FLOPs, bytes
-   accessed, output/temp bytes), and — the alerting signal — a
+   accessed, output/temp bytes), the compiled step's instruction ->
+   scope table (``scopes.py``), and — the alerting signal — a
    ``train.recompiles_unexpected`` counter.  The documented epoch-tail
    K'=leftover compile is whitelisted (provisionally at compile time;
    the trainer confirms an epoch boundary actually follows and
@@ -50,6 +51,8 @@ import logging
 import threading
 import time
 from typing import Optional
+
+from fast_tffm_tpu.obs import scopes
 
 __all__ = ["CompileSentinel", "read_rss", "read_open_fds",
            "basic_block"]
@@ -198,8 +201,13 @@ class CompileSentinel:
             # describes what run 2 dispatches; keep it.
 
     def record(self, wall_s: float, k: int, expected: bool,
-               cost: Optional[dict] = None, step: int = 0) -> None:
-        """Account one actual compile (cache misses only)."""
+               cost: Optional[dict] = None, step: int = 0,
+               compiled=None) -> None:
+        """Account one actual compile (cache misses only).  Where the
+        run writes records, ``compiled`` (the executable) gives the
+        ``record: compile`` entry its instruction -> scope table
+        (``obs.scopes.record_of``: ``scopes``, ``moved``, ``mixed``,
+        ``containers`` and ``scope_table_s``, or ``scope_table_error``)."""
         self._tel.timer("train.compile").observe(wall_s)
         cost = cost or {}
         with self._lock:
@@ -231,6 +239,8 @@ class CompileSentinel:
                 "expected": bool(expected),
             }
             rec.update(cost)
+            if compiled is not None:
+                rec.update(scopes.record_of(compiled))
             try:
                 writer.write(rec)
             except Exception as e:  # noqa: BLE001 - never kill a compile

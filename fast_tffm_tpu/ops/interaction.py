@@ -156,11 +156,13 @@ def _forward(rows, vals, impl):
     return _scores_jnp(rows, vals)
 
 
+@jax.named_scope("tffm.fm_interaction_fwd")
 def _fwd(rows, vals, impl):
     scores, s1 = _forward(rows, vals, impl)
     return scores, (rows, vals, s1)
 
 
+@jax.named_scope("tffm.fm_interaction_bwd")
 def _bwd(impl, res, g):
     rows, vals, s1 = res
     impl = _impl_name(impl)

@@ -1342,7 +1342,8 @@ def entries_exchange_apply(update, tables, lids, g_rows, *, vocab_local,
     rung = entries_rung(cap, data_shards)
     if rung is None:
         return merge_and_write(tables, *gather(cap))
-    fits = jax.lax.pmax(count, (data_axis, model_axis)) <= rung
+    with jax.named_scope("tffm.exchange"):
+        fits = jax.lax.pmax(count, (data_axis, model_axis)) <= rung
     return jax.lax.cond(
         fits,
         lambda tables, compact: merge_and_write(tables, *compact),

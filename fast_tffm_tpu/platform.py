@@ -147,7 +147,7 @@ def enable_compile_cache(path: str = "") -> bool:
     """Turn on jax's persistent compilation cache at
     ``compile_cache_dir(path)`` (created if missing) and start counting
     cache hit/miss events.  Idempotent; no directory from either source
-    is a no-op (returns False).  The min-size/min-time floors are
+    turns nothing on (returns False).  The min-size/min-time floors are
     dropped so EVERY executable persists — this project's rung/step
     compiles are small but warmup-critical."""
     global _compile_cache_dir, _compile_cache_listener_installed
@@ -183,6 +183,31 @@ def enable_compile_cache(path: str = "") -> bool:
         jax.monitoring.register_event_listener(_listener)
         _compile_cache_listener_installed = True
     return True
+
+
+@contextlib.contextmanager
+def metadata_in_cache_key():
+    """Compile inside this block with the program's metadata (names,
+    ``jax.named_scope`` parts, source places) in the persistent cache's
+    key.  jax's key drops the metadata by default, and an executable
+    loaded from the cache carries the metadata of the program that
+    filled the entry: two train steps that differ only in their scopes
+    would share an entry, and the compile record's scope table
+    (``obs/scopes.py``), read from the executable, would name the other
+    program's parts.  Only around the compile whose metadata is read:
+    the source places in a key depend on the caller's stack, so keyed
+    this way every other program would miss the cache wherever it is
+    called from somewhere new.  Whoever turned the cache on (this module
+    or a harness around the program), and a no-op without one."""
+    import jax
+
+    prev = jax.config.jax_compilation_cache_include_metadata_in_key
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    try:
+        yield
+    finally:
+        jax.config.update(
+            "jax_compilation_cache_include_metadata_in_key", prev)
 
 
 def disable_compile_cache() -> None:

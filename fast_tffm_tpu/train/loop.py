@@ -52,7 +52,6 @@ from fast_tffm_tpu.train.optimizers import make_optimizer
 
 log = logging.getLogger(__name__)
 
-
 class MetricState(NamedTuple):
     loss_sum: jax.Array  # weighted sum of per-example data losses
     weight_sum: jax.Array
@@ -126,6 +125,7 @@ class HealthState(NamedTuple):
         )
 
 
+@jax.named_scope("tffm.loss")
 def _metric_update(
     ms: MetricState, scores, labels, weights, loss_type: str
 ) -> MetricState:
@@ -249,6 +249,7 @@ def make_health_update(cfg: FmConfig):
     to index ``vocab`` and drop out of the scatter."""
     vocab = cfg.vocabulary_size
 
+    @jax.named_scope("tffm.health")
     def update(health: HealthState, new_state: TrainState, batch: Batch,
                aux) -> HealthState:
         grad_sq, nonfinite, *applied = aux
@@ -1162,8 +1163,12 @@ class Trainer:
         k = int(batches.labels.shape[0])
         rest = tuple(x.shape[1:] for x in leaves)
         t0 = time.perf_counter()
+        # The sentinel reads the scope table from this executable's own
+        # text: a persistent-cache entry of a step with other scopes
+        # must not stand in for it.
         with self.tracer.span("train.compile", args={"k": k}), \
-                obs.trace_span("tffm:compile"):
+                obs.trace_span("tffm:compile"), \
+                platform.metadata_in_cache_key():
             fn = self._scan_health_jit.lower(
                 state, self._health, batches
             ).compile()
@@ -1184,7 +1189,7 @@ class Trainer:
                 self._tail_probation = (k, self._run_steps)
         self._sentinel.record(
             wall, k, expected, cost=self._cost_of(fn),
-            step=self._run_steps,
+            step=self._run_steps, compiled=fn,
         )
         self._compile_cache[key] = fn
         return fn
@@ -1915,6 +1920,7 @@ class Trainer:
         # heartbeat derives ingest_wait_frac = wait / wall from these.
         t_wait = self.telemetry.timer("train.wait_input")
         t_disp = self.telemetry.timer("train.dispatch")
+        t_log = self.telemetry.timer("train.log_readback")
         # Tiered-table migration time (eviction gather enqueue + load
         # apply): part of "other" in the wall split — the H2D of the
         # cold rows themselves already overlapped in the prefetcher.
@@ -2426,53 +2432,58 @@ class Trainer:
                         and stepno - last_log_step >= cfg.log_steps
                     ):
                         last_log_step = stepno
-                        # Examples come from the on-device weight sum —
-                        # the GLOBAL count in multi-host runs (each host
-                        # only sees its local shard).
-                        m = _finalize_metrics(
-                            self.state.metrics, cfg.loss_type
-                        )
-                        now = time.time()
-                        rate = (m["examples"] - last_log_ex) / max(
-                            now - last_log_t, 1e-9
-                        )
-                        last_log_t, last_log_ex = now, m["examples"]
-                        # The log readback already synced the host;
-                        # piggyback the exact health refresh (row
-                        # occupancy included) so heartbeats between
-                        # logs serve fresh cached values.
-                        self._health_summary(exact=True)
-                        log.info(
-                            "step %d examples %d loss %.6f auc %.4f "
-                            "ex/s %.0f",
-                            stepno, int(m["examples"]), m["loss"],
-                            m["auc"], rate,
-                        )
-                        # Surface parser truncation (reference FmParser
-                        # warned; silently vanishing features hide data
-                        # bugs like a too-small max_features).  The
-                        # counter spans the whole run now — it folds in
-                        # process-worker drops and cached-epoch replays.
-                        cur_trunc = pipeline.truncated_features
-                        if cur_trunc > trunc_logged:
-                            log.warning(
-                                "%d feature occurrences dropped by "
-                                "max_features=%d since last report "
-                                "(total %d)",
-                                cur_trunc - trunc_logged,
-                                cfg.max_features, cur_trunc,
+                        # The read-back of the metrics and the exact
+                        # health waits for the device: one phase, so
+                        # that a trace names the gap it leaves.
+                        with obs.Phase(t_log,
+                                       "tffm:train.log_readback"):
+                            # Examples come from the on-device weight sum —
+                            # the GLOBAL count in multi-host runs (each host
+                            # only sees its local shard).
+                            m = _finalize_metrics(
+                                self.state.metrics, cfg.loss_type
                             )
-                            trunc_logged = cur_trunc
-                        if metrics_out is not None:
-                            metrics_out.write({
-                                "record": "train",
-                                "step": stepno,
-                                "examples": m["examples"],
-                                "loss": m["loss"],
-                                "auc": m["auc"],
-                                "examples_per_sec": rate,
-                                "elapsed": now - t0,
-                            })
+                            now = time.time()
+                            rate = (m["examples"] - last_log_ex) / max(
+                                now - last_log_t, 1e-9
+                            )
+                            last_log_t, last_log_ex = now, m["examples"]
+                            # The log readback already synced the host;
+                            # piggyback the exact health refresh (row
+                            # occupancy included) so heartbeats between
+                            # logs serve fresh cached values.
+                            self._health_summary(exact=True)
+                            log.info(
+                                "step %d examples %d loss %.6f auc %.4f "
+                                "ex/s %.0f",
+                                stepno, int(m["examples"]), m["loss"],
+                                m["auc"], rate,
+                            )
+                            # Surface parser truncation (reference FmParser
+                            # warned; silently vanishing features hide data
+                            # bugs like a too-small max_features).  The
+                            # counter spans the whole run now — it folds in
+                            # process-worker drops and cached-epoch replays.
+                            cur_trunc = pipeline.truncated_features
+                            if cur_trunc > trunc_logged:
+                                log.warning(
+                                    "%d feature occurrences dropped by "
+                                    "max_features=%d since last report "
+                                    "(total %d)",
+                                    cur_trunc - trunc_logged,
+                                    cfg.max_features, cur_trunc,
+                                )
+                                trunc_logged = cur_trunc
+                            if metrics_out is not None:
+                                metrics_out.write({
+                                    "record": "train",
+                                    "step": stepno,
+                                    "examples": m["examples"],
+                                    "loss": m["loss"],
+                                    "auc": m["auc"],
+                                    "examples_per_sec": rate,
+                                    "elapsed": now - t0,
+                                })
                     if (
                         cfg.validation_steps
                         and cfg.validation_files

@@ -124,28 +124,35 @@ def sparse_step_shardmap(cfg: FmConfig, params, opt_state, batch: Batch,
 
     def _fm_fwd_bwd(w0, rows, vals, labels, weights):
         """Plain FM: partial (linear, s1, s2) -> psum -> closed-form grad."""
-        w = rows[..., 0].astype(cd)
-        v = rows[..., 1:].astype(cd)
-        vals_c = vals.astype(cd)
-        xv = v * vals_c[..., None]
-        # Partial terms from this shard's rows; psum over model completes
-        # them — the entire "lookup" is this [b, 2k+1] collective.
-        terms = jnp.concatenate(
-            [
-                jnp.sum(w * vals_c, axis=-1, keepdims=True,
-                        dtype=jnp.float32),  # linear
-                jnp.sum(xv, axis=1, dtype=jnp.float32),  # s1 [b, k]
-                jnp.sum(xv * xv, axis=1, dtype=jnp.float32),  # s2 [b, k]
-            ],
-            axis=-1,
-        )
-        terms = jax.lax.psum(terms, MODEL_AXIS)
-        linear, s1, s2 = terms[:, 0], terms[:, 1:1 + k], terms[:, 1 + k:]
-        scores = w0 + linear + 0.5 * jnp.sum(s1 * s1 - s2, axis=-1)
-        g, gx = _g_gx(scores, labels, weights, vals)
-        # Closed-form FmGrad for the occurrences this shard owns.
-        dv = gx[..., None] * (s1[:, None, :] - xv.astype(jnp.float32))
-        return scores, g, jnp.concatenate([gx[..., None], dv], axis=-1)
+        with jax.named_scope("tffm.fm_interaction_fwd"):
+            w = rows[..., 0].astype(cd)
+            v = rows[..., 1:].astype(cd)
+            vals_c = vals.astype(cd)
+            xv = v * vals_c[..., None]
+            # Partial terms from this shard's rows; psum over model
+            # completes them — the entire "lookup" is this [b, 2k+1]
+            # collective.
+            terms = jnp.concatenate(
+                [
+                    jnp.sum(w * vals_c, axis=-1, keepdims=True,
+                            dtype=jnp.float32),  # linear
+                    jnp.sum(xv, axis=1, dtype=jnp.float32),  # s1 [b, k]
+                    jnp.sum(xv * xv, axis=1, dtype=jnp.float32),  # s2
+                ],
+                axis=-1,
+            )
+            terms = jax.lax.psum(terms, MODEL_AXIS)
+            linear, s1, s2 = (terms[:, 0], terms[:, 1:1 + k],
+                              terms[:, 1 + k:])
+            inter = 0.5 * jnp.sum(s1 * s1 - s2, axis=-1)
+        with jax.named_scope("tffm.loss"):
+            scores = w0 + linear + inter
+            g, gx = _g_gx(scores, labels, weights, vals)
+        with jax.named_scope("tffm.fm_interaction_bwd"):
+            # Closed-form FmGrad for the occurrences this shard owns.
+            dv = gx[..., None] * (s1[:, None, :] - xv.astype(jnp.float32))
+            drows = jnp.concatenate([gx[..., None], dv], axis=-1)
+        return scores, g, drows
 
     def _ffm_fwd_bwd(w0, rows, vals, fields, labels, weights):
         """Field-aware FM, same inversion: the field-grouped sums
@@ -157,45 +164,50 @@ def sparse_step_shardmap(cfg: FmConfig, params, opt_state, batch: Batch,
         ffm_cd = ffm_compute_dtype(cd)  # f32 off-TPU: CPU can't bf16-dot
         p_num = cfg.field_num
         b, f = vals.shape
-        w = rows[..., 0].astype(ffm_cd)
-        v = rows[..., 1:].astype(ffm_cd).reshape(b, f, p_num, k)
-        vals_c = vals.astype(ffm_cd)
-        oh = (
-            fields[..., None] == jnp.arange(p_num, dtype=fields.dtype)
-        ).astype(ffm_cd)  # [b, F, P]
-        linear_p = jnp.sum(w * vals_c, axis=-1, dtype=jnp.float32)
-        s_p = jnp.einsum(
-            "bfp,bfqk->bpqk", oh * vals_c[..., None], v,
-            preferred_element_type=jnp.float32,
-        )  # [b, P, P, k] partial field-grouped sums
-        v_own = jnp.einsum(
-            "bfq,bfqk->bfk", oh, v, preferred_element_type=jnp.float32
-        )  # v_i^{f_i}, zero off-shard (v is masked)
-        self_p = jnp.sum(
-            jnp.sum(v_own * v_own, axis=-1) * (vals * vals), axis=-1
-        )
-        terms = jnp.concatenate(
-            [linear_p[:, None], self_p[:, None],
-             s_p.reshape(b, p_num * p_num * k)],
-            axis=-1,
-        )
-        terms = jax.lax.psum(terms, MODEL_AXIS)
-        linear, self_t = terms[:, 0], terms[:, 1]
-        s_full = terms[:, 2:].reshape(b, p_num, p_num, k)
-        cross = jnp.einsum("bpqk,bqpk->b", s_full, s_full)
-        scores = w0 + linear + 0.5 * (cross - self_t)
-        g, gx = _g_gx(scores, labels, weights, vals)
-        oh32 = oh.astype(jnp.float32)
-        # T[b,f,q,:] = S[b, q, f_i, :] — gather S's second field axis by
-        # each occurrence's own field, as a one-hot matmul.
-        t = jnp.einsum("bqpk,bfp->bfqk", s_full, oh32)
-        dv = gx[..., None, None] * (
-            t
-            - oh32[..., None] * v_own[:, :, None, :] * vals[..., None, None]
-        )  # [b, F, P, k]
-        return scores, g, jnp.concatenate(
-            [gx[..., None], dv.reshape(b, f, p_num * k)], axis=-1
-        )
+        with jax.named_scope("tffm.ffm_interaction_fwd"):
+            w = rows[..., 0].astype(ffm_cd)
+            v = rows[..., 1:].astype(ffm_cd).reshape(b, f, p_num, k)
+            vals_c = vals.astype(ffm_cd)
+            oh = (
+                fields[..., None] == jnp.arange(p_num, dtype=fields.dtype)
+            ).astype(ffm_cd)  # [b, F, P]
+            linear_p = jnp.sum(w * vals_c, axis=-1, dtype=jnp.float32)
+            s_p = jnp.einsum(
+                "bfp,bfqk->bpqk", oh * vals_c[..., None], v,
+                preferred_element_type=jnp.float32,
+            )  # [b, P, P, k] partial field-grouped sums
+            v_own = jnp.einsum(
+                "bfq,bfqk->bfk", oh, v, preferred_element_type=jnp.float32
+            )  # v_i^{f_i}, zero off-shard (v is masked)
+            self_p = jnp.sum(
+                jnp.sum(v_own * v_own, axis=-1) * (vals * vals), axis=-1
+            )
+            terms = jnp.concatenate(
+                [linear_p[:, None], self_p[:, None],
+                 s_p.reshape(b, p_num * p_num * k)],
+                axis=-1,
+            )
+            terms = jax.lax.psum(terms, MODEL_AXIS)
+            linear, self_t = terms[:, 0], terms[:, 1]
+            s_full = terms[:, 2:].reshape(b, p_num, p_num, k)
+            cross = jnp.einsum("bpqk,bqpk->b", s_full, s_full)
+            inter = 0.5 * (cross - self_t)
+        with jax.named_scope("tffm.loss"):
+            scores = w0 + linear + inter
+            g, gx = _g_gx(scores, labels, weights, vals)
+        with jax.named_scope("tffm.ffm_interaction_bwd"):
+            oh32 = oh.astype(jnp.float32)
+            # T[b,f,q,:] = S[b, q, f_i, :] — gather S's second field axis
+            # by each occurrence's own field, as a one-hot matmul.
+            t = jnp.einsum("bqpk,bfp->bfqk", s_full, oh32)
+            dv = gx[..., None, None] * (
+                t - oh32[..., None] * v_own[:, :, None, :]
+                * vals[..., None, None]
+            )  # [b, F, P, k]
+            drows = jnp.concatenate(
+                [gx[..., None], dv.reshape(b, f, p_num * k)], axis=-1
+            )
+        return scores, g, drows
 
     def _g_gx(scores, labels, weights, vals):
         # Global weighted-mean loss: normalizer spans the data axis.
@@ -207,12 +219,13 @@ def sparse_step_shardmap(cfg: FmConfig, params, opt_state, batch: Batch,
 
     def device_fn(w0, table_l, labels, ids, vals, fields, weights,
                   *opt_tables_l):
-        m = jax.lax.axis_index(MODEL_AXIS)
-        row_lo = m * vocab_local
-        local = (ids >= row_lo) & (ids < row_lo + vocab_local)  # [b, F]
-        lids = jnp.where(local, ids - row_lo, 0)
-        maskf = local.astype(jnp.float32)
-        rows = table_l[lids] * maskf[..., None]  # [b, F, D], 0 off-shard
+        with jax.named_scope("tffm.row_gather"):
+            m = jax.lax.axis_index(MODEL_AXIS)
+            row_lo = m * vocab_local
+            local = (ids >= row_lo) & (ids < row_lo + vocab_local)  # [b, F]
+            lids = jnp.where(local, ids - row_lo, 0)
+            maskf = local.astype(jnp.float32)
+            rows = table_l[lids] * maskf[..., None]  # [b, F, D], 0 off-shard
         # bf16 mode (cd) rounds the [b, F, D] interaction operands (the
         # step's dominant HBM streams); sums accumulate f32, and the
         # psum'd terms, backward, and optimizer stay f32.
@@ -223,23 +236,33 @@ def sparse_step_shardmap(cfg: FmConfig, params, opt_state, batch: Batch,
         else:
             scores, g, drows = _fm_fwd_bwd(w0, rows, vals, labels, weights)
         # Only occurrences this shard owns update its rows.
-        drows = drows * maskf[..., None]
+        with jax.named_scope("tffm.fm_interaction_bwd"
+                             if not cfg.field_num
+                             else "tffm.ffm_interaction_bwd"):
+            drows = drows * maskf[..., None]
         if cfg.factor_lambda or cfg.bias_lambda:
-            # d/drow of l2_penalty_batch: 2*lambda*row/B per occurrence.
-            bsz = jax.lax.psum(jnp.float32(vals.shape[0]), DATA_AXIS)
-            lam = jnp.concatenate([
-                jnp.full((1,), cfg.bias_lambda, jnp.float32),
-                jnp.full(
-                    (rows.shape[-1] - 1,), cfg.factor_lambda, jnp.float32
-                ),
-            ])
-            occ = (vals != 0).astype(jnp.float32)[..., None] * maskf[..., None]
-            drows = drows + (2.0 / bsz) * lam * rows * occ
+            with jax.named_scope("tffm.loss"):
+                # d/drow of l2_penalty_batch: 2*lambda*row/B per
+                # occurrence.
+                bsz = jax.lax.psum(jnp.float32(vals.shape[0]), DATA_AXIS)
+                lam = jnp.concatenate([
+                    jnp.full((1,), cfg.bias_lambda, jnp.float32),
+                    jnp.full(
+                        (rows.shape[-1] - 1,), cfg.factor_lambda,
+                        jnp.float32
+                    ),
+                ])
+                occ = ((vals != 0).astype(jnp.float32)[..., None]
+                       * maskf[..., None])
+                drows = drows + (2.0 / bsz) * lam * rows * occ
         # Local-coordinate occurrence list; off-shard -> sentinel row.
         b, f = vals.shape
         d = rows.shape[-1]  # 1 + k (FM) or 1 + field_num*k (FFM)
-        ids_flat = jnp.where(local, ids - row_lo, vocab_local).reshape(b * f)
-        g_flat = drows.reshape(b * f, d)
+        with jax.named_scope("tffm.exchange" if exchange == "entries"
+                             else "tffm.apply_prep"):
+            ids_flat = jnp.where(
+                local, ids - row_lo, vocab_local).reshape(b * f)
+            g_flat = drows.reshape(b * f, d)
         if exchange == "entries":
             # Batch-proportional update exchange: dedupe locally, move
             # only the touched entries over the data axis, merge the S
@@ -261,12 +284,14 @@ def sparse_step_shardmap(cfg: FmConfig, params, opt_state, batch: Batch,
             w_new, new_tables = _apply_delta(
                 cfg, delta[:, :d], delta[:, d:], table_l, opt_tables_l
             )
-        dw0 = jax.lax.psum(jnp.sum(g), DATA_AXIS)
-        if cfg.bias_lambda:
-            # l2_penalty_batch includes bias_lambda*w0^2/B — its w0 grad
-            # must land here too or w0 diverges from the scatter path.
-            bsz_g = jax.lax.psum(jnp.float32(vals.shape[0]), DATA_AXIS)
-            dw0 = dw0 + 2.0 * cfg.bias_lambda * w0 / bsz_g
+        with jax.named_scope("tffm.loss"):
+            dw0 = jax.lax.psum(jnp.sum(g), DATA_AXIS)
+            if cfg.bias_lambda:
+                # l2_penalty_batch includes bias_lambda*w0^2/B — its w0
+                # grad must land here too or w0 diverges from the
+                # scatter path.
+                bsz_g = jax.lax.psum(jnp.float32(vals.shape[0]), DATA_AXIS)
+                dw0 = dw0 + 2.0 * cfg.bias_lambda * w0 / bsz_g
         outs = (w_new, scores, dw0) + tuple(new_tables)
         if health:
             # Each occurrence's grad lives on exactly ONE model shard
@@ -274,18 +299,19 @@ def sparse_step_shardmap(cfg: FmConfig, params, opt_state, batch: Batch,
             # squares over both axes is the global occurrence-grad norm
             # — no double counting.  dw0 is already global; folded in
             # by the caller.
-            gsq = jax.lax.psum(
-                jnp.sum(jnp.square(g_flat)), (MODEL_AXIS, DATA_AXIS)
-            )
-            nonfin = jax.lax.psum(
-                jnp.sum((~jnp.isfinite(g_flat)).astype(jnp.int32)),
-                (MODEL_AXIS, DATA_AXIS),
-            )
-            outs = outs + (gsq, nonfin)
-            if exchange == "entries":
-                # every data replica of a model shard holds the same
-                # merged stream: count a shard's once
-                outs = outs + (jax.lax.psum(exchanged, MODEL_AXIS),)
+            with jax.named_scope("tffm.health"):
+                gsq = jax.lax.psum(
+                    jnp.sum(jnp.square(g_flat)), (MODEL_AXIS, DATA_AXIS)
+                )
+                nonfin = jax.lax.psum(
+                    jnp.sum((~jnp.isfinite(g_flat)).astype(jnp.int32)),
+                    (MODEL_AXIS, DATA_AXIS),
+                )
+                outs = outs + (gsq, nonfin)
+                if exchange == "entries":
+                    # every data replica of a model shard holds the same
+                    # merged stream: count a shard's once
+                    outs = outs + (jax.lax.psum(exchanged, MODEL_AXIS),)
         return outs
 
     out_specs = (
@@ -318,8 +344,9 @@ def sparse_step_shardmap(cfg: FmConfig, params, opt_state, batch: Batch,
     new_params = fm.FmParams(w0=w0_new, table=table_new)
     if health:
         gsq, nonfin = outs[3 + n_opt], outs[4 + n_opt]
-        grad_sq = gsq + jnp.square(dw0)
-        nonfin = nonfin + (~jnp.isfinite(dw0)).astype(jnp.int32)
+        with jax.named_scope("tffm.health"):
+            grad_sq = gsq + jnp.square(dw0)
+            nonfin = nonfin + (~jnp.isfinite(dw0)).astype(jnp.int32)
         aux = (grad_sq, nonfin)
         if exchange == "entries":
             # the host solves train.exchange_compact from the two

@@ -223,6 +223,7 @@ def _rows_loss_fn(
     optimizer).
     """
 
+    @jax.named_scope("tffm.loss")  # the interaction inside keeps its own
     def loss_fn(w0, rows):
         if cfg.field_num:
             # Closed-form FFM op (ops.interaction.ffm_interaction): same
@@ -463,6 +464,7 @@ def make_exchange_probe(mesh):
     return probe
 
 
+@jax.named_scope("tffm.health")
 def grad_health(g_rows, dw0):
     """(grad_sq, nonfinite_count) for a step's gradients — the on-device
     training-health aux the scan carry accumulates (train.loop).
@@ -507,8 +509,9 @@ def sparse_step(
         loss_fn, argnums=(0, 1), has_aux=True
     )(params.w0, rows)
     b, f, d = drows.shape
-    ids = batch.ids.reshape(b * f)
-    g_rows = drows.reshape(b * f, d)
+    with jax.named_scope("tffm.apply_prep"):  # the occurrence stream
+        ids = batch.ids.reshape(b * f)
+        g_rows = drows.reshape(b * f, d)
     mode = apply_mode(cfg, mesh)
     if rows_all is not None and mode != "sharded":
         raise ValueError(
