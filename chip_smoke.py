@@ -430,7 +430,11 @@ def _applies_vs_host(cfg, seed: int) -> dict:
       apply (``scatter_apply_unique``) through its scatter writer, its
       additive and its gather-update-set form;
     * ``stream_adagrad`` / ``stream_ftrl``: the same apply through its
-      transposed tile-stream writer (a vocabulary of whole subtiles);
+      transposed tile-stream writer (a vocabulary of whole subtiles),
+      and the writer's row-touch mask: a tenth of the occurrences are
+      marked padding (their gradients still count: only the mask
+      leaves them out), ``touched_rows_wrong`` the rows where the mask
+      is not the host's set of rows with a real occurrence;
     * ``tile_adagrad``: the tile kernels (K1 + K2), whose step-level
       oracle (``sparse_apply=scatter``) runs the same prep since PR 27;
       left out at a row they do not hold (their payload ``[g | g^2 |
@@ -462,6 +466,9 @@ def _applies_vs_host(cfg, seed: int) -> dict:
     np.add.at(g2, inv, g64 * g64)
     rest = np.ones(v, bool)
     rest[uniq] = False
+    real = rng.uniform(size=n) > 0.1
+    touched_ref = np.zeros(v, bool)
+    touched_ref[ids[real]] = True
 
     acc_ref = acc[uniq] + g2
     adagrad_ref = [table[uniq] - lr * g1 / np.sqrt(acc_ref + eps), acc_ref]
@@ -477,11 +484,13 @@ def _applies_vs_host(cfg, seed: int) -> dict:
     def unique(update, additive, stream=False):
         def apply(i, gr, *tabs):
             return sparse_apply.scatter_apply_unique(
-                update, tabs, i, gr, additive=additive, stream=stream)
+                update, tabs, i, gr, additive=additive, stream=stream,
+                real=real if stream else None)
         return apply
 
     def tile(i, gr, t, a):
-        return sparse_apply.adagrad_apply(t, a, i, gr, lr=lr, eps=eps), None
+        return (sparse_apply.adagrad_apply(t, a, i, gr, lr=lr, eps=eps),
+                None, None)
 
     adagrad = partial(sparse_apply.adagrad_update, lr=lr, eps=eps)
     ftrl = partial(sparse_apply.ftrl_update, lr=lr, l1=l1, l2=l2, beta=beta)
@@ -501,11 +510,13 @@ def _applies_vs_host(cfg, seed: int) -> dict:
         "hottest_row_occurrences": int(np.bincount(inv).max()),
     }
     for name, (apply, before, want) in cases.items():
-        after, count = jax.jit(apply)(ids, g, *before)
+        after, count, touched = jax.jit(apply)(ids, g, *before)
         after = [np.asarray(x) for x in after]
         out[name] = {
             "atol": TILE_ATOL if name == "tile_adagrad" else SCATTER_ATOL,
             "rows_written": None if count is None else int(count),
+            "touched_rows_wrong": None if touched is None else int(
+                np.sum(np.asarray(touched) != touched_ref)),
             "max_abs": [float(np.abs(x[uniq] - w).max())
                         for x, w in zip(after, want)],
             "untouched_rows_changed": int(sum(
@@ -566,6 +577,7 @@ def phase_kernels(out: Out, cfg, size: Size, work: str, seed: int):
         require(
             got is None or (
                 got["rows_written"] in (None, applies["unique_rows"])
+                and got["touched_rows_wrong"] in (None, 0)
                 and got["untouched_rows_changed"] == 0
                 and max(got["max_abs"]) <= got["atol"]),
             f"the {name} apply differs from the host's per-occurrence "
@@ -658,6 +670,7 @@ def phase_ffm(out: Out, cfg, seed: int):
         require(
             got is None or (
                 got["rows_written"] == applies["unique_rows"]
+                and got["touched_rows_wrong"] in (None, 0)
                 and got["untouched_rows_changed"] == 0
                 and max(got["max_abs"]) <= got["atol"]),
             f"the {name} apply at {ffm.embedding_dim} floats a row "

@@ -672,12 +672,14 @@ def unique_entries(ids, g_rows, *, vocab, cap, segment_sums=_k1_dedup,
     return rows, pay, count
 
 
-def _unique_stream(ids, g_rows, vocab, segment_sums, pad_first):
+def _unique_stream(ids, g_rows, vocab, segment_sums, pad_first,
+                   real=None):
     """unique_entries' first half: K1's deduped stream ``u`` (one unique
-    row a slot from slot 0, sum g | sum g^2 | lrow | tidx on its lanes)
-    and ``count``, its real (non-sentinel) entries."""
+    row a slot from slot 0, sum g | sum g^2 | lrow | tidx on its lanes,
+    and the real occurrences where ``real`` is given: see _prep) and
+    ``count``, its real (non-sentinel) entries."""
     payload, upos, starts, firsts, ends, sidx, n_pad = _prep(
-        ids, g_rows, vocab, pad_first
+        ids, g_rows, vocab, pad_first, real
     )
     u = segment_sums(payload, upos, starts, firsts, ends, n_pad + TILE)
     count = _tile_starts(
@@ -686,15 +688,21 @@ def _unique_stream(ids, g_rows, vocab, segment_sums, pad_first):
     return u, count
 
 
-def _stream_entries(u, count, slots, vocab, d):
+def _stream_entries(u, count, slots, vocab, d, touch=False):
     """unique_entries' second half over the stream's first ``slots``:
     ``(rows [slots], pay [slots, 2 D])``, the slots past ``count`` the
-    sentinel row with a zero payload."""
+    sentinel row with a zero payload.  ``touch``: ``pay`` ends in a
+    column more, each row's real occurrences (``u`` carries them: see
+    _prep)."""
     valid = jnp.arange(slots, dtype=jnp.int32) < count
     lrow = u[:slots, 2 * d].astype(jnp.int32)
     tidx = u[:slots, 2 * d + 1].astype(jnp.int32)
     rows = jnp.where(valid, tidx * TILE + lrow, vocab)
-    pay = jnp.where(valid[:, None], u[:slots, :2 * d], 0.0)
+    pay = u[:slots, :2 * d]
+    if touch:
+        lane = _real_lane(d)
+        pay = jnp.concatenate([pay, u[:slots, lane:lane + 1]], axis=1)
+    pay = jnp.where(valid[:, None], pay, 0.0)
     return rows, pay
 
 
@@ -763,14 +771,27 @@ def _xla_segment_sums(payload, upos, starts, firsts, ends, n_out):
 STREAM_WINDOW = 128
 
 
-def _placed_sums_t(u, cnt, first_tile, d, block):
+def _real_lane(d):
+    """The payload lane of a row's real occurrences (see _prep): behind
+    sum g | sum g^2 | lrow | tidx."""
+    return 2 * d + 2
+
+
+def _placed_width(d, touch):
+    """Payload columns the stream writer places: the 2 D sums, and with
+    ``touch`` on through the real occurrences."""
+    return _real_lane(d) + 1 if touch else 2 * d
+
+
+def _placed_sums_t(u, cnt, first_tile, d, block, width):
     """Window entries -> the block's dense per-row sums, TRANSPOSED
-    ([2 D to 16 sublanes, block]: rows 0..D-1 sum g, D..2D-1 sum g^2),
-    and how many entries hit each row ([1, block]).
+    ([``width`` to 16 sublanes, block]: rows 0..D-1 sum g, D..2D-1 sum
+    g^2, and where ``width`` reaches it row _real_lane(d) the real
+    occurrences), and how many entries hit each row ([1, block]).
 
     ``u`` holds the window of each 128-lane stream, [streams, E, 128]
-    (the payload columns [g | g^2 | lrow | tidx | 0...] cut into lane
-    tiles); its first ``cnt`` entries belong to this block, whose first
+    (the payload columns [g | g^2 | lrow | tidx | real | 0...] cut into
+    lane tiles, ``real`` where the caller asked: _prep); its first ``cnt`` entries belong to this block, whose first
     TILE-row subtile is ``first_tile``.  ``dense_t[L, R] = u^T[L, E] .
     onehot[E, R]`` with u as three bf16 terms, each rounding what the
     ones before left over: an output column has ONE non-zero product a
@@ -792,10 +813,10 @@ def _placed_sums_t(u, cnt, first_tile, d, block):
     match = (row == r_iota) & (e_col < cnt)  # [entry, row of the block]
     hits = jnp.sum(match.astype(jnp.float32), axis=0, keepdims=True)
     p = match.astype(jnp.bfloat16)
-    # u^T, only the 2 D rows that hold sums (to whole bf16 sublane tiles).
+    # u^T, only the ``width`` rows placed (to whole bf16 sublane tiles).
     ut = jnp.concatenate([
-        u[k].T[:min(128, _round_up(2 * d - 128 * k, 16))]
-        for k in range(-(-2 * d // 128))
+        u[k].T[:min(128, _round_up(width - 128 * k, 16))]
+        for k in range(-(-width // 128))
     ], axis=0)  # [L, E]
     e_row = jax.lax.broadcasted_iota(jnp.int32, (1, window), 1)
     # where(), not a multiply: the tail may hold NaN garbage.
@@ -822,7 +843,7 @@ def _placed_sums_t(u, cnt, first_tile, d, block):
 
 
 def _stream_kernel(gs_ref, *args, n_tables, n_streams, tile, group, d,
-                   update, additive):
+                   update, additive, touch):
     """One grid step of the stream writer: a block of TILE * group rows
     of every (transposed) table.  The block's unique entries, stream
     positions ``gs_ref[t] .. gs_ref[t + 1]``, are placed STREAM_WINDOW
@@ -832,11 +853,14 @@ def _stream_kernel(gs_ref, *args, n_tables, n_streams, tile, group, d,
     new value is the old one PLUS what ``update`` makes of zeros: the
     scatter writer's arithmetic operation for operation); a row the
     batch did not touch keeps its bits whatever ``update`` would make of
-    zero sums (FTRL's recompute is NOT leaned on)."""
+    zero sums (FTRL's recompute is NOT leaned on).  ``touch``: a last
+    output block holds the block's row-touch mask, the rows with a real
+    occurrence (int32, lane-dense: [1, block / 128, 128])."""
     ins = args[:n_tables]
     u_hbm_refs = args[n_tables:n_tables + n_streams]
+    n_outs = 2 * n_tables + n_streams + int(touch)
     outs = args[n_tables + n_streams:2 * n_tables + n_streams]
-    u_vmem, sem, sums_ref, hits_ref = args[2 * n_tables + n_streams:]
+    u_vmem, sem, sums_ref, hits_ref = args[n_outs:]
     t = pl.program_id(0)
     start, end = gs_ref[t], gs_ref[t + 1]
     window, block = STREAM_WINDOW, tile * group
@@ -870,7 +894,8 @@ def _stream_kernel(gs_ref, *args, n_tables, n_streams, tile, group, d,
         for cp in copies(k, slot):
             cp.wait()
         dense, hits = _placed_sums_t(
-            u_vmem[slot], end - start - k * window, t * group, d, block)
+            u_vmem[slot], end - start - k * window, t * group, d, block,
+            _placed_width(d, touch))
         sums_ref[...] += dense
         hits_ref[...] += hits
         return carry
@@ -889,6 +914,16 @@ def _stream_kernel(gs_ref, *args, n_tables, n_streams, tile, group, d,
             new = update(g1, g2, *old)
         for out_ref, val, keep in zip(outs, new, old):
             out_ref[:, cols] = jnp.where(touched, val, keep)
+    if touch:
+        # The mask counts real occurrences, not hits: a padding
+        # occurrence is written above (with zero sums) but touches
+        # nothing.  One 128-lane row a store.
+        touch_ref = args[n_outs - 1]
+        lane = _real_lane(d)
+        real = sums_ref[lane:lane + 1, :]
+        for i in range(block // 128):
+            touch_ref[0, i:i + 1, :] = (
+                real[:, i * 128:(i + 1) * 128] > 0.0).astype(jnp.int32)
 
 
 # VMEM the stream writer's table blocks may take: each table has a
@@ -915,7 +950,8 @@ def _stream_group(n_tiles, d, n_tables):
         STREAM_GROUP, _STREAM_BLOCK_BYTES // per_subtile)))
 
 
-def _stream_call(update, group_start, u_tiles, tables, additive, group):
+def _stream_call(update, group_start, u_tiles, tables, additive, group,
+                 touch=False):
     """Stream ``tables`` whole through the transposed apply kernel.
 
     ``u_tiles``: the unique-entry stream as its 128-lane tiles
@@ -923,21 +959,34 @@ def _stream_call(update, group_start, u_tiles, tables, additive, group):
     TILE * ``group`` rows (_tile_starts).  Each table goes in as ``t.T``
     — a bitcast of how [V, D] rests on the TPU — aliased in place, and
     comes back as ``out.T``: no whole-table copy, no write loop.
+
+    Returns ``(tables, touched)``.  ``touch`` (the stream carries each
+    row's real occurrences at _real_lane(d): see _prep): ``touched`` is
+    the step's row-touch mask, bool [V], the rows with at least one
+    real occurrence — what the writer already knows a block at a time,
+    written lane-dense, 4 bytes a row; else None.
     """
     v, d = tables[0].shape
     n_tables, n_streams = len(tables), len(u_tiles)
     block = TILE * group
     spec = pl.BlockSpec((d, block), lambda t, *_: (0, t))
+    mask_spec, mask_shape = [], []
+    if touch:
+        mask_spec = [pl.BlockSpec((1, block // 128, 128),
+                                  lambda t, *_: (t, 0, 0))]
+        mask_shape = [jax.ShapeDtypeStruct(
+            (v // block, block // 128, 128), jnp.int32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(v // block,),
         in_specs=[spec] * n_tables
         + [pl.BlockSpec(memory_space=pl.ANY)] * n_streams,
-        out_specs=[spec] * n_tables,
+        out_specs=[spec] * n_tables + mask_spec,
         scratch_shapes=[
             pltpu.VMEM((2, n_streams, STREAM_WINDOW, 128), jnp.float32),
             pltpu.SemaphoreType.DMA((2, n_streams)),
-            pltpu.VMEM((_round_up(2 * d, 16), block), jnp.float32),
+            pltpu.VMEM((_round_up(_placed_width(d, touch), 16), block),
+                       jnp.float32),
             pltpu.VMEM((1, block), jnp.float32),
         ],
     )
@@ -945,13 +994,16 @@ def _stream_call(update, group_start, u_tiles, tables, additive, group):
         functools.partial(
             _stream_kernel, n_tables=n_tables, n_streams=n_streams,
             tile=TILE, group=group, d=d, update=update, additive=additive,
+            touch=touch,
         ),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((d, v), t.dtype) for t in tables],
+        out_shape=[jax.ShapeDtypeStruct((d, v), t.dtype) for t in tables]
+        + mask_shape,
         input_output_aliases={1 + i: i for i in range(n_tables)},
         interpret=_use_interpret(),
     )(group_start, *(t.T for t in tables), *u_tiles)
-    return tuple(o.T for o in out)
+    touched = out[n_tables].reshape(v) != 0 if touch else None
+    return tuple(o.T for o in out[:n_tables]), touched
 
 
 # The rule's two constants, v5e (my chip runs, PR 34; PERF.md §6).
@@ -1003,7 +1055,7 @@ def takes_stream(n_occurrences: int, vocab: int, d: int,
 
 
 def scatter_apply_unique(update, tables, ids, g_rows, *, additive=False,
-                         stream=None):
+                         stream=None, real=None):
     """One-device optimizer apply that writes each touched row ONCE.
 
     The per-occurrence scatter costs ~0.1 us a row on v5e whatever the
@@ -1044,15 +1096,23 @@ def scatter_apply_unique(update, tables, ids, g_rows, *, additive=False,
     accepted: hashed CTR batches sit far below, and the caller's
     ``count`` (gauge ``train.apply_unique_frac``) shows where a run is.
 
+    ``real`` (bool [n]: the occurrences with ``val != 0``): where the
+    stream writer runs, it also returns the step's row-touch mask, bool
+    [V], the rows with at least one real occurrence (see _prep and
+    _stream_call) — the health carry's ``rows_touched`` without a
+    scatter of the batch.  The scatter loop returns none.
+
     Single-device only (a global sort under GSPMD would all-gather the
     batch, and the partitioner cannot split the Mosaic call).  Returns
-    ``(new_tables, count)``.
+    ``(new_tables, count, touched)``, ``touched`` None where no mask was
+    made.
     """
     vocab, d = tables[0].shape
     if stream is None:
         stream = takes_stream(ids.shape[0], vocab, d, len(tables))
     if stream:
-        return _stream_apply_unique(update, tables, ids, g_rows, additive)
+        return _stream_apply_unique(
+            update, tables, ids, g_rows, additive, real)
     cap = entries_cap(ids.shape[0], vocab)
     if vocab + cap >= 1 << 31:
         raise ValueError(
@@ -1094,10 +1154,10 @@ def scatter_apply_unique(update, tables, ids, g_rows, *, additive=False,
     trips = (count + chunk - 1) // chunk
     with jax.named_scope("tffm.apply_write"):
         tables = jax.lax.fori_loop(0, trips, body, tuple(tables))
-    return tables, count
+    return tables, count, None
 
 
-def _stream_apply_unique(update, tables, ids, g_rows, additive):
+def _stream_apply_unique(update, tables, ids, g_rows, additive, real=None):
     """scatter_apply_unique through the stream writer: the same sort,
     payload and three-pass K1 (its XLA stand-in where interpreted); the
     entries are found per block of TILE * group rows by ``group_start``
@@ -1105,7 +1165,7 @@ def _stream_apply_unique(update, tables, ids, g_rows, additive):
     vocab, d = tables[0].shape
     with jax.named_scope("tffm.apply_prep"):  # sort, payload, K1
         payload, upos, starts, firsts, ends, sidx, n_pad = _prep(
-            ids, g_rows, vocab, pad_first=True
+            ids, g_rows, vocab, pad_first=True, real=real
         )
         if _use_interpret():
             u = _xla_segment_sums(
@@ -1120,9 +1180,10 @@ def _stream_apply_unique(update, tables, ids, g_rows, additive):
         group_start = _tile_starts(sidx, upos, bounds, "scan_unrolled")
         count = group_start[-1]  # uniques among real (non-sentinel) rows
     with jax.named_scope("tffm.apply_write"):
-        tables = _stream_call(
-            update, group_start, u_tiles, tables, additive, group)
-    return tables, count
+        tables, touched = _stream_call(
+            update, group_start, u_tiles, tables, additive, group,
+            touch=real is not None)
+    return tables, count, touched
 
 
 def merge_entries(rows, pay, *, vocab):
@@ -1163,7 +1224,8 @@ def k2_apply(update, tile_start, u, tables, compact=None):
                     compact=compact)
 
 
-def merge_entries_stream(rows, pay, *, vocab, group, segment_sums=None):
+def merge_entries_stream(rows, pay, *, vocab, group, segment_sums=None,
+                         touch=False):
     """merge_entries in the stream writer's form: ``(u_tiles,
     group_start)`` as _stream_call takes them.
 
@@ -1178,6 +1240,9 @@ def merge_entries_stream(rows, pay, *, vocab, group, segment_sums=None):
     (see _payload_padded_first: N is two or more batches' worth).
     ``segment_sums`` stands in for K1 (default: three-pass K1 compiled,
     XLA's sorted segment sum interpreted, as the one-device stream).
+    ``touch``: ``pay`` ends in a column more, each row's real
+    occurrences in the shard (gather_entries), and the merged payload
+    carries their sum at _real_lane(D), as _prep lays it out.
     """
     n = rows.shape[0]
     if n % CHUNK:
@@ -1190,8 +1255,12 @@ def merge_entries_stream(rows, pay, *, vocab, group, segment_sums=None):
         upos, last, starts, firsts, ends = _sorted_stream_meta(sidx)
         lrow = (sidx % TILE).astype(jnp.float32)
         tidx = (sidx // TILE).astype(jnp.float32)
-        payload = _padded_then_permuted(
-            [pay], perm, [lrow * last, tidx * last])
+        meta = [lrow * last, tidx * last]
+        if touch:
+            payload = _padded_then_permuted(
+                [pay[:, :-1]], perm, meta, pay[:, -1])
+        else:
+            payload = _padded_then_permuted([pay], perm, meta)
         u = segment_sums(payload, upos, starts, firsts, ends, n + TILE)
         # whole lane tiles: the slices move nothing (see _k1_tiles)
         u_tiles = [u[:, j:j + 128] for j in range(0, u.shape[1], 128)]
@@ -1200,33 +1269,39 @@ def merge_entries_stream(rows, pay, *, vocab, group, segment_sums=None):
     return u_tiles, group_start
 
 
-def merged_stream_apply(update, tables, rows, pay):
+def merged_stream_apply(update, tables, rows, pay, touch=False):
     """Apply ``update`` from the concatenated per-shard entry streams
     through the stream writer (_stream_call): every table of the shard
     whole through VMEM once as ``table.T``, no row-major copy.  Returns
-    ``(new_tables, count)``, ``count`` the merged stream's real entries
-    (the rows written)."""
+    ``(new_tables, count, touched)``, ``count`` the merged stream's real
+    entries (the rows written), ``touched`` the shard's row-touch mask
+    where ``touch`` (``pay``'s last column counts real occurrences:
+    merge_entries_stream), else None."""
     vocab, d = tables[0].shape
     group = _stream_group(vocab // TILE, d, len(tables))
     u_tiles, group_start = merge_entries_stream(
-        rows, pay, vocab=vocab, group=group)
+        rows, pay, vocab=vocab, group=group, touch=touch)
     with jax.named_scope("tffm.apply_write"):
-        tables = _stream_call(
-            update, group_start, u_tiles, tuple(tables), False, group)
-    return tables, group_start[-1]
+        tables, touched = _stream_call(
+            update, group_start, u_tiles, tuple(tables), False, group,
+            touch=touch)
+    return tables, group_start[-1], touched
 
 
 def gather_entries(u, count, *, slots, d, vocab_local, data_axis,
-                   rows_all=None):
+                   rows_all=None, touch=False):
     """All-gather the first ``slots`` of every data shard's deduped
     stream (``u``, ``count``: _unique_stream's) over ``data_axis``.
     Returns the concatenated ``(rows [S * slots], pay [S * slots,
     2 D])``.  ``slots`` is a CHUNK multiple that holds every stream's
     ``count`` (entries_exchange_apply sees to that): what lies past it is
     sentinels.  ``rows_all``: the pre-gathered id plane (see
-    entries_exchange_apply) at full capacity, cut to ``slots`` a shard."""
+    entries_exchange_apply) at full capacity, cut to ``slots`` a shard.
+    ``touch``: ``pay`` ends in a column more, each row's real
+    occurrences (_stream_entries)."""
     with jax.named_scope("tffm.exchange"):
-        rows_e, pay_e = _stream_entries(u, count, slots, vocab_local, d)
+        rows_e, pay_e = _stream_entries(
+            u, count, slots, vocab_local, d, touch)
         if rows_all is None:
             rows_all = jax.lax.all_gather(
                 rows_e, data_axis, axis=0, tiled=True
@@ -1250,7 +1325,7 @@ def exchange_takes_stream(data_shards: int) -> bool:
 
 def entries_exchange_apply(update, tables, lids, g_rows, *, vocab_local,
                            data_axis, model_axis, data_shards,
-                           rows_all=None):
+                           rows_all=None, real=None):
     """The ONE copy of the entries-exchange protocol, with the apply on
     top of it (shard_map body; what both the shardmap step and the GSPMD
     sharded apply call — keep it the only copy): dedupe the
@@ -1258,9 +1333,17 @@ def entries_exchange_apply(update, tables, lids, g_rows, *, vocab_local,
     sentinel ``vocab_local``, their payloads zeroed), all-gather the
     touched-entry streams over ``data_axis``, merge them, and apply
     ``update(g1, g2, *table_rows) -> new_table_rows`` to the shard's
-    ``tables``.  Returns ``(new_tables, count, slots)``: the merged
-    stream's real entries, and the slots that were merged for them
-    (``data_shards`` x the rung taken).
+    ``tables``.  Returns ``(new_tables, count, slots, touched)``: the
+    merged stream's real entries, the slots that were merged for them
+    (``data_shards`` x the rung taken), and the shard's row-touch mask
+    (bool [vocab_local]) where ``real`` is given and the stream writer
+    runs, else None.
+
+    ``real`` (bool, per occurrence: ``val != 0``): each stream carries
+    its rows' real occurrences as one more column of the sums, the
+    merge adds them up, and the writer marks the rows whose total is
+    above zero — every data shard's real occurrences, so every data
+    replica of a model shard holds the same mask.
 
     The first-stage K1 runs _EXACT_PASSES: the rows are recovered as
     ``tidx * TILE + lrow``, and two bf16 passes carry the tile index
@@ -1314,30 +1397,42 @@ def entries_exchange_apply(update, tables, lids, g_rows, *, vocab_local,
     if data_shards == 1:
         u, tile_start = _dedup_and_starts(lids, g_rows, vocab_local)
         new = tuple(k2_apply(update, tile_start, u, tables))
-        return new, tile_start[-1], jnp.int32(cap)
+        return new, tile_start[-1], jnp.int32(cap), None
     d = g_rows.shape[1]
+    stream = exchange_takes_stream(data_shards)
+    touch = stream and real is not None
     with jax.named_scope("tffm.exchange"):
         # pad_first: the same payload, bit for bit, lane-padded before
         # it is permuted (a data shard's occurrences are a whole batch's
         # on one device: _payload_padded_first's side of its reading)
         u, count = _unique_stream(
-            lids, g_rows, vocab_local, _k1_exact, pad_first=True)
+            lids, g_rows, vocab_local, _k1_exact, pad_first=True,
+            real=real if touch else None)
 
     def gather(slots):
         return gather_entries(
             u, count, slots=slots, d=d, vocab_local=vocab_local,
-            data_axis=data_axis, rows_all=rows_all)
+            data_axis=data_axis, rows_all=rows_all, touch=touch)
 
     def merge_and_write(tables, rows, pay):
         """The exchange from the all-gathered streams on, at whatever
         length they came."""
-        if exchange_takes_stream(data_shards):
-            new, merged = merged_stream_apply(update, tables, rows, pay)
+        if stream:
+            new, merged, touched = merged_stream_apply(
+                update, tables, rows, pay, touch=touch)
+            if touch:
+                # A fence.  Without it XLA's conditional code motion
+                # hoists the mask's read of the writer's results out of
+                # the branch and returns those results whole, and the
+                # tables, which the branch returns too, are then copied
+                # (two f32[2^25, 9] copies a branch at the 2x2 cell's
+                # shape: tests/test_tpu_compile.py).
+                touched = jax.lax.optimization_barrier(touched)
         else:
             mu, tile_start = merge_entries(rows, pay, vocab=vocab_local)
             new = tuple(k2_apply(update, tile_start, mu, tables))
-            merged = tile_start[-1]
-        return tuple(new), merged, jnp.int32(rows.shape[0])
+            merged, touched = tile_start[-1], None
+        return tuple(new), merged, jnp.int32(rows.shape[0]), touched
 
     rung = entries_rung(cap, data_shards)
     if rung is None:
@@ -1492,7 +1587,7 @@ def _payload(g_sorted, lrow_last, tidx_last=None):
     return _pad_lanes(jnp.concatenate(cols, axis=1))
 
 
-def _payload_padded_first(g_rows, perm, lrow_last, tidx_last):
+def _payload_padded_first(g_rows, perm, lrow_last, tidx_last, real=None):
     """``_payload(g_rows[perm], ...)`` bit for bit, in another order:
     the rows are squared and lane-padded BEFORE they are permuted, and
     the (already sorted) metadata lanes selected in afterwards.  At the
@@ -1503,20 +1598,27 @@ def _payload_padded_first(g_rows, perm, lrow_last, tidx_last):
     n = 640k, where this order read 1.5 ms slower (53.7 against 52.3 ms
     an apply), so that path keeps _payload (PERF.md §6, PR 27)."""
     return _padded_then_permuted(
-        [g_rows, g_rows * g_rows], perm, [lrow_last, tidx_last])
+        [g_rows, g_rows * g_rows], perm, [lrow_last, tidx_last], real)
 
 
-def _padded_then_permuted(cols, perm, meta):
-    """``[cols...[perm] | meta...]`` lane-padded, the padding done
-    before the permutation (see _payload_padded_first): ``cols`` the
-    unsorted column groups, ``meta`` the already sorted metadata columns
-    that follow them."""
+def _padded_then_permuted(cols, perm, meta, real=None):
+    """``[cols...[perm] | meta... | real[perm]]`` lane-padded, the
+    padding done before the permutation (see _payload_padded_first):
+    ``cols`` the unsorted column groups, ``meta`` the already sorted
+    metadata columns that follow them, ``real`` (unsorted, [n]) a row's
+    real occurrences (see _prep), selected into the padded rows as the
+    sorted columns are after it: a column more in the concatenation
+    has it laid out apart, 128 lanes a row."""
     n = cols[0].shape[0]
     width = sum(c.shape[1] for c in cols)
+    extra = len(meta) + (real is not None)
     p = _pad_lanes(jnp.concatenate(
-        cols + [jnp.zeros((n, len(meta)), cols[0].dtype)], axis=1,
-    ))[perm]
+        cols + [jnp.zeros((n, extra), cols[0].dtype)], axis=1,
+    ))
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, p.shape[1]), 1)
+    if real is not None:
+        p = jnp.where(lane == width + len(meta), real[:, None], p)
+    p = p[perm]
     for k, col in enumerate(meta):
         p = jnp.where(lane == width + k, col[:, None], p)
     return p
@@ -1536,13 +1638,25 @@ def _sorted_stream_meta(sidx):
     return upos, last, starts, firsts, ends
 
 
-def _prep(ids, g_rows, vocab, pad_first=False):
+def _prep(ids, g_rows, vocab, pad_first=False, real=None):
     """Sort, dedup-position, and chunk-boundary metadata (all XLA).
     ``pad_first`` picks _payload_padded_first's order of the same
-    payload."""
+    payload.
+
+    ``real`` (bool [n], with ``pad_first``): which occurrences are real
+    (``val != 0``).  The payload then carries it as a float32 column at
+    lane ``_real_lane(d)``, behind the metadata, and K1 sums it like
+    ``g``: a unique row's real occurrences, which the stream writer
+    turns into the step's row-touch mask.  A padding occurrence (``val
+    == 0``, most often on row 0) sorts and sums with the rest and still
+    reaches the writer; only this column tells it apart."""
     n = ids.shape[0]
     d = g_rows.shape[1]
     n_pad = -(-n // CHUNK) * CHUNK
+    if real is not None:
+        if not pad_first:
+            raise ValueError("the real-occurrence column needs pad_first")
+        real = real.astype(jnp.float32)
     if n_pad != n:
         # Sentinel occurrences: id = vocab sorts last, lands in no real
         # tile (tile_start covers rows < vocab), grads are zero anyway.
@@ -1552,6 +1666,8 @@ def _prep(ids, g_rows, vocab, pad_first=False):
         g_rows = jnp.concatenate(
             [g_rows, jnp.zeros((n_pad - n, d), g_rows.dtype)]
         )
+        if real is not None:
+            real = jnp.concatenate([real, jnp.zeros((n_pad - n,))])
     sidx, perm = jax.lax.sort_key_val(ids, jnp.arange(n_pad, dtype=jnp.int32))
     upos, last, starts, firsts, ends = _sorted_stream_meta(sidx)
     lrow = (sidx % TILE).astype(jnp.float32)  # tile-local row, exact < TILE
@@ -1560,7 +1676,8 @@ def _prep(ids, g_rows, vocab, pad_first=False):
     # K1 must carry it whole: two bf16 passes hold 17 bits of it.
     tidx = (sidx // TILE).astype(jnp.float32)
     if pad_first:
-        payload = _payload_padded_first(g_rows, perm, lrow * last, tidx * last)
+        payload = _payload_padded_first(
+            g_rows, perm, lrow * last, tidx * last, real)
     else:
         payload = _payload(g_rows[perm], lrow * last, tidx * last)
     return payload, upos, starts, firsts, ends, sidx, n_pad
@@ -1704,7 +1821,7 @@ def _sharded_call(update_fn, mesh, data_axis, model_axis, tables, ids,
                 update_fn if n_tables > 1
                 else (lambda g1, g2, *t: (update_fn(g1, g2, *t),))
             )
-            out, _, _ = entries_exchange_apply(
+            out, *_ = entries_exchange_apply(
                 upd, tables_l, lids, g_masked, vocab_local=vocab_local,
                 data_axis=data_axis, model_axis=model_axis,
                 data_shards=mesh.shape[data_axis], rows_all=rows_in,

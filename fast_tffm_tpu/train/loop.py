@@ -88,11 +88,15 @@ class HealthState(NamedTuple):
 
     Updated once per fused-scan step from gradients the step already
     materialized, so the marginal cost is a handful of reductions plus
-    one [B*F] -> [vocab] boolean scatter — noise next to the step.  The
-    host reads these OUTSIDE the hot path: a one-dispatch-delayed async
-    copy of the scalars drives ``nan_policy``, and the occupancy sums
-    are computed at logging cadence (never from the heartbeat thread,
-    which must stay host-only).
+    the row-touch plane: an elementwise OR of the mask the stream
+    writer made where the step holds it (train.sparse.touch_mask), else
+    a [B*F] -> [vocab] boolean scatter of the batch's ids (a sort of
+    the ids and a scatter of every occurrence: 19.8 ms of a 173.9 ms
+    step at B*F = 2.56 M on v5e, PERF.md section 5).  The host reads
+    these OUTSIDE the hot path: a one-dispatch-delayed async copy of the
+    scalars drives ``nan_policy``, and the occupancy sums are computed
+    at logging cadence (never from the heartbeat thread, which must
+    stay host-only).
     """
 
     grad_sq_last: jax.Array  # squared global grad norm, last step
@@ -110,7 +114,9 @@ class HealthState(NamedTuple):
     # capacity (zeros on every other apply path).  The host reads the
     # difference of two dispatches, which the wrap leaves exact.
     apply_rows: jax.Array
-    rows_touched: jax.Array  # bool[vocab]: rows ever touched this run
+    # bool[vocab]: rows ever touched by a real (val != 0) occurrence
+    # this run; P(model) on a mesh where the writer's mask feeds it
+    rows_touched: jax.Array
 
     @staticmethod
     def zeros(vocab: int) -> "HealthState":
@@ -123,6 +129,21 @@ class HealthState(NamedTuple):
             apply_rows=jnp.zeros((2,), jnp.uint32),
             rows_touched=jnp.zeros((vocab,), jnp.bool_),
         )
+
+
+def health_sharding(cfg: FmConfig, mesh) -> HealthState:
+    """Where the trainer places the health carry of the step compiled
+    for ``cfg`` on ``mesh``: replicated, but for a ``rows_touched`` fed
+    by the stream writer's mask on a mesh (train.sparse.touch_mask),
+    which lies as the mask comes out of the step — a model shard's own
+    rows on each chip (``P(model)``), replicated over data."""
+    rep = NamedSharding(mesh, P())
+    shardings = HealthState(**{f: rep for f in HealthState._fields})
+    if mesh.size > 1 and cfg.sparse_update and sparse_lib.touch_mask(
+            cfg, mesh):
+        shardings = shardings._replace(
+            rows_touched=NamedSharding(mesh, P(mesh_lib.MODEL_AXIS)))
+    return shardings
 
 
 @jax.named_scope("tffm.loss")
@@ -153,7 +174,7 @@ def _tree_grad_health(grads):
 def make_train_step(cfg: FmConfig, optimizer, with_health: bool = False):
     """Dense train step (optax): full-table optimizer update each step.
 
-    ``with_health=True`` returns ``(state, (grad_sq, nonfinite),
+    ``with_health=True`` returns ``(state, StepAux(grad_sq, nonfinite),
     scores)`` — the health aux the scan carry accumulates (the dense
     path reduces the full gradient pytree it already materialized) plus
     the step's raw scores, which the quality plane's scan wrapper can
@@ -183,7 +204,8 @@ def make_train_step(cfg: FmConfig, optimizer, with_health: bool = False):
         )
         new_state = TrainState(params, opt_state, ms, state.step + 1)
         if with_health:
-            return new_state, _tree_grad_health(grads), aux["scores"]
+            return (new_state, sparse_lib.StepAux(*_tree_grad_health(grads)),
+                    aux["scores"])
         return new_state
 
     return step
@@ -244,22 +266,31 @@ def make_sparse_train_step(cfg: FmConfig, mesh=None,
 
 def make_health_update(cfg: FmConfig):
     """(health, new_state, batch, aux) -> health, applied once per scan
-    step: fold the step's grad aux into the carry and mark the batch's
-    real (val != 0) ids in the row-touch mask.  Padded occurrences map
-    to index ``vocab`` and drop out of the scatter."""
+    step: fold the step's grad aux (a train.sparse.StepAux) into the
+    carry and mark the batch's real (val != 0) ids in the row-touch
+    plane — by OR-ing in the stream writer's mask where the step made
+    one (``aux.touched``: the same rows), else by a scatter of the ids,
+    where padded occurrences map to index ``vocab`` and drop out."""
     vocab = cfg.vocabulary_size
 
     @jax.named_scope("tffm.health")
     def update(health: HealthState, new_state: TrainState, batch: Batch,
                aux) -> HealthState:
-        grad_sq, nonfinite, *applied = aux
-        bad = nonfinite > 0
+        bad = aux.nonfinite > 0
         real = batch.vals.reshape(-1) != 0
-        ids = jnp.where(real, batch.ids.reshape(-1), vocab)
+        if aux.touched is not None:
+            rows_touched = health.rows_touched | aux.touched
+        else:
+            ids = jnp.where(real, batch.ids.reshape(-1), vocab)
+            rows_touched = health.rows_touched.at[ids].set(
+                True, mode="drop")
+        apply_rows = health.apply_rows
+        if aux.apply_rows is not None:
+            apply_rows = apply_rows + aux.apply_rows
         this_step = new_state.step - 1  # the step this batch trained
         return HealthState(
-            grad_sq_last=grad_sq,
-            grad_sq_sum=health.grad_sq_sum + grad_sq,
+            grad_sq_last=aux.grad_sq,
+            grad_sq_sum=health.grad_sq_sum + aux.grad_sq,
             nonfinite_steps=(
                 health.nonfinite_steps + bad.astype(jnp.int32)
             ),
@@ -271,10 +302,8 @@ def make_health_update(cfg: FmConfig):
             touch_events=(
                 health.touch_events + jnp.sum(real, dtype=jnp.float32)
             ),
-            apply_rows=health.apply_rows + sum(applied),
-            rows_touched=health.rows_touched.at[ids].set(
-                True, mode="drop"
-            ),
+            apply_rows=apply_rows,
+            rows_touched=rows_touched,
         )
 
     return update
@@ -691,7 +720,8 @@ class Trainer:
             else make_train_step(dcfg, self.optimizer, with_health=True)
         )
         self._health = jax.device_put(
-            HealthState.zeros(dcfg.vocabulary_size), rep
+            HealthState.zeros(dcfg.vocabulary_size),
+            health_sharding(dcfg, self.mesh),
         )
         self._health_host: dict = {}  # last host-read health scalars
         self._health_step0 = int(self.state.step)  # run-start step base
@@ -1352,9 +1382,9 @@ class Trainer:
         pinned here: health reporting divides by PER-RUN steps and
         rebases ``first_nonfinite_step`` to match the per-run ``step``
         every other record carries."""
-        rep = NamedSharding(self.mesh, P())
         self._health = jax.device_put(
-            HealthState.zeros(self._dcfg.vocabulary_size), rep
+            HealthState.zeros(self._dcfg.vocabulary_size),
+            health_sharding(self._dcfg, self.mesh),
         )
         self._health_step0 = int(self.state.step)
 
@@ -1778,6 +1808,11 @@ class Trainer:
         # another apply mode altogether)
         self.telemetry.gauge("train.apply_stream").set(int(
             self.sparse and sparse_lib.apply_stream(self._dcfg, self.mesh)))
+        # where the health carry's rows_touched comes from: 1 = the
+        # stream writer's row-touch mask, OR-ed in; 0 = a scatter of
+        # the batch's ids
+        self.telemetry.gauge("train.touch_mask").set(int(
+            self.sparse and sparse_lib.touch_mask(self._dcfg, self.mesh)))
         # the exchange over the data axis the compiled step holds:
         # 1 = entries (touched rows all-gathered), 0 = dense (a whole
         # shard's delta psum'd); absent where the step exchanges nothing

@@ -50,6 +50,7 @@ from fast_tffm_tpu.train.sparse import (
     ADAGRAD_EPS,
     SparseAdagradState,
     SparseFtrlState,
+    StepAux,
     exchange_slots,
     resolve_exchange,
 )
@@ -105,13 +106,17 @@ def _rebuild_opt(cfg: FmConfig, opt_state, new_tables, dw0, w0_old):
 def sparse_step_shardmap(cfg: FmConfig, params, opt_state, batch: Batch,
                          mesh, health: bool = False):
     """One sparse train step, hand-sharded. Returns (params, opt, scores),
-    plus a ``(grad_sq, nonfinite_count)`` health aux when ``health=True``
-    — each quantity reduced locally from the shard's own (masked)
-    occurrence grads and psum'd over BOTH mesh axes, so the monitor is
-    global at the cost of two extra scalar collectives per step.  Under
-    the entries exchange the aux ends in ``[merged real entries, slots
-    merged for them]`` summed over the model shards (gauges
-    ``train.exchange_fill``, ``train.exchange_compact``)."""
+    plus a :class:`StepAux` when ``health=True``: ``(grad_sq,
+    nonfinite_count)``, each reduced locally from the shard's own
+    (masked) occurrence grads and psum'd over BOTH mesh axes, so the
+    monitor is global at the cost of two extra scalar collectives per
+    step.  Under the entries exchange it adds ``[merged real entries,
+    slots merged for them]`` summed over the model shards (gauges
+    ``train.exchange_fill``, ``train.exchange_compact``), and where the
+    exchange ends in the stream writer the row-touch mask the writer
+    made of every data shard's real occurrences: bool [vocab] out of
+    the shard_map as ``P(model)``, a model shard's own rows (every data
+    replica of it merged the same stream)."""
     model_shards = mesh.shape[MODEL_AXIS]
     data_shards = mesh.shape[DATA_AXIS]
     vocab_local = cfg.vocabulary_size // model_shards
@@ -119,6 +124,8 @@ def sparse_step_shardmap(cfg: FmConfig, params, opt_state, batch: Batch,
     n_opt = len(_opt_tables(cfg, opt_state))
     b_local = batch.vals.shape[0] // data_shards
     exchange = resolve_exchange(cfg, mesh, b_local * batch.vals.shape[1])
+    touch = (health and exchange == "entries"
+             and sparse_apply.exchange_takes_stream(data_shards))
 
     cd = cfg.compute_jnp_dtype
 
@@ -271,9 +278,10 @@ def sparse_step_shardmap(cfg: FmConfig, params, opt_state, batch: Batch,
             # IndexedSlices scaling property.  (ids_flat is already
             # local-coordinate with off-shard -> sentinel, the helper's
             # contract; drows already masked.)
-            w_new, new_tables, exchanged = _apply_stream(
+            w_new, new_tables, exchanged, touched = _apply_stream(
                 cfg, ids_flat.astype(jnp.int32), g_flat, table_l,
                 opt_tables_l, data_shards=data_shards,
+                real=(vals != 0).reshape(b * f) if touch else None,
             )
         else:
             delta = sparse_apply.dense_delta(
@@ -312,6 +320,8 @@ def sparse_step_shardmap(cfg: FmConfig, params, opt_state, batch: Batch,
                     # every data replica of a model shard holds the same
                     # merged stream: count a shard's once
                     outs = outs + (jax.lax.psum(exchanged, MODEL_AXIS),)
+            if touch:
+                outs = outs + (touched,)
         return outs
 
     out_specs = (
@@ -319,6 +329,7 @@ def sparse_step_shardmap(cfg: FmConfig, params, opt_state, batch: Batch,
         + (P(MODEL_AXIS, None),) * n_opt
         + ((P(), P()) if health else ())
         + ((P(),) if health and exchange == "entries" else ())
+        + ((P(MODEL_AXIS),) if touch else ())
     )
     from jax import shard_map
 
@@ -347,7 +358,7 @@ def sparse_step_shardmap(cfg: FmConfig, params, opt_state, batch: Batch,
         with jax.named_scope("tffm.health"):
             grad_sq = gsq + jnp.square(dw0)
             nonfin = nonfin + (~jnp.isfinite(dw0)).astype(jnp.int32)
-        aux = (grad_sq, nonfin)
+        aux = StepAux(grad_sq, nonfin, touched=outs[-1] if touch else None)
         if exchange == "entries":
             # the host solves train.exchange_compact from the two
             # figures exchange_slots derives from cfg; a step traced at
@@ -357,7 +368,8 @@ def sparse_step_shardmap(cfg: FmConfig, params, opt_state, batch: Batch,
             rung = sparse_apply.entries_rung(cap, data_shards)
             assert exchange_slots(cfg, mesh) == (
                 rung and mesh.size * rung, mesh.size * cap), (cap, rung)
-            aux += (outs[5 + n_opt].astype(jnp.uint32),)
+            aux = aux._replace(
+                apply_rows=outs[5 + n_opt].astype(jnp.uint32))
         return new_params, opt_new, scores, aux
     return new_params, opt_new, scores
 
@@ -395,13 +407,15 @@ def make_exchange_probe(mesh):
     return probe
 
 
-def _apply_stream(cfg, lids, g_flat, w_l, opt_tables_l, *, data_shards):
+def _apply_stream(cfg, lids, g_flat, w_l, opt_tables_l, *, data_shards,
+                  real=None):
     """The entries exchange and the optimizer update on top of it
     (sparse_apply.entries_exchange_apply): same formulas as
     _apply_delta, run inside the stream writer (or the K2 tile kernel)
     over the shard's own tables — rows no data shard touched keep their
     bits.  Returns ``(table, opt tables, [merged real entries, slots
-    merged for them])``."""
+    merged for them], touched)``: the shard's row-touch mask where
+    ``real`` is given and the writer runs, else None."""
     lr = cfg.learning_rate
     if cfg.optimizer == "adagrad":
         upd = functools.partial(
@@ -414,12 +428,14 @@ def _apply_stream(cfg, lids, g_flat, w_l, opt_tables_l, *, data_shards):
         )
     else:
         upd = functools.partial(sparse_apply.sgd_update, lr=lr)
-    (w_new, *opt_new), merged, slots = sparse_apply.entries_exchange_apply(
-        upd, (w_l,) + tuple(opt_tables_l), lids, g_flat,
-        vocab_local=w_l.shape[0], data_axis=DATA_AXIS,
-        model_axis=MODEL_AXIS, data_shards=data_shards,
+    (w_new, *opt_new), merged, slots, touched = (
+        sparse_apply.entries_exchange_apply(
+            upd, (w_l,) + tuple(opt_tables_l), lids, g_flat,
+            vocab_local=w_l.shape[0], data_axis=DATA_AXIS,
+            model_axis=MODEL_AXIS, data_shards=data_shards, real=real,
+        )
     )
-    return w_new, tuple(opt_new), jnp.stack([merged, slots])
+    return w_new, tuple(opt_new), jnp.stack([merged, slots]), touched
 
 
 def _apply_delta(cfg, g1, g2, w_l, opt_tables_l):

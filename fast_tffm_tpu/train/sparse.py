@@ -169,6 +169,34 @@ def apply_stream(cfg: FmConfig, mesh=None) -> bool:
     )
 
 
+def touch_mask(cfg: FmConfig, mesh=None) -> bool:
+    """Whether the step compiled for ``cfg`` on ``mesh`` takes the health
+    carry's ``rows_touched`` from the stream writer's row-touch mask
+    (gauge ``train.touch_mask``) instead of scattering the batch's ids:
+    wherever it holds the writer (apply_stream), but for the GSPMD
+    'sharded' apply, whose shard_map returns the tables alone.  On a
+    mesh the mask, and so the carry's plane, is a model shard's own
+    rows: ``P(model)``."""
+    if not apply_stream(cfg, mesh):
+        return False
+    return mesh is None or mesh.size == 1 or cfg.lookup == "shardmap"
+
+
+class StepAux(NamedTuple):
+    """What a train step hands the health carry (train.loop's
+    make_health_update), beside its state and scores."""
+
+    grad_sq: jax.Array  # squared global grad norm of the step
+    nonfinite: jax.Array  # int32: non-finite grad elements
+    # uint32[2]: (rows written, occurrences merged) of the one-device
+    # scatter apply; (merged real entries, slots merged) of the
+    # hand-sharded entries exchange; None on every other path
+    apply_rows: jax.Array | None = None
+    # bool [vocab]: the rows this step's real occurrences touched, from
+    # the stream writer (touch_mask); None where the carry scatters ids
+    touched: jax.Array | None = None
+
+
 class SparseAdagradState(NamedTuple):
     acc: fm.FmParams  # per-weight squared-gradient accumulators
 
@@ -287,12 +315,13 @@ def overlap_active(cfg: FmConfig, mesh=None) -> bool:
 
 
 def _apply_adagrad(cfg, params, opt, ids, g_rows, dw0, w_rows,
-                   mode="scatter", mesh=None, meta=None, rows_all=None):
+                   mode="scatter", mesh=None, meta=None, rows_all=None,
+                   real=None):
     del w_rows  # adagrad needs no pre-update weights
     # Same formula as optax.scale_by_rss: u = g * rsqrt(acc_new + eps),
     # so sparse and dense paths agree exactly on duplicate-free batches.
     lr = cfg.learning_rate
-    unique = None
+    unique = touched = None
     if mode == "sharded":
         table, acc_table = sparse_apply.adagrad_apply_sharded(
             params.table, opt.acc.table, ids, g_rows,
@@ -308,9 +337,12 @@ def _apply_adagrad(cfg, params, opt, ids, g_rows, dw0, w_rows,
             lr=lr, eps=ADAGRAD_EPS, meta=meta,
         )
     elif mode == "unique":
-        (table, acc_table), unique = sparse_apply.scatter_apply_unique(
-            partial(sparse_apply.adagrad_update, lr=lr, eps=ADAGRAD_EPS),
-            (params.table, opt.acc.table), ids, g_rows, additive=True,
+        (table, acc_table), unique, touched = (
+            sparse_apply.scatter_apply_unique(
+                partial(sparse_apply.adagrad_update, lr=lr, eps=ADAGRAD_EPS),
+                (params.table, opt.acc.table), ids, g_rows, additive=True,
+                real=real,
+            )
         )
     else:
         acc_table = opt.acc.table.at[ids].add(g_rows * g_rows)
@@ -323,7 +355,7 @@ def _apply_adagrad(cfg, params, opt, ids, g_rows, dw0, w_rows,
     return (
         fm.FmParams(w0=w0, table=table),
         SparseAdagradState(acc=fm.FmParams(w0=acc_w0, table=acc_table)),
-        unique,
+        unique, touched,
     )
 
 
@@ -332,11 +364,12 @@ _ftrl_solve = sparse_apply.ftrl_solve
 
 
 def _apply_ftrl(cfg, params, opt, ids, g_rows, dw0, w_rows,
-                mode="scatter", mesh=None, meta=None, rows_all=None):
+                mode="scatter", mesh=None, meta=None, rows_all=None,
+                real=None):
     lr, l1, l2, beta = (
         cfg.learning_rate, cfg.ftrl_l1, cfg.ftrl_l2, cfg.ftrl_beta,
     )
-    unique = None
+    unique = touched = None
     if mode == "sharded":
         table, z_table, n_table = sparse_apply.ftrl_apply_sharded(
             params.table, opt.z.table, opt.n.table, ids, g_rows,
@@ -355,11 +388,12 @@ def _apply_ftrl(cfg, params, opt, ids, g_rows, dw0, w_rows,
         # Unique rows: ftrl_update's single -sigma*w per row is the whole
         # duplicate-id care (see the GSPMD branch below for what it
         # takes per occurrence).
-        (table, z_table, n_table), unique = (
+        (table, z_table, n_table), unique, touched = (
             sparse_apply.scatter_apply_unique(
                 partial(sparse_apply.ftrl_update,
                         lr=lr, l1=l1, l2=l2, beta=beta),
                 (params.table, opt.z.table, opt.n.table), ids, g_rows,
+                real=real,
             )
         )
     else:
@@ -395,15 +429,16 @@ def _apply_ftrl(cfg, params, opt, ids, g_rows, dw0, w_rows,
             z=fm.FmParams(w0=z0, table=z_table),
             n=fm.FmParams(w0=n0_new, table=n_table),
         ),
-        unique,
+        unique, touched,
     )
 
 
 def _apply_sgd(cfg, params, opt, ids, g_rows, dw0, w_rows,
-               mode="scatter", mesh=None, meta=None, rows_all=None):
+               mode="scatter", mesh=None, meta=None, rows_all=None,
+               real=None):
     del w_rows
     lr = cfg.learning_rate
-    unique = None
+    unique = touched = None
     if mode == "sharded":
         table = sparse_apply.sgd_apply_sharded(
             params.table, ids, g_rows, lr=lr, mesh=mesh,
@@ -416,13 +451,14 @@ def _apply_sgd(cfg, params, opt, ids, g_rows, dw0, w_rows,
         table = sparse_apply.sgd_apply(
             params.table, ids, g_rows, lr=lr, meta=meta)
     elif mode == "unique":
-        (table,), unique = sparse_apply.scatter_apply_unique(
+        (table,), unique, touched = sparse_apply.scatter_apply_unique(
             partial(sparse_apply.sgd_update, lr=lr),
-            (params.table,), ids, g_rows, additive=True,
+            (params.table,), ids, g_rows, additive=True, real=real,
         )
     else:
         table = params.table.at[ids].add(-lr * g_rows)
-    return fm.FmParams(w0=params.w0 - lr * dw0, table=table), opt, unique
+    return (fm.FmParams(w0=params.w0 - lr * dw0, table=table), opt, unique,
+            touched)
 
 
 _APPLY = {"adagrad": _apply_adagrad, "ftrl": _apply_ftrl, "sgd": _apply_sgd}
@@ -491,10 +527,12 @@ def sparse_step(
     rows_all=None,
 ):
     """One sparse train step. Returns (params, opt_state, scores), plus
-    a ``(grad_sq, nonfinite_count)`` health aux when ``health=True``
-    (computed from the per-occurrence row grads this step already
-    materialized — no extra memory traffic); the single-device scatter
-    apply appends its ``[unique rows written, occurrences]`` pair.
+    a :class:`StepAux` when ``health=True``: ``(grad_sq,
+    nonfinite_count)`` computed from the per-occurrence row grads this
+    step already materialized — no extra memory traffic; the
+    single-device scatter apply adds its ``[unique rows written,
+    occurrences]`` pair, and where it holds the stream writer the
+    step's row-touch mask.
 
     ``rows_all`` is the prefetched entries-exchange id plane (see
     ops.sparse_apply.make_entries_prefetch) — only legal on the sharded
@@ -522,17 +560,22 @@ def sparse_step(
         # One device: dedup first, write each touched row once.  The
         # GSPMD scatter of a multi-device mesh stays per occurrence.
         mode = "unique"
-    params, opt_state, unique = _APPLY[cfg.optimizer](
+    real = None
+    if health and mode == "unique":
+        # the health carry's rows_touched counts real occurrences only
+        real = batch.vals.reshape(b * f) != 0
+    params, opt_state, unique, touched = _APPLY[cfg.optimizer](
         cfg, params, opt_state, ids, g_rows, dw0, rows.reshape(b * f, d),
         mode=mode, mesh=mesh,
         meta=batch.sort_meta if mode == "tile" else None,
-        rows_all=rows_all,
+        rows_all=rows_all, real=real,
     )
     if health:
-        aux = grad_health(g_rows, dw0)
+        aux = StepAux(*grad_health(g_rows, dw0), touched=touched)
         if unique is not None:
             # (rows written, occurrences they were merged from): what
             # train.apply_unique_frac is the ratio of.
-            aux += (jnp.stack([unique, b * f]).astype(jnp.uint32),)
+            aux = aux._replace(apply_rows=jnp.stack(
+                [unique, b * f]).astype(jnp.uint32))
         return params, opt_state, scores, aux
     return params, opt_state, scores

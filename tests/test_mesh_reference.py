@@ -316,7 +316,8 @@ def test_merged_stream_is_k2_from_merge_entries_bit_for_bit(optimizer):
         return sparse_apply.merged_stream_apply(update, tables, rows, pay)
 
     want, n_want = by_k2(rows, pay, *tables)
-    got, n_got = by_stream(rows, pay, *tables)
+    got, n_got, touched = by_stream(rows, pay, *tables)
+    assert touched is None  # no mask asked for
     real = np.unique(np.asarray(rows)[np.asarray(rows) < vocab])
     assert int(n_got) == int(n_want) == len(real)
     hit = np.zeros(vocab, bool)

@@ -249,6 +249,79 @@ def test_stream_apply_holds_no_table_copy_and_no_loop(
     assert mem.temp_size_in_bytes < 64 << 20, mem  # a table is >= 2 GiB
 
 
+@pytest.mark.parametrize("d,vocab", [(D, 1 << 25), (D_FFM, 1 << 22)])
+def test_stream_writer_makes_the_touch_mask_lane_dense(
+        one_chip, no_persistent_cache, d, vocab):
+    """The writer asked for the step's row-touch mask, at the two train
+    cells' rows: the real-occurrence count rides in the payload's first
+    free lane (no K1 call more), and the mask leaves the writer as int32
+    rows of 128 lanes, a block of the writer a [1, block / 128, 128]
+    slab (32 rows at D = 9, 8 at D = 157), with the tables still in
+    place."""
+    n = 16 * sparse_apply.CHUNK
+    group = sparse_apply._stream_group(vocab // sparse_apply.TILE, d, 2)
+    block = sparse_apply.TILE * group
+    compiled = compile_for(
+        one_chip,
+        lambda i, gr, r, *t: sparse_apply.scatter_apply_unique(
+            functools.partial(sparse_apply.adagrad_update, lr=0.1, eps=1e-7),
+            t, i, gr, additive=True, stream=True, real=r),
+        _s((n,), jnp.int32), _s((n, d)), _s((n,), jnp.bool_),
+        *[_s((vocab, d))] * 2, donate_argnums=(3, 4),
+    )
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == -(-(2 * d + 3) // 128) + 1
+    writer = next(ln for ln in text.splitlines()
+                  if "custom-call(" in ln and "tffm.apply_write" in ln)
+    assert f"s32[{vocab // block},{block // 128},128]" in writer, writer
+    for k in range(2):
+        assert f"{{{k}}}: ({1 + k}, {{}})" in writer, writer
+
+
+def test_one_chip_step_at_the_shard_cell_shape_takes_the_writers_mask(
+        topo, no_persistent_cache):
+    """The program ``criteo1tb-train-shard`` dispatches — the scan-fused
+    one-device scatter apply with the health carry at V = 2^25, B =
+    65,536 — compiled for one described chip: the step holds the stream
+    writer, and the health carry's row-touch plane is the writer's mask
+    OR-ed in (no sort and no scatter of the batch's 2.56 M ids)."""
+    import re
+
+    from fast_tffm_tpu.config import load_config
+    from fast_tffm_tpu.parallel import mesh as mesh_lib
+    from fast_tffm_tpu.train import loop, sparse as sparse_lib
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = dataclasses.replace(  # benchmarks/configs/criteo1tb-fm-shard2.json
+        load_config(os.path.join(repo, "examples", "criteo_1tb_dist.cfg")),
+        vocabulary_size=1 << 25, batch_size=65536, mesh_data=1,
+        mesh_model=1, sparse_apply="scatter",
+    )
+    mesh = Mesh(
+        np.array(topo.devices[:1]).reshape(1, 1),
+        (mesh_lib.DATA_AXIS, mesh_lib.MODEL_AXIS),
+    )
+    state, health, batches = _trainer_shapes(cfg, mesh)
+    step = loop.make_scan_train_step(
+        loop.make_sparse_train_step(cfg, mesh, with_health=True),
+        loop.make_health_update(cfg),
+    )
+    with pf.force_compiled():
+        assert sparse_lib.touch_mask(cfg, mesh)
+        compiled = jax.jit(step, donate_argnums=0).lower(
+            state, health, batches).compile()
+    text = compiled.as_text()
+    health_ops = [ln for ln in text.splitlines()
+                  if re.search(r" (sort|scatter)\(", ln)
+                  and "tffm.health" in ln]
+    assert not health_ops, health_ops
+    assert re.search(rf"= pred\[{1 << 25}\]\S* or\(.*tffm.health", text)
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert used < 15.75 * 2**30, f"{used / 2**30:.2f} GiB of 15.75"
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_field_aware_interaction_at_libffm_criteo_shape(
         one_chip, no_persistent_cache, dtype):
@@ -275,8 +348,9 @@ def test_field_aware_interaction_at_libffm_criteo_shape(
 def _trainer_shapes(cfg, mesh):
     """``(state, health, batches)`` of one scan dispatch of one step, as
     the Trainer places them on ``mesh`` (tables row-sharded over the
-    model axis, the health carry replicated, the super-batch over the
-    data axis): shapes with shardings, nothing allocated."""
+    model axis, the health carry as loop.health_sharding has it, the
+    super-batch over the data axis): shapes with shardings, nothing
+    allocated."""
     from fast_tffm_tpu.data.libsvm import Batch
     from fast_tffm_tpu.models import fm
     from fast_tffm_tpu.parallel import mesh as mesh_lib
@@ -301,8 +375,12 @@ def _trainer_shapes(cfg, mesh):
         metrics=jax.eval_shape(loop.MetricState.zeros),
         step=_s((), jnp.int32),
     ), table_sh)
-    health = placed(jax.eval_shape(
-        lambda: loop.HealthState.zeros(cfg.vocabulary_size)), rep)
+    with pf.force_compiled():  # the rule as the chip's compiler sees it
+        health_sh = loop.health_sharding(cfg, mesh)
+    health = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        jax.eval_shape(lambda: loop.HealthState.zeros(cfg.vocabulary_size)),
+        health_sh)
     b, f = cfg.batch_size, cfg.max_features
     batches = jax.tree.map(
         lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
@@ -366,8 +444,10 @@ def test_mesh_step_at_the_2x2_cell_shape_holds_the_rung(
     all-gathered ahead of it, ``entries_cap`` in its full branch), the
     rung's all-gathers keep the name the benchmark's reader of
     collective time knows them by, the two tables of a shard pass
-    through the conditional with no table-sized copy, and a chip's
-    program fits its 15.75 GiB."""
+    through the conditional with no table-sized copy, the health
+    carry's row-touch plane is the writer's mask OR-ed in on a chip's
+    own rows (no sort, no scatter, no all-gather of the ids), and a
+    chip's program fits its 15.75 GiB."""
     import re
 
     from fast_tffm_tpu.config import load_config
@@ -398,19 +478,20 @@ def test_mesh_step_at_the_2x2_cell_shape_holds_the_rung(
     rung = sparse_apply.entries_rung(cap, 2)
     assert (cap, rung) == (2_555_904, 638_976)
     assert re.search(r"\bconditional\(", text)
-    # each branch gathers the rows and the sums of its own length
+    # each branch gathers the rows and the sums of its own length: sum
+    # g | sum g^2 | the row's real occurrences (for the row-touch mask)
     for slots in (rung, cap):
         assert re.search(rf"= s32\[{2 * slots}\]\S* all-gather", text), slots
         assert re.search(
-            rf"= f32\[{2 * slots},18\]\S* all-gather", text), slots
+            rf"= f32\[{2 * slots},19\]\S* all-gather", text), slots
     # what every step of the cell moves between chips is an operation
     # named all-gather.N (benchmarks/fmbench/xplane_collectives.py takes
     # a trace event for a collective by that prefix), not part of an
     # async-collective fusion as it was inside a branch
     assert re.search(
-        rf"%all-gather[.\d]* = f32\[{2 * rung},18\]\S* all-gather\(", text)
+        rf"%all-gather[.\d]* = f32\[{2 * rung},19\]\S* all-gather\(", text)
     assert not re.search(
-        rf"%async-collective-start[.\d]* = \([^)]*\[{2 * rung},18\]", text)
+        rf"%async-collective-start[.\d]* = \([^)]*\[{2 * rung},19\]", text)
     # K1 of the first stage; K1 and the writer of each branch
     assert text.count("tpu_custom_call") == 5
     vl, d = cfg.vocabulary_size // 2, cfg.embedding_dim
@@ -418,6 +499,11 @@ def test_mesh_step_at_the_2x2_cell_shape_holds_the_rung(
     moved = [ln.strip()[:160] for ln in text.splitlines()
              if re.search(rf"= {table}\S* (copy|copy-start|transpose)\(", ln)]
     assert not moved, moved
+    health = [ln for ln in text.splitlines()
+              if re.search(r" (sort|scatter|all-gather)\(", ln)
+              and "tffm.health" in ln]
+    assert not health, health
+    assert re.search(rf"= pred\[{vl}\]\S* or\(.*tffm.health", text)
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
