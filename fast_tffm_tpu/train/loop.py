@@ -431,6 +431,13 @@ def make_eval_step(cfg: FmConfig):
     return step
 
 
+@jax.jit
+def _zero_share(table: jax.Array) -> jax.Array:
+    """Share of a table's coordinates at exactly 0: where FTRL's L1
+    holds a weight (one reduction over the table, no copy of it)."""
+    return jnp.mean(table == 0, dtype=jnp.float32)
+
+
 def _finalize_metrics(ms: MetricState, loss_type: str = "logistic") -> dict:
     """Streaming means. The loss key is "logloss" for logistic training and
     "mse" for mse training (plus a loss_type-agnostic "loss" alias).
@@ -1421,6 +1428,14 @@ class Trainer:
                     "emb_row_occupancy": round(rows / vocab, 6),
                     "emb_touch_events": float(h.touch_events),
                 })
+                if self.cfg.optimizer == "ftrl" and self.tiered is None:
+                    # what a user of an L1-sparse model watches (gauge
+                    # train.ftrl_zero_frac); Adagrad runs no such pass,
+                    # nor a tiered table, whose device table is the hot
+                    # slots and not the logical one
+                    zero = float(_zero_share(self.state.params.table))
+                    out["ftrl_zero_frac"] = zero
+                    self.telemetry.gauge("train.ftrl_zero_frac").set(zero)
                 if self.tiered is not None:
                     # The scan-carry mask counts HOT SLOTS under
                     # tiering; the manager sees every logical id

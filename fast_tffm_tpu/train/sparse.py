@@ -363,6 +363,11 @@ def _apply_adagrad(cfg, params, opt, ids, g_rows, dw0, w_rows,
 _ftrl_solve = sparse_apply.ftrl_solve
 
 
+# Every operation of FTRL's update carries a scope: the apply's own prep,
+# writer and exchange keep theirs (ops.sparse_apply), and what is FTRL's
+# alone around them -- the per-occurrence rows branch, the solve of w0 --
+# is the write of the optimizer's new values.
+@jax.named_scope("tffm.apply_write")
 def _apply_ftrl(cfg, params, opt, ids, g_rows, dw0, w_rows,
                 mode="scatter", mesh=None, meta=None, rows_all=None,
                 real=None):

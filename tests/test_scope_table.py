@@ -166,6 +166,9 @@ ONE_DEVICE = {"tffm.row_gather", "tffm.loss", "tffm.apply_prep",
 CASES = {
     "fm_scatter": (dict(), ONE_DEVICE | {
         "tffm.fm_interaction_fwd", "tffm.fm_interaction_bwd"}),
+    "ftrl_scatter": (dict(optimizer="ftrl", ftrl_l1=1e-3, ftrl_l2=1e-3),
+                     ONE_DEVICE | {"tffm.fm_interaction_fwd",
+                                   "tffm.fm_interaction_bwd"}),
     "ffm": (dict(field_num=4), ONE_DEVICE | {
         "tffm.ffm_interaction_fwd", "tffm.ffm_interaction_bwd"}),
     "shardmap_2x2": (
@@ -219,6 +222,41 @@ def test_the_real_step_is_named_and_recorded(case, tmp_path, monkeypatch):
     for scopes in rec["mixed"].values():
         assert len(scopes) > 1 and set(scopes) <= set(
             expected) | {"tffm.apply_prep"}
+
+
+@pytest.mark.parametrize("writer", ["stream", "scatter_loop"])
+def test_ftrl_arithmetic_is_scoped_wherever_it_lands(monkeypatch, writer):
+    """FTRL's own operations -- the square roots of n, the sign and the
+    absolute value of z, the closed form's division -- carry a scope in
+    the compiled one-device step, on the table's rows and on w0, in
+    either writer and whether or not the compiler fused them with
+    another part of the step."""
+    import re
+    from functools import partial
+
+    from fast_tffm_tpu.data.libsvm import Batch
+    from fast_tffm_tpu.models import fm
+    from fast_tffm_tpu.train import sparse as sparse_lib
+
+    monkeypatch.setattr(sparse_apply, "takes_stream",
+                        lambda *a: writer == "stream")
+    cfg = FmConfig(vocabulary_size=2048, factor_num=4, max_features=8,
+                   batch_size=64, optimizer="ftrl", ftrl_l1=1e-3,
+                   ftrl_l2=1e-3, sparse_update=True, sparse_apply="scatter")
+    params = fm.init_params(jax.random.PRNGKey(0), cfg)
+    opt = sparse_lib.init_sparse_opt_state(cfg, params)
+    b, f = cfg.batch_size, cfg.max_features
+    batch = Batch(labels=jnp.zeros((b,)), ids=jnp.zeros((b, f), jnp.int32),
+                  vals=jnp.ones((b, f)), fields=jnp.zeros((b, f), jnp.int32),
+                  weights=jnp.ones((b,)))
+    text = jax.jit(partial(sparse_lib.sparse_step, cfg, health=True)).lower(
+        params, opt, batch).compile().as_text()
+    ops = re.findall(r"= \S+ (sqrt|sign|abs|divide)\(.*?op_name=\"([^\"]*)\"",
+                     text)
+    assert {op for op, _ in ops} >= {"sqrt", "sign", "abs", "divide"}
+    unscoped = [(op, name) for op, name in ops
+                if not scopes_lib._SCOPE.search(name)]
+    assert not unscoped, unscoped
 
 
 def _scoped(scope):

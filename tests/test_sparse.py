@@ -167,16 +167,27 @@ def test_sparse_ftrl_matches_dense_on_unique_ids(tmp_path):
     )
 
 
-def test_sparse_ftrl_stable_under_heavy_duplicates(tmp_path):
+@pytest.mark.parametrize("devices", [1, 8])
+def test_sparse_ftrl_stable_under_heavy_duplicates(tmp_path, devices):
     """Regression: per-occurrence -sigma*w scatter double-counted duplicate
-    rows and diverged to NaN within a few hundred steps."""
+    rows and diverged to NaN within a few hundred steps.  On one device
+    the unique-row apply (the path of the benchmark's FTRL cell); on the
+    8-device data mesh GSPMD's per-occurrence scatter.
+
+    Each step is waited for before the next is dispatched.  With steps in
+    flight, XLA:CPU's in-process all-reduce of one step could hold the
+    client's threads while a device's program of the same step found
+    none: seven of eight participants waited, and after 40 s XLA ended
+    the process (under a loaded CPU, about one run in three)."""
     rng = np.random.default_rng(5)
     cfg = _cfg(tmp_path, "fdup", optimizer="ftrl", learning_rate=0.5,
-               vocabulary_size=50)  # tiny vocab -> heavy duplicates
-    t = Trainer(cfg)
+               vocabulary_size=50,  # tiny vocab -> heavy duplicates
+               sparse_apply="scatter")
+    t = Trainer(cfg, mesh=mesh_lib.make_mesh(cfg, jax.devices()[:devices]))
+    assert t.mesh.size == devices
     for _ in range(150):
         b = _dup_batch(rng, cfg, cfg.batch_size)
-        t.state = t._train_step(t.state, t._put(b))
+        t.state = jax.block_until_ready(t._train_step(t.state, t._put(b)))
     table = np.asarray(t.state.params.table)
     assert np.all(np.isfinite(table))
     assert np.abs(table).max() < 10.0

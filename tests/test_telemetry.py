@@ -415,6 +415,44 @@ class TestTrainerTelemetry:
         else:
             assert "train.apply_unique_frac" not in gauges
 
+    @pytest.mark.parametrize("optimizer,tiering", [
+        ("ftrl", "off"), ("adagrad", "off"), ("ftrl", "on"),
+    ])
+    def test_ftrl_zero_frac_at_log_steps(
+        self, optimizer, tiering, train_file, tmp_path
+    ):
+        """FTRL's L1 share: the gauge ``train.ftrl_zero_frac`` and the
+        health summary's ``ftrl_zero_frac`` read the share of the
+        table's coordinates at exactly 0, made at a log step; Adagrad
+        runs no such pass and publishes neither, nor does a tiered
+        table (its device table holds hot slots, not the logical
+        rows)."""
+        import jax
+
+        from fast_tffm_tpu.parallel import mesh as mesh_lib
+        from fast_tffm_tpu.train.loop import Trainer
+
+        mf = str(tmp_path / "metrics.jsonl")
+        cfg = _train_cfg(
+            train_file, tmp_path, f"z{optimizer}{tiering}", metrics_file=mf,
+            sparse_apply="scatter", optimizer=optimizer, ftrl_l1=0.05,
+            learning_rate=0.5, table_tiering=tiering, hot_rows=64,
+        )
+        t = Trainer(cfg, mesh=mesh_lib.make_mesh(cfg, jax.devices()[:1]))
+        t.train()
+        final = [json.loads(line) for line in open(mf)][-1]
+        gauges = final["stages"]["gauges"]
+        assert (t.tiered is not None) == (tiering == "on")
+        if optimizer == "adagrad" or tiering == "on":
+            assert "train.ftrl_zero_frac" not in gauges
+            assert "ftrl_zero_frac" not in final["health"]
+            return
+        table = np.asarray(t.state.params.table)
+        share = float(np.mean(table == 0))
+        assert 0.0 < share < 1.0  # L1 holds some weights at 0
+        assert gauges["train.ftrl_zero_frac"] == pytest.approx(share)
+        assert final["health"]["ftrl_zero_frac"] == pytest.approx(share)
+
     def test_truncation_counter_in_results(self, tmp_path):
         """max_features smaller than the widest line: the drop count
         must surface in train results, not just a log warning."""

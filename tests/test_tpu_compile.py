@@ -322,6 +322,53 @@ def test_one_chip_step_at_the_shard_cell_shape_takes_the_writers_mask(
     assert used < 15.75 * 2**30, f"{used / 2**30:.2f} GiB of 15.75"
 
 
+def test_one_chip_ftrl_step_at_the_ftrl_cell_shape(topo, no_persistent_cache):
+    """The program ``criteo1tb-ftrl-train-shard`` dispatches -- the step
+    above under FTRL (``examples/criteo_1tb_ftrl.cfg``: weights, z and
+    n, three 2^25-row tables) -- compiled for one described chip: one
+    stream writer takes all three tables in place, the health carry
+    still ORs the writer's mask in, and the step fits the chip."""
+    import re
+
+    from fast_tffm_tpu.config import load_config
+    from fast_tffm_tpu.parallel import mesh as mesh_lib
+    from fast_tffm_tpu.train import loop, sparse as sparse_lib
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = load_config(os.path.join(repo, "examples", "criteo_1tb_ftrl.cfg"))
+    assert cfg.optimizer == "ftrl" and cfg.vocabulary_size == 1 << 25
+    mesh = Mesh(
+        np.array(topo.devices[:1]).reshape(1, 1),
+        (mesh_lib.DATA_AXIS, mesh_lib.MODEL_AXIS),
+    )
+    state, health, batches = _trainer_shapes(cfg, mesh)
+    step = loop.make_scan_train_step(
+        loop.make_sparse_train_step(cfg, mesh, with_health=True),
+        loop.make_health_update(cfg),
+    )
+    with pf.force_compiled():
+        assert sparse_lib.apply_stream(cfg, mesh)
+        assert sparse_lib.touch_mask(cfg, mesh)
+        compiled = jax.jit(step, donate_argnums=0).lower(
+            state, health, batches).compile()
+    text = compiled.as_text()
+    table = rf"f32\[{cfg.embedding_dim},{1 << 25}\]"
+    writers = [ln for ln in text.splitlines()
+               if "custom-call(" in ln and "tffm.apply_write" in ln
+               and re.search(table, ln)]
+    assert len(writers) == 1, writers
+    for k in range(3):  # the weights, z and n, each in place
+        assert f"{{{k}}}: ({1 + k}, {{}})" in writers[0], writers[0]
+    health_ops = [ln for ln in text.splitlines()
+                  if re.search(r" (sort|scatter)\(", ln)
+                  and "tffm.health" in ln]
+    assert not health_ops, health_ops
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert used < 15.75 * 2**30, f"{used / 2**30:.2f} GiB of 15.75"
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_field_aware_interaction_at_libffm_criteo_shape(
         one_chip, no_persistent_cache, dtype):
